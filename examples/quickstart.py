@@ -52,6 +52,9 @@ x = jax.random.normal(jax.random.fold_in(key, 1), (128,))
 mesh = jax.make_mesh((len(jax.devices()),), ("workers",))
 finished = np.ones(dep.num_workers, dtype=bool)
 finished[-2:] = False  # two slow-group stragglers miss the deadline
-y, ok = end_to_end_coded_matvec(mesh, a, x, dep, finished, use_kernel=True)
+# the Pallas kernel runs compiled, so it needs a TPU; elsewhere the
+# worker matvec is the einsum route
+on_tpu = jax.default_backend() == "tpu"
+y, ok = end_to_end_coded_matvec(mesh, a, x, dep, finished, use_kernel=on_tpu)
 err = float(jnp.max(jnp.abs(jnp.asarray(y) - a @ x)))
 print(f"coded matvec with 2 erasures: recovered={ok}, max|err|={err:.2e}")

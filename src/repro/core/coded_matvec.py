@@ -8,8 +8,10 @@ the master decodes A·x from the surviving coded rows.
 
 Layout: the coded matrix ``A~`` is laid out worker-major with per-worker
 blocks PADDED to ``max_load`` rows so the array shards evenly over the
-``workers`` mesh axis: shape (W, max_load, d). shard_map gives each
-device its block; the local product is one matvec (the Pallas kernel in
+``workers`` mesh axis: shape (W, max_load, d), or (W, max_load, R, d)
+when the code runs over blocks of R rows (the coded LM head's layout).
+shard_map gives each device its workers' blocks; the local product is
+one matvec (the Pallas kernel in
 ``repro/kernels/coded_matvec`` is the TPU-tiled version, selectable with
 ``use_kernel=True``); results are all-gathered and decoded.
 
@@ -26,7 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.coding import (
     decode_from_rows,
@@ -36,38 +38,35 @@ from repro.core.coding import (
 )
 from repro.core.planner import DeploymentPlan
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6: top-level API, check_vma kwarg
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-else:  # older jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
-
 
 def pack_coded_matrix(generator, a, plan: DeploymentPlan):
-    """Encode A and pack per-worker blocks padded to max_load.
+    """Encode A and pack per-worker blocks padded to max_load, on device.
+
+    ``a`` is (k, d), or (k, R, d) when the code runs over row BLOCKS of R
+    rows (the coded LM head's layout): a coded row is then a whole
+    (R, d) block and a worker's product for it is an (R,) vector.
 
     Returns:
-      packed: (W, max_load, d) float32 — worker i's rows in [i, :load_i].
-      row_of: (W, max_load) int32 — index into coded rows for each packed
-        slot (used to select generator rows at decode time); -1 = pad.
+      packed: (W, max_load, ..., d) — worker i's coded rows in
+        [i, :load_i], zeros in the pad slots.
+      row_of: (W, max_load) int32 numpy — index into coded rows for each
+        packed slot (used to select generator rows at decode time); -1 = pad.
     """
-    coded = np.asarray(encode(generator, a))
-    w = plan.num_workers
-    ml = plan.max_load
-    d = coded.shape[1]
-    packed = np.zeros((w, ml, d), dtype=np.float32)
-    row_of = np.full((w, ml), -1, dtype=np.int32)
+    coded = encode(generator, jnp.asarray(a, jnp.float32))
+    row_of = np.full((plan.num_workers, plan.max_load), -1, dtype=np.int32)
     for i, (s, e) in enumerate(plan.row_ranges):
-        packed[i, : e - s] = coded[s:e]
         row_of[i, : e - s] = np.arange(s, e, dtype=np.int32)
+    pad = (row_of < 0).reshape(row_of.shape + (1,) * (coded.ndim - 1))
+    packed = jnp.where(pad, 0.0, coded[np.maximum(row_of, 0)])
     return packed, row_of
 
 
 def _local_matvec(a_block, x):
-    # a_block: (1, max_load, d) on this shard; x replicated (d,)
-    return jnp.einsum("wld,d->wl", a_block, x)
+    # a_block: (W_local, max_load, ..., d) on this shard; x replicated (d,).
+    # Full float32: the decode solve amplifies bfloat16-rounded products.
+    return jnp.einsum(
+        "wl...d,d->wl...", a_block, x, precision=jax.lax.Precision.HIGHEST
+    )
 
 
 def coded_matvec(
@@ -92,13 +91,13 @@ def coded_matvec(
         local = _local_matvec
 
     fn = jax.jit(
-        _shard_map(
+        jax.shard_map(
             lambda a_block, xv: local(a_block, xv),
             mesh=mesh,
-            in_specs=(P(axis, None, None), P()),
-            out_specs=P(axis, None),
+            in_specs=(P(axis), P()),
+            out_specs=P(axis),
             # pallas_call outputs carry no varying-mesh-axes metadata
-            **_SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
     )
     return fn(packed, x)
@@ -149,8 +148,9 @@ def masked_decode(generator, row_of, partials, finished_workers):
     fin = jnp.asarray(finished_workers, dtype=bool)
     # row index per packed slot; dead/pad slots pushed out of bounds
     rows = jnp.where((row_of >= 0) & fin[:, None], row_of, n).ravel()
-    y = jnp.zeros((n,), partials.dtype).at[rows].set(
-        partials.ravel(), mode="drop"
+    block = partials.shape[row_of.ndim:]  # (R,) per slot for block codes
+    y = jnp.zeros((n, *block), partials.dtype).at[rows].set(
+        partials.reshape(rows.shape[0], *block), mode="drop"
     )
     alive = jnp.zeros((n,), bool).at[rows].set(True, mode="drop")
     return decode_systematic_jit(generator, y, alive)
@@ -174,12 +174,12 @@ def _fused_master_step(
         local = cmv_ops.blocked_matvec_batch
     else:
         local = _local_matvec
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         lambda a_block, xv: local(a_block, xv),
         mesh=mesh,
-        in_specs=(P(axis, None, None), P()),
-        out_specs=P(axis, None),
-        **_SHARD_MAP_NO_CHECK,
+        in_specs=(P(axis), P()),
+        out_specs=P(axis),
+        check_vma=False,
     )
     partials = sharded(packed, x)
     return masked_decode(generator, row_of, partials, finished_workers)

@@ -52,8 +52,15 @@ def make_generator(n: int, k: int, key=None, kind: str = "systematic_gaussian"):
 
 
 def encode(generator, a):
-    """A~ = G A  (rows of A are coded; columns untouched)."""
-    return generator @ a
+    """A~ = G A  (rows of A are coded; columns untouched).
+
+    ``a`` may carry trailing dims (k, ...): a row is then a whole block.
+    Full float32 precision: on a TPU the default would round the coded
+    rows to bfloat16, and the erasure solve amplifies that error.
+    """
+    return jnp.tensordot(
+        generator, a, axes=1, precision=jax.lax.Precision.HIGHEST
+    )
 
 
 def split_loads(loads_int_per_worker):
@@ -115,7 +122,8 @@ def decode_systematic_jit(generator, coded_values, finished_mask):
     rhs = y_s if y_s.ndim == 2 else y_s[:, None]
     lu, piv = jax.scipy.linalg.lu_factor(g_s)
     z = jax.scipy.linalg.lu_solve((lu, piv), rhs)
-    z = z + jax.scipy.linalg.lu_solve((lu, piv), rhs - g_s @ z)  # refine
+    resid = rhs - jnp.matmul(g_s, z, precision=jax.lax.Precision.HIGHEST)
+    z = z + jax.scipy.linalg.lu_solve((lu, piv), resid)  # refine
     z = z if y_s.ndim == 2 else z[:, 0]
     ok = jnp.sum(mask) >= k
     return jnp.where(ok, z.astype(y.dtype), jnp.zeros_like(z, dtype=y.dtype)), ok

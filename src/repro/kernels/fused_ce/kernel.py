@@ -75,7 +75,7 @@ def _kernel(h_ref, e_ref, lab_ref, lse_ref, ll_ref, m_ref, s_ref, ll_acc):
 
 @functools.partial(jax.jit, static_argnames=("bt", "bv", "interpret"))
 def fused_ce_kernel(h, table, labels, *, bt: int = BT, bv: int = BV,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """Per-token (lse, label_logit). Shapes must divide (bt, bv)."""
     t, d = h.shape
     v, _ = table.shape

@@ -22,7 +22,7 @@ def _pad_to(n: int, m: int) -> int:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def fused_linear_ce(h, table, labels, bt: int = BT, bv: int = BV,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """Mean cross entropy of ``h @ table^T`` vs labels, fused.
 
     h: (T, D); table: (V, D); labels: (T,) int32 (negatives = masked).

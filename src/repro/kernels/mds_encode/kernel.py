@@ -46,7 +46,7 @@ def _kernel(g_ref, a_ref, o_ref, acc_ref):
 
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "bk", "interpret"))
 def encode_kernel(g, a, *, bn: int = BN, bd: int = BD, bk: int = BK,
-                  interpret: bool = True):
+                  interpret: bool = False):
     n, k = g.shape
     k2, d = a.shape
     assert k == k2 and n % bn == 0 and d % bd == 0 and k % bk == 0

@@ -15,7 +15,7 @@ def _pad_to(n: int, m: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "bk", "interpret"))
 def mds_encode(g, a, *, bn: int = BN, bd: int = BD, bk: int = BK,
-               interpret: bool = True):
+               interpret: bool = False):
     """A~ = G A for arbitrary shapes: pad, run kernel, slice."""
     n, k = g.shape
     _, d = a.shape

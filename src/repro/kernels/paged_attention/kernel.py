@@ -64,7 +64,7 @@ def _kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_kernel(q, k_pool, v_pool, table, pos, *,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """Paged decode attend. q: (S, KV, G, hd); pools: (NBp, BL, KV, hd);
     table: (S, MB) int32; pos: (S,) int32. Returns (S, KV, G, hd)."""
     s, kv, g, hd = q.shape

@@ -127,7 +127,7 @@ def paged_chunk_attend(q, k_pool, v_pool, table, q_pos):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attend_kernel(q, k_pool, v_pool, table, pos, *,
-                               interpret: bool = True):
+                               interpret: bool = False):
     """Pallas-kernel route for the decode attend (ops-compatible API)."""
     from repro.kernels.paged_attention.kernel import paged_decode_kernel
 

@@ -325,8 +325,6 @@ def dryrun_cell(config: ModelConfig, shape: ShapeConfig, *, multi_pod: bool,
     compile_s = time.perf_counter() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x wraps the dict in a list
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_rec = {

@@ -162,7 +162,8 @@ def main(argv=None):
     if args.reduced:
         config = config.reduced()
     model = Model(config)
-    params = model.init_params(jax.random.PRNGKey(0))
+    # one compiled program: op-by-op init compiles every op on its own
+    params = jax.jit(model.init_params)(jax.random.PRNGKey(0))
 
     cluster = None
     scheme = make_scheme(

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.coding import decode_systematic_jit, make_generator
+from repro.core.coding import decode_systematic_jit, encode, make_generator
 from repro.core.planner import DeploymentPlan
 from repro.core.runtime_model import ClusterSpec
 from repro.core.schemes import AllocationScheme
@@ -101,7 +101,7 @@ class CodedLMHead:
                  key=None, scheme: str | AllocationScheme = "optimal",
                  deadline_safety: float = 3.0,
                  bucket_config: BucketConfig | None = None, telemetry=None):
-        self.table = np.asarray(embed_table, np.float32)  # (Vp, D)
+        self.table = jnp.asarray(embed_table, jnp.float32)  # (Vp, D)
         vp, _ = self.table.shape
         self.block_rows = block_rows
         self.kb = -(-vp // block_rows)  # blocks needed to cover the vocab
@@ -137,20 +137,30 @@ class CodedLMHead:
             else self.executor.generator(key=self._generator_key)
         )
         self.generator_j = jnp.asarray(self.generator)
-        # coded blocks: (nb, R, D) = einsum over the block-reshaped table
-        vp, d = self.table.shape
-        pad = self.kb * self.block_rows - vp
-        tbl = np.pad(self.table, ((0, pad), (0, 0)))
-        blocks = tbl.reshape(self.kb, self.block_rows, d)
-        self.coded = jnp.asarray(
-            np.einsum("nk,krd->nrd", self.generator, blocks, optimize=True)
-        )
+        self._coded = None  # built on first use (``coded``)
         self.deadline = self.executor.deadline
         self._rows_of_worker = self.plan.row_ranges  # block ranges per worker
         # worker->block scatter map: block_owner[i] = worker holding coded
         # block i, so a (W,) finish mask gathers to an (nb,) erasure mask
         # in one device op (no per-worker Python loop at decode time).
         self.block_owner = self.executor.slot_owner
+
+    @property
+    def coded(self):
+        """Coded vocab blocks (nb, R, D): ``E~_i = sum_j G[i, j] E_j``.
+
+        Only ``worker_products`` (computing from h) reads them; the serve
+        path mixes logits instead (``encode_logits``). So the table —
+        nb x R x D float32, about 0.9 GB at a 152k vocab — is encoded on
+        the device on first use, not at every (re)plan.
+        """
+        if self._coded is None:
+            pad = self.kb * self.block_rows - self.table.shape[0]
+            blocks = jnp.pad(self.table, ((0, pad), (0, 0))).reshape(
+                self.kb, self.block_rows, -1
+            )
+            self._coded = encode(self.generator_j, blocks)
+        return self._coded
 
     def rebind_soft(self) -> None:
         """Rebind after a NON-structural bucket-switch replan.
@@ -189,8 +199,11 @@ class CodedLMHead:
         Since logits = E h, mixing logit blocks with G is numerically
         identical to each worker computing E~_i h from h directly, so the
         erasure/decode path is exercised end-to-end without re-running
-        the unembed matmul. ``use_kernel`` routes the mix through the
-        Pallas coded-matvec kernel (one matvec per rhs column).
+        the unembed matmul. The mix runs at full float32 precision: a
+        TPU's default would round every product to bfloat16, and the
+        erasure solve amplifies that. ``use_kernel`` routes the mix
+        through the Pallas coded-matvec kernel, with the B x R logit
+        columns as its batch of vectors.
         """
         b, v = logits.shape
         vp = self.kb * self.block_rows
@@ -199,13 +212,11 @@ class CodedLMHead:
         if use_kernel:
             from repro.kernels.coded_matvec import ops as cmv_ops
 
-            cols = blocks.transpose(1, 0, 2).reshape(self.kb, b * self.block_rows)
-            mixed = jax.vmap(
-                lambda col: cmv_ops.blocked_matvec(self.generator_j, col),
-                in_axes=1, out_axes=1,
-            )(cols)
-            return mixed.reshape(self.nb, b, self.block_rows)
-        return jnp.einsum("nk,bkr->nbr", self.generator_j, blocks)
+            cols = blocks.transpose(0, 2, 1).reshape(b * self.block_rows, self.kb)
+            mixed = cmv_ops.blocked_matvec(self.generator_j, cols)  # (B*R, nb)
+            return mixed.T.reshape(self.nb, b, self.block_rows)
+        return jnp.einsum("nk,bkr->nbr", self.generator_j, blocks,
+                          precision=jax.lax.Precision.HIGHEST)
 
     def decode_logits_jit(self, products, finished_workers):
         """Fixed-shape on-device decode: (nb, B, R) + (W,) -> ((B, kb*R), ok).
@@ -252,9 +263,11 @@ class CodedLMHead:
         if use_kernel:
             from repro.kernels.coded_matvec import ops as cmv_ops
 
-            per_seq = jax.vmap(lambda hb: cmv_ops.blocked_matvec_batch(self.coded, hb))
-            return jnp.moveaxis(per_seq(hf), 0, 1)
-        return jnp.einsum("nrd,bd->nbr", self.coded, hf)
+            flat = self.coded.reshape(-1, self.coded.shape[-1])  # (nb*R, D)
+            out = cmv_ops.blocked_matvec(flat, hf)  # (B, nb*R)
+            return out.reshape(-1, self.nb, self.block_rows).transpose(1, 0, 2)
+        return jnp.einsum("nrd,bd->nbr", self.coded, hf,
+                          precision=jax.lax.Precision.HIGHEST)
 
     # ------------------------------------------- host-side reference path
     def decode_logits(self, products, finished_workers) -> tuple[np.ndarray, bool]:
@@ -302,6 +315,9 @@ class ServeReport:
     admitted: int
     shed: int
     wall_s: float
+    #: decode rounds whose coded head lacked survivors to decode, so the
+    #: plain logits stood in (``_coded_select``'s ``ok`` was False)
+    fallback_rounds: int = 0
 
     @property
     def tokens_per_s(self) -> float:
@@ -316,6 +332,14 @@ class ServeReport:
     def latency_percentile(self, q: float) -> float:
         lat = self.latencies()
         return float(np.percentile(lat, q)) if lat.size else float("nan")
+
+
+def _count_fallbacks(decode_oks) -> int:
+    """Rounds with ``ok`` False over a run's per-dispatch flag arrays
+    (read once, after the run, so dispatches never wait on the host)."""
+    if not decode_oks:
+        return 0
+    return int(jnp.sum(~jnp.concatenate(decode_oks)))
 
 
 class Server:
@@ -495,6 +519,9 @@ class Server:
         and the slot-erasure mask all come from the branch picked
         in-program, so a replan within bucket capacity never retraces
         this program (DESIGN.md §11).
+
+        Returns ``(logits, ok)``: ``ok`` is False on a round whose
+        survivors could not decode, where the plain logits stood in.
         """
         head = self.coded_head
         vocab = self.model.config.vocab_size
@@ -519,7 +546,7 @@ class Server:
             dec, ok = head.decode_logits_jit(products, mask)
         dec = dec[:, : logits.shape[-1]]
         dec = jnp.where(ids[None, :] < vocab, dec, NEG_INF)
-        return jnp.where(ok, dec, lf)
+        return jnp.where(ok, dec, lf), ok
 
     def _gen_program(self, params, cache, prompts, key, deadline,
                      true_params=None, bucket_args=None, *, max_new):
@@ -557,7 +584,7 @@ class Server:
             return self._coded_select(
                 logits, jax.random.fold_in(key, step), deadline, true_params,
                 bucket_args,
-            )
+            )[0]
 
         # every sampled token goes through the coded head, including the
         # first post-prefill one (the old host loop skipped it)
@@ -651,9 +678,9 @@ class Server:
 
         def body(carry, t):
             cache, logits, pos = carry
-            sel = logits
+            sel, ok = logits, jnp.bool_(True)
             if self.coded_head is not None:
-                sel = self._coded_select(
+                sel, ok = self._coded_select(
                     logits, jax.random.fold_in(key, t), deadline, true_params,
                     bucket_args,
                 )
@@ -665,12 +692,12 @@ class Server:
                 active[:, None], nlog.astype(jnp.float32), logits
             )
             pos = jnp.where(active, pos + 1, pos)
-            return (cache, logits, pos), tok
+            return (cache, logits, pos), (tok, ok)
 
-        (cache, logits, pos), toks = jax.lax.scan(
+        (cache, logits, pos), (toks, oks) = jax.lax.scan(
             body, (cache, logits, pos), jnp.arange(steps, dtype=jnp.int32)
         )
-        return cache, logits, pos, toks
+        return cache, logits, pos, toks, oks
 
     def _serve_step_paged_program(self, params, cache, logits, pos,
                                   chunk_tokens, chunk_start, chunk_lens,
@@ -724,9 +751,9 @@ class Server:
 
         def body(carry, t):
             cache, logits, pos = carry
-            sel = logits
+            sel, ok = logits, jnp.bool_(True)
             if self.coded_head is not None:
-                sel = self._coded_select(
+                sel, ok = self._coded_select(
                     logits, jax.random.fold_in(key, t), deadline, true_params,
                     bucket_args,
                 )
@@ -739,12 +766,12 @@ class Server:
                 active[:, None], nlog.astype(jnp.float32), logits
             )
             pos = jnp.where(active, pos + 1, pos)
-            return (cache, logits, pos), tok
+            return (cache, logits, pos), (tok, ok)
 
-        (cache, logits, pos), toks = jax.lax.scan(
+        (cache, logits, pos), (toks, oks) = jax.lax.scan(
             body, (cache, logits, pos), jnp.arange(steps, dtype=jnp.int32)
         )
-        return cache, logits, pos, toks
+        return cache, logits, pos, toks, oks
 
     def serve(self, trace, *, slots: int = 4, prompt_cap: int | None = None,
               max_out: int | None = None, decode_block: int = 4,
@@ -873,6 +900,7 @@ class Server:
 
         now, i, call = 0.0, 0, 0
         prefill_rounds = decode_rounds = 0
+        decode_oks = []  # per-dispatch (steps,) coded-decode ok flags
         # constant "no admissions this round" arguments (hoisted so the
         # common no-admit dispatch ships no fresh host arrays)
         no_prompts = jnp.zeros((slots, prompt_cap), jnp.int32)
@@ -924,7 +952,7 @@ class Server:
                                  round=now, placed=len(placed)):
                     if clock is None:
                         with tracer.span("dispatch"):
-                            cache, logits, pos, _ = self._serve_step_fn(
+                            cache, logits, pos, _, oks = self._serve_step_fn(
                                 self.params, cache, logits, pos, prompts,
                                 lengths, rows, jnp.asarray(active), skey,
                                 deadline, true_params, bucket_args,
@@ -943,7 +971,7 @@ class Server:
                                 key=skey,
                                 true_cluster=self._true_cluster,
                             )
-                        cache, logits, pos, _ = timing.result
+                        cache, logits, pos, _, oks = timing.result
                         if controller is not None:
                             d = controller.observe_timing(timing)
                             if (
@@ -955,6 +983,7 @@ class Server:
                                 # program: compile, not round latency
                                 clock.discard_next()
                 call += 1
+                decode_oks.append(oks)
                 if placed:  # the fused admit pass costs its own round
                     now += 1.0
                     prefill_rounds += 1
@@ -979,6 +1008,7 @@ class Server:
             admitted=sched.admitted,
             shed=sched.shed,
             wall_s=wall,
+            fallback_rounds=_count_fallbacks(decode_oks),
         )
         sched.metrics.emit(telemetry, phase="serve", rounds=float(now))
         return report
@@ -1054,6 +1084,7 @@ class Server:
 
         now, i, call = 0.0, 0, 0
         prefill_rounds = decode_rounds = 0
+        decode_oks = []  # per-dispatch (steps,) coded-decode ok flags
         t0 = time.perf_counter()
         while i < len(trace) or not sched.idle:
             with tracer.span("admit", round=now) as asp:
@@ -1131,7 +1162,7 @@ class Server:
                 ):
                     if clock is None:
                         with tracer.span("dispatch"):
-                            cache, logits, pos, _ = (
+                            cache, logits, pos, _, oks = (
                                 self._serve_step_paged_fn(
                                     *args, steps=steps
                                 )
@@ -1144,7 +1175,7 @@ class Server:
                                 ),
                                 key=skey, true_cluster=self._true_cluster,
                             )
-                        cache, logits, pos, _ = timing.result
+                        cache, logits, pos, _, oks = timing.result
                         if controller is not None:
                             d = controller.observe_timing(timing)
                             if (
@@ -1154,6 +1185,7 @@ class Server:
                             ):
                                 clock.discard_next()
                 call += 1
+                decode_oks.append(oks)
                 for si, take in notes:
                     sched.note_prefill(si, take)
                 if prefilling:  # the batched chunk pass costs one round
@@ -1182,6 +1214,7 @@ class Server:
             admitted=sched.admitted,
             shed=sched.shed,
             wall_s=wall,
+            fallback_rounds=_count_fallbacks(decode_oks),
         )
         metrics.emit(telemetry, phase="serve", rounds=float(now))
         return report

@@ -1,4 +1,5 @@
 """MDS coding: encode/decode correctness, erasure tolerance, planner."""
+import os
 import subprocess
 import sys
 
@@ -155,8 +156,9 @@ def test_distributed_coded_matvec_8_devices():
         [sys.executable, "-c", DISTRIBUTED_SCRIPT],
         capture_output=True,
         text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"},
-        cwd="/root/repo",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
+             "JAX_PLATFORMS": "cpu"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         timeout=600,
     )
     assert r.returncode == 0, r.stderr[-2000:]

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh
 
 from repro.configs import ARCHS
@@ -113,7 +114,7 @@ def test_fused_pipeline_insufficient_flag():
 
 
 def test_decode_pipeline_kernel_route():
-    """use_kernel=True (Pallas interpret) matches the einsum route."""
+    """use_kernel=True (Pallas, interpreted on CPU) matches the einsum route."""
     mesh = _one_device_mesh()
     cluster = ClusterSpec.make([3, 3], [4.0, 1.0], 1.0)
     plan = plan_deployment(cluster, k=48)
@@ -125,7 +126,8 @@ def test_decode_pipeline_kernel_route():
     ref = DecodePipeline(mesh, gen, row_of)
     ker = DecodePipeline(mesh, gen, row_of, use_kernel=True)
     z_ref, ok_ref = ref(jnp.asarray(packed), x, fin)
-    z_ker, ok_ker = ker(jnp.asarray(packed), x, fin)
+    with pltpu.force_tpu_interpret_mode():
+        z_ker, ok_ker = ker(jnp.asarray(packed), x, fin)
     assert bool(ok_ref) and bool(ok_ker)
     np.testing.assert_allclose(np.asarray(z_ker), np.asarray(z_ref),
                                rtol=1e-4, atol=1e-4)
@@ -185,7 +187,8 @@ def test_head_encode_logits_kernel_parity():
     c, m, params, cluster, head = _head()
     logits = jax.random.normal(KEY, (2, head.kb * head.block_rows))
     ref = head.encode_logits(logits)
-    ker = head.encode_logits(logits, use_kernel=True)
+    with pltpu.force_tpu_interpret_mode():
+        ker = head.encode_logits(logits, use_kernel=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -194,7 +197,8 @@ def test_head_worker_products_kernel_parity():
     c, m, params, cluster, head = _head()
     h = jax.random.normal(KEY, (2, c.d_model))
     ref = head.worker_products(h)
-    ker = head.worker_products(h, use_kernel=True)
+    with pltpu.force_tpu_interpret_mode():
+        ker = head.worker_products(h, use_kernel=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 

@@ -16,7 +16,7 @@ def test_fused_ce_value_matches_ref(t, v, d):
     h = jax.random.normal(KEY, (t, d)) * 0.5
     e = jax.random.normal(jax.random.fold_in(KEY, 1), (v, d)) * 0.5
     labels = jax.random.randint(jax.random.fold_in(KEY, 2), (t,), 0, v)
-    got = fused_linear_ce(h, e, labels)
+    got = fused_linear_ce(h, e, labels, interpret=True)
     want = linear_ce_ref(h, e, labels)
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
 
@@ -27,7 +27,7 @@ def test_fused_ce_masked_labels():
     e = jax.random.normal(jax.random.fold_in(KEY, 1), (v, d))
     labels = jax.random.randint(jax.random.fold_in(KEY, 2), (t,), 0, v)
     masked = labels.at[: t // 2].set(-1)
-    got = fused_linear_ce(h, e, masked)
+    got = fused_linear_ce(h, e, masked, interpret=True)
     want = linear_ce_ref(h[t // 2:], e, labels[t // 2:])
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
 
@@ -37,7 +37,10 @@ def test_fused_ce_gradients_match_ref():
     h = jax.random.normal(KEY, (t, d)) * 0.3
     e = jax.random.normal(jax.random.fold_in(KEY, 1), (v, d)) * 0.3
     labels = jax.random.randint(jax.random.fold_in(KEY, 2), (t,), 0, v)
-    gh, ge = jax.grad(fused_linear_ce, argnums=(0, 1))(h, e, labels)
+    gh, ge = jax.grad(
+        lambda hh, ee: fused_linear_ce(hh, ee, labels, interpret=True),
+        argnums=(0, 1),
+    )(h, e)
     gh_r, ge_r = jax.grad(
         lambda hh, ee: linear_ce_ref(hh, ee, labels), argnums=(0, 1)
     )(h, e)

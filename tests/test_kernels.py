@@ -25,10 +25,22 @@ def test_matvec_kernel_matches_ref(r, d, dtype):
     x = jax.random.normal(jax.random.fold_in(KEY, 1), (d,), dtype)
     rtol, atol = _tol(dtype)
     np.testing.assert_allclose(
-        np.asarray(blocked_matvec(a, x), np.float32),
+        np.asarray(blocked_matvec(a, x, interpret=True), np.float32),
         np.asarray(matvec_ref(a, x), np.float32),
         rtol=rtol, atol=atol,
     )
+
+
+@pytest.mark.parametrize("n,r,d", [(1, 8, 128), (4, 300, 1030),
+                                   (300, 24, 37)])
+def test_matvec_kernel_vector_batch_matches_ref(n, r, d):
+    """Vectors as the rows of x: (N, D) -> (N, R), every A tile shared."""
+    a = jax.random.normal(KEY, (r, d), jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(KEY, 5), (n, d), jnp.float32)
+    got = np.asarray(blocked_matvec(a, x, interpret=True))
+    assert got.shape == (n, r)
+    np.testing.assert_allclose(got, np.asarray(matvec_ref(a, x)),
+                               rtol=1e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize("w,l,d", [(3, 16, 64), (5, 100, 257)])
@@ -36,7 +48,7 @@ def test_matvec_batch_matches_ref(w, l, d):
     a = jax.random.normal(KEY, (w, l, d))
     x = jax.random.normal(KEY, (d,))
     np.testing.assert_allclose(
-        np.asarray(blocked_matvec_batch(a, x)),
+        np.asarray(blocked_matvec_batch(a, x, interpret=True)),
         np.asarray(matvec_batch_ref(a, x)),
         rtol=1e-5, atol=1e-4,
     )
@@ -50,7 +62,7 @@ def test_encode_kernel_matches_ref(n, k, d, dtype):
     a = jax.random.normal(jax.random.fold_in(KEY, 2), (k, d), dtype)
     rtol, atol = _tol(dtype)
     np.testing.assert_allclose(
-        np.asarray(mds_encode(g, a), np.float32),
+        np.asarray(mds_encode(g, a, interpret=True), np.float32),
         np.asarray(encode_ref(g, a), np.float32),
         rtol=rtol, atol=atol * 10,
     )
@@ -66,7 +78,8 @@ def test_matvec_property_random_shapes(r, d, seed):
     a = jax.random.normal(k, (r, d))
     x = jax.random.normal(jax.random.fold_in(k, 1), (d,))
     np.testing.assert_allclose(
-        np.asarray(blocked_matvec(a, x)), np.asarray(matvec_ref(a, x)),
+        np.asarray(blocked_matvec(a, x, interpret=True)),
+        np.asarray(matvec_ref(a, x)),
         rtol=1e-5, atol=1e-4,
     )
 
@@ -81,7 +94,8 @@ def test_encode_property_random_shapes(n, k, d, seed):
     g = jax.random.normal(key, (n, k))
     a = jax.random.normal(jax.random.fold_in(key, 1), (k, d))
     np.testing.assert_allclose(
-        np.asarray(mds_encode(g, a)), np.asarray(encode_ref(g, a)),
+        np.asarray(mds_encode(g, a, interpret=True)),
+        np.asarray(encode_ref(g, a)),
         rtol=1e-5, atol=1e-4,
     )
 
@@ -91,7 +105,7 @@ def test_kernel_linearity_invariant():
     g = jax.random.normal(KEY, (24, 16))
     a = jax.random.normal(jax.random.fold_in(KEY, 3), (16, 80))
     x = jax.random.normal(jax.random.fold_in(KEY, 4), (80,))
-    coded = mds_encode(g, a)
-    lhs = blocked_matvec(coded, x)
-    rhs = g @ blocked_matvec(a, x)
+    coded = mds_encode(g, a, interpret=True)
+    lhs = blocked_matvec(coded, x, interpret=True)
+    rhs = g @ blocked_matvec(a, x, interpret=True)
     np.testing.assert_allclose(np.asarray(lhs), np.asarray(rhs), rtol=1e-4, atol=1e-4)

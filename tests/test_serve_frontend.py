@@ -242,6 +242,25 @@ def test_serve_slot_swaps_never_retrace_and_replay_is_deterministic():
     assert rep1.latency_percentile(99) == rep2.latency_percentile(99)
 
 
+@pytest.mark.parametrize("dead_fleet", [False, True])
+def test_serve_counts_undecodable_rounds(dead_fleet):
+    """``fallback_rounds`` counts decode rounds whose coded head had too
+    few survivors: none for the planned fleet, every one when no worker
+    can meet the deadline (the plain logits stand in, tokens still flow)."""
+    c = ARCHS["qwen3-0.6b"].reduced()
+    m = Model(c)
+    params = m.init_params(KEY)
+    cluster = ClusterSpec.make([2, 2], [4.0, 0.8])
+    server = Server(m, params, cluster, ServeConfig(block_rows=64))
+    if dead_fleet:
+        server.set_true_cluster(ClusterSpec.make([2, 2], [1e-9, 1e-9]))
+    wl = make_workload("poisson", num_requests=4, prompt_len=(4, 8),
+                       out_len=(2, 6), vocab=c.vocab_size)
+    rep = server.serve(wl.trace(seed=5), slots=2, decode_block=2)
+    assert rep.tokens > 0 and rep.decode_rounds > 0
+    assert rep.fallback_rounds == (rep.decode_rounds if dead_fleet else 0)
+
+
 def test_serve_overload_sheds_and_reports():
     c = ARCHS["qwen3-0.6b"].reduced()
     m = Model(c)
