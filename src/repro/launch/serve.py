@@ -10,6 +10,9 @@ and the logits are recovered from the surviving coded block-products.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import json
+import os
 import time
 
 import jax
@@ -121,9 +124,13 @@ def main(argv=None):
                     help="JSONL telemetry sink (round_timing / "
                          "adapt_decision / request events; feed it to "
                          "repro.launch.obsreport for the ops report)")
-    ap.add_argument("--chrome-trace", default=None, metavar="PATH",
-                    help="export the run's spans as Chrome trace_event "
-                         "JSON (open in Perfetto / chrome://tracing)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="serve under jax.profiler into DIR with the serve "
+                         "loop's spans as trace annotations: one "
+                         ".xplane.pb holds the host spans and the device "
+                         "ops on one clock; --trace runs also write "
+                         "DIR/scopes.json, each compiled program's "
+                         "instruction -> named scope map")
     args = ap.parse_args(argv)
     if args.trace is not None and args.scenario is not None:
         raise SystemExit("--trace and --scenario are separate serving "
@@ -187,47 +194,49 @@ def main(argv=None):
               f"loads/worker={h.plan.loads_per_worker.tolist()}, "
               f"deadline={h.deadline:.4f}")
 
-    if args.trace is not None:
-        _serve_trace(server, args, config)
-        return
-    prompts = jax.random.randint(
-        jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0, config.vocab_size
-    ).astype(jnp.int32)
-    extras = make_extras(config, args.batch)
-    if config.family == "audio":
-        extras = {"enc_out": model.encode(params, extras["frames"])}
-    if args.scenario is not None:
-        _serve_scenario(server, prompts, extras, args, cluster)
-        return
-    tracer = _attach_tracer(server, args)
-    t0 = time.perf_counter()
-    out = server.generate(prompts, args.max_new, extras=extras)
-    dt = time.perf_counter() - t0
-    print(f"generated {out.shape} in {dt:.2f}s "
-          f"({args.batch * args.max_new / dt:.1f} tok/s)")
-    print("sample:", out[0, -args.max_new:].tolist())
-    _export_chrome(tracer, args)
+    with _profiled(args):
+        if args.trace is not None:
+            _serve_trace(server, args, config)
+            return
+        prompts = jax.random.randint(
+            jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0,
+            config.vocab_size,
+        ).astype(jnp.int32)
+        extras = make_extras(config, args.batch)
+        if config.family == "audio":
+            extras = {"enc_out": model.encode(params, extras["frames"])}
+        if args.scenario is not None:
+            _serve_scenario(server, prompts, extras, args, cluster)
+            return
+        _attach_tracer(server, args)
+        t0 = time.perf_counter()
+        out = server.generate(prompts, args.max_new, extras=extras)
+        dt = time.perf_counter() - t0
+        print(f"generated {out.shape} in {dt:.2f}s "
+              f"({args.batch * args.max_new / dt:.1f} tok/s)")
+        print("sample:", out[0, -args.max_new:].tolist())
+
+
+def _profiled(args):
+    """A ``jax.profiler`` session into ``--profile DIR``, if asked for."""
+    if args.profile is None:
+        return contextlib.nullcontext()
+    return jax.profiler.trace(args.profile)
 
 
 def _attach_tracer(server, args, telemetry=None):
-    """A ``SpanTracer`` on the server (and its coded executor) when
-    ``--chrome-trace`` asks for one; mirrors spans to ``telemetry``
-    when the run has a JSONL sink too."""
-    if args.chrome_trace is None:
+    """An annotating ``SpanTracer`` on the server (and its coded
+    executor) under ``--profile``, so its spans land in the profile;
+    mirrors spans to ``telemetry`` when the run has a JSONL sink too."""
+    if args.profile is None:
         return None
     from repro.obs.trace import SpanTracer
 
-    tracer = SpanTracer(telemetry)
+    tracer = SpanTracer(telemetry, annotate=True)
     server.tracer = tracer
     if server.coded_head is not None:
         server.coded_head.executor.tracer = tracer
     return tracer
-
-
-def _export_chrome(tracer, args):
-    if tracer is not None:
-        path = tracer.export_chrome(args.chrome_trace)
-        print(f"chrome trace: {path} ({len(tracer.spans)} spans)")
 
 
 def _serve_trace(server, args, config):
@@ -271,7 +280,13 @@ def _serve_trace(server, args, config):
             paged=not args.dense_kv, block_len=args.block_len,
             num_blocks=args.num_blocks, prefill_chunk=args.prefill_chunk,
         )
-    _export_chrome(tracer, args)
+    if tracer is not None and rep.scopes is not None:
+        os.makedirs(args.profile, exist_ok=True)
+        path = os.path.join(args.profile, "scopes.json")
+        with open(path, "w") as f:
+            json.dump(rep.scopes, f)
+        print(f"profile: {args.profile} ({len(tracer.spans)} spans; "
+              f"scope maps of {len(rep.scopes)} programs in {path})")
     if clock is not None:
         unit = "-" if clock.unit_s is None else f"{clock.unit_s:.3e}"
         print(f"measured: {clock.fed}/{clock.rounds} rounds fed, "
@@ -313,7 +328,7 @@ def _serve_scenario(server, prompts, extras, args, cluster):
     trace = spec.trace(cluster, seed=0)
     head = server.coded_head
     tel = Telemetry(args.telemetry)
-    tracer = _attach_tracer(server, args, telemetry=tel)
+    _attach_tracer(server, args, telemetry=tel)
     controller = None
     if args.adapt_every is not None:
         controller = AdaptiveController(
@@ -377,7 +392,6 @@ def _serve_scenario(server, prompts, extras, args, cluster):
         print(f"controller: {len(controller.decisions)} decisions, "
               f"{len(replans)} replans at rounds "
               f"{[d.round for d in replans]}")
-    _export_chrome(tracer, args)
     tel.close()
 
 

@@ -45,6 +45,12 @@ from repro.models import layers as L
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models import xlstm as xlstm_mod
+from repro.obs.trace import (
+    SCOPE_ATTENTION,
+    SCOPE_LAYERS,
+    SCOPE_MLP,
+    SCOPE_UNEMBED,
+)
 
 PyTree = Any
 
@@ -868,36 +874,40 @@ class Model:
     def _paged_stack_apply(self, body, x, blocks, cache):
         """Scan-or-unroll over layers carrying per-layer pool slices."""
         layer_kv = {"k": cache["kv"]["k"], "v": cache["kv"]["v"]}
-        if self.config.scan_layers:
-            x, new_kv = jax.lax.scan(body, x, (blocks, layer_kv))
-        else:
-            news = []
-            for i in range(self.config.num_layers):
-                inp = jax.tree.map(lambda t: t[i], (blocks, layer_kv))
-                x, new = body(x, inp)
-                news.append(new)
-            new_kv = jax.tree.map(lambda *ts: jnp.stack(ts), *news)
+        with jax.named_scope(SCOPE_LAYERS):
+            if self.config.scan_layers:
+                x, new_kv = jax.lax.scan(body, x, (blocks, layer_kv))
+            else:
+                news = []
+                for i in range(self.config.num_layers):
+                    inp = jax.tree.map(lambda t: t[i], (blocks, layer_kv))
+                    x, new = body(x, inp)
+                    news.append(new)
+                new_kv = jax.tree.map(lambda *ts: jnp.stack(ts), *news)
         return x, {"kv": new_kv}
 
     def _paged_block_body(self, attn_fn):
-        """Residual block body around a paged attention fn (dense/moe)."""
+        """Residual block body around a paged attention fn (dense/moe),
+        each half in its named scope (DESIGN.md §14)."""
         c = self.config
         if c.family == "moe":
-            def body(h, inp):
-                p, kv_slice = inp
-                h, new = attn_fn(p, h, kv_slice)
-                h = h + moe_mod.moe_ffn(
+            def ffn(p, h):
+                return moe_mod.moe_ffn(
                     p["moe"], L.rmsnorm(p["ln2"], h),
                     num_experts=c.num_experts, top_k=c.top_k,
                     capacity_factor=c.capacity_factor,
                 )
-                return h, new
         else:
-            def body(h, inp):
-                p, kv_slice = inp
+            def ffn(p, h):
+                return L.mlp(p["mlp"], L.rmsnorm(p["ln2"], h))
+
+        def body(h, inp):
+            p, kv_slice = inp
+            with jax.named_scope(SCOPE_ATTENTION):
                 h, new = attn_fn(p, h, kv_slice)
-                h = h + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], h))
-                return h, new
+            with jax.named_scope(SCOPE_MLP):
+                h = h + ffn(p, h)
+            return h, new
         return body
 
     def decode_step_paged(self, params, cache, tokens, pos, table, active,
@@ -929,9 +939,12 @@ class Model:
         x, new_cache = self._paged_stack_apply(
             self._paged_block_body(attn_fn), x, params["blocks"], cache
         )
-        x = L.rmsnorm(params["final_norm"], x)
-        logits = L.unembed(params["embed"], x, DTYPES_LOGITS[c.logits_dtype])
-        return self._mask_pad_logits(logits[:, 0]), new_cache
+        with jax.named_scope(SCOPE_UNEMBED):
+            x = L.rmsnorm(params["final_norm"], x)
+            logits = L.unembed(
+                params["embed"], x, DTYPES_LOGITS[c.logits_dtype]
+            )
+            return self._mask_pad_logits(logits[:, 0]), new_cache
 
     def prefill_paged(self, params, cache, tokens, start, chunk_len, table):
         """One chunked-prefill admit round: C prompt tokens per slot.
@@ -968,13 +981,14 @@ class Model:
         x, new_cache = self._paged_stack_apply(
             self._paged_block_body(attn_fn), x, params["blocks"], cache
         )
-        last = jnp.clip(chunk_len - 1, 0, cc - 1)
-        x_last = x[jnp.arange(b), last][:, None]  # (S, 1, D)
-        x_last = L.rmsnorm(params["final_norm"], x_last)
-        logits = L.unembed(
-            params["embed"], x_last, DTYPES_LOGITS[c.logits_dtype]
-        )[:, 0]
-        return self._mask_pad_logits(logits), new_cache
+        with jax.named_scope(SCOPE_UNEMBED):
+            last = jnp.clip(chunk_len - 1, 0, cc - 1)
+            x_last = x[jnp.arange(b), last][:, None]  # (S, 1, D)
+            x_last = L.rmsnorm(params["final_norm"], x_last)
+            logits = L.unembed(
+                params["embed"], x_last, DTYPES_LOGITS[c.logits_dtype]
+            )[:, 0]
+            return self._mask_pad_logits(logits), new_cache
 
     # --------------------------------------------------------- analytics
     def param_count(self) -> int:

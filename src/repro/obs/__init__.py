@@ -3,7 +3,8 @@
 Four pieces, one import point:
 
 * :mod:`repro.obs.trace` — nested wall-clock span tracing over the
-  telemetry JSONL stream, exportable to Chrome ``trace_event`` JSON;
+  telemetry JSONL stream, optionally on a ``jax.profiler`` trace's
+  clock, and the named scopes of the serve program;
 * :mod:`repro.obs.metrics` — typed counters/gauges/mergeable
   histograms with per-deadline-class latency percentiles;
 * :mod:`repro.obs.schema` — the central event-schema registry every
@@ -30,10 +31,11 @@ from repro.obs.schema import (  # noqa: F401
 )
 from repro.obs.trace import (  # noqa: F401
     NULL_TRACER,
+    SCOPES,
     NullTracer,
     Span,
     SpanTracer,
-    spans_to_chrome,
+    scope_map,
 )
 
 __all__ = [
@@ -41,6 +43,6 @@ __all__ = [
     "MetricsRegistry", "REGISTRY",
     "EVENT_SCHEMAS", "EventSchema", "render_markdown",
     "validate_event", "validate_events",
-    "NULL_TRACER", "NullTracer", "Span", "SpanTracer",
-    "spans_to_chrome",
+    "NULL_TRACER", "NullTracer", "SCOPES", "Span", "SpanTracer",
+    "scope_map",
 ]

@@ -261,10 +261,11 @@ _SCHEMAS = (
         "`repro.obs.trace.SpanTracer` (§14) — one finished wall-clock "
         "span from the serve/train/executor/controller loops",
         [
-            ("span", "span name (`admit` \\| `prefill_chunk` \\| "
-                     "`decode_chunk` \\| `dispatch` \\| `erasure_solve` "
-                     "\\| `replan` \\| `bucket_switch` \\| "
-                     "`adapt_update`)"),
+            ("span", "span name (`serve_setup` \\| `admit` \\| "
+                     "`prepare` \\| `prefill_chunk` \\| `decode_chunk` "
+                     "\\| `dispatch` \\| `retire` \\| `finish` \\| "
+                     "`erasure_solve` \\| `replan` \\| `bucket_switch` "
+                     "\\| `adapt_update`)"),
             ("t0_s", "`perf_counter` at span entry"),
             ("dur_s", "span wall duration, seconds"),
             ("depth", "nesting depth (0 = top-level)"),

@@ -33,7 +33,9 @@ trainer consumes, so the per-worker block counts follow the configured
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import time
 
 import jax
@@ -46,7 +48,16 @@ from repro.core.runtime_model import ClusterSpec
 from repro.core.schemes import AllocationScheme
 from repro.models.model import DTYPES_LOGITS, Model, padded_vocab
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NULL_TRACER, SpanTracer
+from repro.obs.trace import (
+    NULL_TRACER,
+    SCOPE_FINISH_MASK,
+    SCOPE_MIX,
+    SCOPE_PREFILL,
+    SCOPE_SAMPLE,
+    SCOPE_SOLVE,
+    SpanTracer,
+    scope_map,
+)
 from repro.runtime.executor import CodedRoundExecutor
 from repro.runtime.plan_bucket import BucketConfig
 
@@ -318,6 +329,10 @@ class ServeReport:
     #: decode rounds whose coded head lacked survivors to decode, so the
     #: plain logits stood in (``_coded_select``'s ``ok`` was False)
     fallback_rounds: int = 0
+    #: traced paged runs: chunk size -> {instruction: scope} of each
+    #: compiled program the run dispatched (``obs.trace.scope_map``), so
+    #: a device profile's ops can be put down to the program's scopes
+    scopes: dict | None = None
 
     @property
     def tokens_per_s(self) -> float:
@@ -335,11 +350,10 @@ class ServeReport:
 
 
 def _count_fallbacks(decode_oks) -> int:
-    """Rounds with ``ok`` False over a run's per-dispatch flag arrays
-    (read once, after the run, so dispatches never wait on the host)."""
-    if not decode_oks:
-        return 0
-    return int(jnp.sum(~jnp.concatenate(decode_oks)))
+    """Rounds with ``ok`` False over a run's per-dispatch flag arrays,
+    read back in one ``device_get`` after the run: dispatches never wait
+    on the host, and no new sequence of chunk sizes compiles anything."""
+    return sum(int(np.count_nonzero(~f)) for f in jax.device_get(decode_oks))
 
 
 class Server:
@@ -387,6 +401,11 @@ class Server:
         #: the ClusterSpec behind _true_params (RoundClock decomposition
         #: needs the spec, not the flattened arrays)
         self._true_cluster = None
+        self._jit_programs()
+
+    def _jit_programs(self) -> None:
+        """(Re)build the compiled programs (a structural replan changes
+        their closure constants)."""
         self._generate_fn = jax.jit(
             self._gen_program, static_argnames=("max_new",)
         )
@@ -397,10 +416,16 @@ class Server:
             self._serve_step_program, static_argnames=("steps",),
             donate_argnums=(1, 2, 3),
         )
-        self._serve_step_paged_fn = jax.jit(
+        #: the paged program the loop dispatches through
+        #: ``_serve_step_paged_fn``, which a caller may wrap; this keeps
+        #: the jitted function itself, whose compiled text names scopes
+        self._serve_step_paged_jit = jax.jit(
             self._serve_step_paged_program, static_argnames=("steps",),
             donate_argnums=(1, 2, 3),
         )
+        self._serve_step_paged_fn = self._serve_step_paged_jit
+        #: chunk size -> ``scope_map`` of that compiled paged program
+        self._scope_maps: dict[int, dict] = {}
 
     # --------------------------------------------------------- adaptivity
     def set_true_cluster(self, cluster: ClusterSpec | None) -> None:
@@ -444,20 +469,22 @@ class Server:
         self.coded_head.refresh()
         self._true_params = None  # stale shapes after a replan
         self._true_cluster = None
-        self._generate_fn = jax.jit(
-            self._gen_program, static_argnames=("max_new",)
-        )
-        # cache/logits/pos are donated: the serve loop threads them
-        # through every dispatch and never reuses the old buffers, so XLA
-        # can update the KV cache in place instead of copying it per call
-        self._serve_step_fn = jax.jit(
-            self._serve_step_program, static_argnames=("steps",),
-            donate_argnums=(1, 2, 3),
-        )
-        self._serve_step_paged_fn = jax.jit(
-            self._serve_step_paged_program, static_argnames=("steps",),
-            donate_argnums=(1, 2, 3),
-        )
+        self._jit_programs()
+
+    def _program_scopes(self, args, sizes) -> dict:
+        """Chunk size -> ``scope_map`` of the compiled paged serve program,
+        for each of ``sizes``; ``args`` are arguments of one dispatch (the
+        same shapes for every size). Lowering after a call with the same
+        shapes reuses the call's trace and executable, so this reads the
+        program that ran and compiles nothing; maps are kept until the
+        programs are rebuilt."""
+        for steps in sizes:
+            if steps not in self._scope_maps:
+                compiled = self._serve_step_paged_jit.lower(
+                    *args, steps=steps
+                ).compile()
+                self._scope_maps[steps] = scope_map(compiled.as_text())
+        return {steps: self._scope_maps[steps] for steps in sorted(sizes)}
 
     def _bucket_args(self):
         """Fresh (bucket state, index) runtime args — None when off."""
@@ -527,26 +554,35 @@ class Server:
         vocab = self.model.config.vocab_size
         ids = jnp.arange(logits.shape[-1])
         lf = logits.astype(jnp.float32)
-        clean = jnp.where(ids[None, :] < vocab, lf, 0.0)
-        products = head.encode_logits(clean, use_kernel=self.cfg.use_kernel)
+        with jax.named_scope(SCOPE_MIX):
+            clean = jnp.where(ids[None, :] < vocab, lf, 0.0)
+            products = head.encode_logits(
+                clean, use_kernel=self.cfg.use_kernel
+            )
         mus, alphas, shifts = (
             true_params if true_params is not None else (None, None, None)
         )
         if bucket_args is not None:
             state, index = bucket_args
-            mask, sel = head.executor.finish_mask_bucket_jit(
-                step_key, state, index, mus=mus, alphas=alphas, shifts=shifts
-            )
-            alive = head.executor.slot_mask_bucket_jit(mask, sel)
-            dec, ok = head.decode_logits_bucket_jit(products, alive)
+            with jax.named_scope(SCOPE_FINISH_MASK):
+                mask, sel = head.executor.finish_mask_bucket_jit(
+                    step_key, state, index, mus=mus, alphas=alphas,
+                    shifts=shifts,
+                )
+                alive = head.executor.slot_mask_bucket_jit(mask, sel)
+            with jax.named_scope(SCOPE_SOLVE):
+                dec, ok = head.decode_logits_bucket_jit(products, alive)
         else:
-            mask = head.finish_mask_jit(
-                step_key, deadline, mus=mus, alphas=alphas, shifts=shifts
-            )
-            dec, ok = head.decode_logits_jit(products, mask)
-        dec = dec[:, : logits.shape[-1]]
-        dec = jnp.where(ids[None, :] < vocab, dec, NEG_INF)
-        return jnp.where(ok, dec, lf), ok
+            with jax.named_scope(SCOPE_FINISH_MASK):
+                mask = head.finish_mask_jit(
+                    step_key, deadline, mus=mus, alphas=alphas, shifts=shifts
+                )
+            with jax.named_scope(SCOPE_SOLVE):
+                dec, ok = head.decode_logits_jit(products, mask)
+        with jax.named_scope(SCOPE_SAMPLE):
+            dec = dec[:, : logits.shape[-1]]
+            dec = jnp.where(ids[None, :] < vocab, dec, NEG_INF)
+            return jnp.where(ok, dec, lf), ok
 
     def _gen_program(self, params, cache, prompts, key, deadline,
                      true_params=None, bucket_args=None, *, max_new):
@@ -734,14 +770,16 @@ class Server:
 
         def splice(ops):
             cache, logits, pos = ops
-            plog, new_cache = self.model.prefill_paged(
-                params, cache, chunk_tokens, chunk_start, chunk_lens, tables
-            )
-            new_logits = jnp.where(
-                finishing[:, None], plog.astype(jnp.float32), logits
-            )
-            new_pos = jnp.where(finishing, chunk_start + chunk_lens, pos)
-            return new_cache, new_logits, new_pos
+            with jax.named_scope(SCOPE_PREFILL):
+                plog, new_cache = self.model.prefill_paged(
+                    params, cache, chunk_tokens, chunk_start, chunk_lens,
+                    tables,
+                )
+                new_logits = jnp.where(
+                    finishing[:, None], plog.astype(jnp.float32), logits
+                )
+                new_pos = jnp.where(finishing, chunk_start + chunk_lens, pos)
+                return new_cache, new_logits, new_pos
 
         cache, logits, pos = jax.lax.cond(
             jnp.any(chunk_lens > 0), splice, lambda ops: ops,
@@ -753,11 +791,13 @@ class Server:
             cache, logits, pos = carry
             sel, ok = logits, jnp.bool_(True)
             if self.coded_head is not None:
+                with jax.named_scope(SCOPE_FINISH_MASK):
+                    step_key = jax.random.fold_in(key, t)
                 sel, ok = self._coded_select(
-                    logits, jax.random.fold_in(key, t), deadline, true_params,
-                    bucket_args,
+                    logits, step_key, deadline, true_params, bucket_args,
                 )
-            tok = jnp.argmax(sel, -1).astype(jnp.int32)
+            with jax.named_scope(SCOPE_SAMPLE):
+                tok = jnp.argmax(sel, -1).astype(jnp.int32)
             nlog, cache = self.model.decode_step_paged(
                 params, cache, tok, pos, tables, active,
                 use_kernel=False,
@@ -818,8 +858,6 @@ class Server:
         trace can never exhaust it (dense-equivalent capacity);
         an explicit pool turns on memory admission control.
         """
-        from repro.serve.scheduler import SlotScheduler
-
         if clock is not None and self.coded_head is None:
             raise ValueError("clock (measured serving) requires a coded head")
 
@@ -835,46 +873,67 @@ class Server:
         if self.coded_head is not None:
             self.coded_head.executor.tracer = tracer
 
-        paged = self.cfg.paged if paged is None else paged
-        trace = sorted(trace, key=lambda r: (r.arrival, r.rid))
-        if not trace:
-            raise ValueError("serve needs a non-empty request trace")
-        prompt_cap = int(
-            prompt_cap if prompt_cap is not None
-            else max(r.prompt_len for r in trace)
-        )
-        if not paged:
-            # dense slot caches are (S, prompt_cap + max_out + 1): a
-            # longer prompt cannot be represented. Paged mode has no such
-            # bound — long prompts prefill chunk-by-chunk instead.
-            too_long = [r.rid for r in trace if r.prompt_len > prompt_cap]
-            if too_long:
-                raise ValueError(
-                    f"requests {too_long} exceed prompt_cap={prompt_cap}"
+        # the ``serve_setup`` span (§14) runs from here through the
+        # loop's own set-up (KV cache, initial arrays), which closes it
+        with contextlib.ExitStack() as setup:
+            setup.enter_context(tracer.span("serve_setup"))
+            paged = self.cfg.paged if paged is None else paged
+            trace = sorted(trace, key=lambda r: (r.arrival, r.rid))
+            if not trace:
+                raise ValueError("serve needs a non-empty request trace")
+            prompt_cap = int(
+                prompt_cap if prompt_cap is not None
+                else max(r.prompt_len for r in trace)
+            )
+            if not paged:
+                # dense slot caches are (S, prompt_cap + max_out + 1): a
+                # longer prompt cannot be represented. Paged mode has no
+                # such bound — long prompts prefill chunk-by-chunk.
+                too_long = [r.rid for r in trace
+                            if r.prompt_len > prompt_cap]
+                if too_long:
+                    raise ValueError(
+                        f"requests {too_long} exceed prompt_cap={prompt_cap}"
+                    )
+            max_out = int(
+                max_out if max_out is not None
+                else max(r.out_len for r in trace)
+            )
+            if round_latency is None and controller is not None:
+                round_latency = controller.coverage_latency
+            reference = 1.0
+            if round_latency is not None:
+                reference = float(round_latency())
+                if not np.isfinite(reference) or reference <= 0:
+                    reference = 1.0
+            common = dict(
+                setup=setup, slots=slots, prompt_cap=prompt_cap,
+                max_out=max_out, decode_block=decode_block,
+                queue_cap=queue_cap, admission_threshold=admission_threshold,
+                controller=controller, round_latency=round_latency,
+                reference=reference, telemetry=telemetry, clock=clock,
+                key=key,
+            )
+            if paged:
+                return self._serve_paged(
+                    trace, block_len=block_len, num_blocks=num_blocks,
+                    prefill_chunk=prefill_chunk, **common,
                 )
-        max_out = int(
-            max_out if max_out is not None else max(r.out_len for r in trace)
-        )
+            return self._serve_dense(trace, **common)
+
+    def _serve_dense(self, trace, *, setup, slots, prompt_cap, max_out,
+                     decode_block, queue_cap, admission_threshold,
+                     controller, round_latency, reference, telemetry, clock,
+                     key) -> ServeReport:
+        """Dense-slot host loop behind ``serve(paged=False)``: per-slot
+        caches of ``prompt_cap + max_out + 1`` positions. ``setup`` holds
+        the open ``serve_setup`` span, closed once the cache exists."""
+        from repro.serve.scheduler import SlotScheduler
+
+        tracer = self.tracer  # resolved by serve()
         # +1: a finished (frozen) slot idempotently rewrites the entry at
         # its final pos, which sits one past its last sampled token
         cache_len = prompt_cap + max_out + 1
-        if round_latency is None and controller is not None:
-            round_latency = controller.coverage_latency
-        reference = 1.0
-        if round_latency is not None:
-            reference = float(round_latency())
-            if not np.isfinite(reference) or reference <= 0:
-                reference = 1.0
-        if paged:
-            return self._serve_paged(
-                trace, slots=slots, prompt_cap=prompt_cap, max_out=max_out,
-                decode_block=decode_block, queue_cap=queue_cap,
-                admission_threshold=admission_threshold,
-                controller=controller, round_latency=round_latency,
-                reference=reference, telemetry=telemetry, clock=clock,
-                key=key, block_len=block_len, num_blocks=num_blocks,
-                prefill_chunk=prefill_chunk,
-            )
         sched = SlotScheduler(
             slots, queue_cap=queue_cap,
             admission_threshold=admission_threshold,
@@ -906,14 +965,16 @@ class Server:
         no_prompts = jnp.zeros((slots, prompt_cap), jnp.int32)
         no_lengths = jnp.zeros((slots,), jnp.int32)
         no_rows = jnp.full((slots,), -1, jnp.int32)
+        setup.close()
         t0 = time.perf_counter()
         while i < len(trace) or not sched.idle:
-            with tracer.span("admit", round=now) as asp:
+            with tracer.span("admit") as asp:
                 while i < len(trace) and trace[i].arrival <= now + 1e-9:
                     sched.offer(trace[i], now)
                     i += 1
                 placed = sched.fill_slots(now)
-                asp.set(placed=len(placed))
+                if tracer.enabled:
+                    asp.set(round=now, placed=len(placed))
                 if placed:
                     prompts_np = np.zeros((slots, prompt_cap), np.int32)
                     lengths_np = np.zeros((slots,), np.int32)
@@ -948,8 +1009,9 @@ class Server:
                     )
                     bucket_args = self._bucket_args()
                 skey = jax.random.fold_in(key, call)
-                with tracer.span("decode_chunk", steps=steps,
-                                 round=now, placed=len(placed)):
+                with tracer.span("decode_chunk") as csp:
+                    if tracer.enabled:
+                        csp.set(steps=steps, round=now, placed=len(placed))
                     if clock is None:
                         with tracer.span("dispatch"):
                             cache, logits, pos, _, oks = self._serve_step_fn(
@@ -995,25 +1057,27 @@ class Server:
                 now = max(now, trace[i].arrival)  # idle: jump to next arrival
             else:
                 break
-        jax.block_until_ready(logits)
-        wall = time.perf_counter() - t0
-        report = ServeReport(
-            finished=tuple(sched.finished),
-            tokens=sum(
-                f.tokens for f in sched.finished if f.outcome == "done"
-            ),
-            rounds=now,
-            decode_rounds=decode_rounds,
-            prefill_rounds=prefill_rounds,
-            admitted=sched.admitted,
-            shed=sched.shed,
-            wall_s=wall,
-            fallback_rounds=_count_fallbacks(decode_oks),
-        )
-        sched.metrics.emit(telemetry, phase="serve", rounds=float(now))
+        with tracer.span("finish"):
+            jax.block_until_ready(logits)
+            wall = time.perf_counter() - t0
+            report = ServeReport(
+                finished=tuple(sched.finished),
+                tokens=sum(
+                    f.tokens for f in sched.finished if f.outcome == "done"
+                ),
+                rounds=now,
+                decode_rounds=decode_rounds,
+                prefill_rounds=prefill_rounds,
+                admitted=sched.admitted,
+                shed=sched.shed,
+                wall_s=wall,
+                fallback_rounds=_count_fallbacks(decode_oks),
+            )
+            sched.metrics.emit(telemetry, phase="serve", rounds=float(now))
         return report
 
-    def _serve_paged(self, trace, *, slots, prompt_cap, max_out,
+
+    def _serve_paged(self, trace, *, setup, slots, prompt_cap, max_out,
                      decode_block, queue_cap, admission_threshold,
                      controller, round_latency, reference, telemetry, clock,
                      key, block_len, num_blocks, prefill_chunk) -> ServeReport:
@@ -1025,6 +1089,12 @@ class Server:
         admit rounds, so one compiled program per decode-chunk size
         covers EVERY prompt length; and rounds where every busy slot is
         still mid-prompt dispatch a prefill-only pass (``steps=0``).
+
+        Its top-level spans (§14) tile the call: ``serve_setup`` (held
+        open in ``setup`` until the pool and initial arrays exist), then
+        per round ``admit``, ``prepare`` (the round's host arrays and
+        their transfer), the chunk span around ``dispatch``, and
+        ``retire``; then ``finish``.
         """
         from repro.serve.scheduler import BlockPool, SlotScheduler
 
@@ -1081,111 +1151,134 @@ class Server:
         no_chunk = jnp.zeros((slots, chunk), jnp.int32)
         no_i32 = jnp.zeros((slots,), jnp.int32)
         no_bool = jnp.zeros((slots,), bool)
+        setup.close()
 
         now, i, call = 0.0, 0, 0
         prefill_rounds = decode_rounds = 0
         decode_oks = []  # per-dispatch (steps,) coded-decode ok flags
+        args = None  # the last dispatch's arguments
         t0 = time.perf_counter()
         while i < len(trace) or not sched.idle:
-            with tracer.span("admit", round=now) as asp:
+            # spans take no attributes unless tracing is on: the disabled
+            # path then allocates nothing per span
+            with tracer.span("admit") as asp:
                 while i < len(trace) and trace[i].arrival <= now + 1e-9:
                     sched.offer(trace[i], now)
                     i += 1
                 placed = sched.fill_slots(now)
-                asp.set(placed=len(placed))
+                if tracer.enabled:
+                    asp.set(round=now, placed=len(placed))
                 for si, _req in placed:
                     blocks = sched.slots[si].blocks
                     table_np[si, :] = -1
                     table_np[si, : len(blocks)] = blocks
-            # this round's prefill chunk: the next `chunk` unconsumed
-            # prompt tokens of EVERY slot still mid-prompt (fresh admits
-            # included) — one batched pass covers them all
-            chunk_np = start_np = lens_np = fin_np = None
-            notes = []
-            for si, s in enumerate(sched.slots):
-                if not s.prefilling:
-                    continue
-                if chunk_np is None:
-                    chunk_np = np.zeros((slots, chunk), np.int32)
-                    start_np = np.zeros((slots,), np.int32)
-                    lens_np = np.zeros((slots,), np.int32)
-                    fin_np = np.zeros((slots,), bool)
-                take = min(chunk, s.request.prompt_len - s.prefilled)
-                chunk_np[si, :take] = s.request.prompt[
-                    s.prefilled : s.prefilled + take
-                ]
-                start_np[si] = s.prefilled
-                lens_np[si] = take
-                fin_np[si] = s.prefilled + take >= s.request.prompt_len
-                notes.append((si, take))
-            prefilling = chunk_np is not None
-            # decode-eligible AFTER the splice: done prefilling already,
-            # or finishing it in this very dispatch (so a short prompt
-            # still costs exactly 1 admit round + out_len decode rounds,
-            # matching the dense path's accounting)
-            active = [
-                s.busy and not s.done
-                and (not s.prefilling or (fin_np is not None and fin_np[si]))
-                for si, s in enumerate(sched.slots)
-            ]
-            steps = 0
-            if any(active):
-                steps = min(
-                    decode_block,
-                    min(s.request.out_len - s.generated
-                        for si, s in enumerate(sched.slots) if active[si]),
-                )
-            if prefilling or steps > 0:
-                if clock is not None:
-                    deadline = jnp.float32(self.coded_head.deadline)
-                    true_params = (
-                        self._true_params
-                        if self._true_params is not None
-                        else self.coded_head.executor.worker_params
+            with tracer.span("prepare") as psp:
+                # this round's prefill chunk: the next `chunk` unconsumed
+                # prompt tokens of EVERY slot still mid-prompt (fresh
+                # admits included) — one batched pass covers them all
+                chunk_np = start_np = lens_np = fin_np = None
+                notes = []
+                for si, s in enumerate(sched.slots):
+                    if not s.prefilling:
+                        continue
+                    if chunk_np is None:
+                        chunk_np = np.zeros((slots, chunk), np.int32)
+                        start_np = np.zeros((slots,), np.int32)
+                        lens_np = np.zeros((slots,), np.int32)
+                        fin_np = np.zeros((slots,), bool)
+                    take = min(chunk, s.request.prompt_len - s.prefilled)
+                    chunk_np[si, :take] = s.request.prompt[
+                        s.prefilled : s.prefilled + take
+                    ]
+                    start_np[si] = s.prefilled
+                    lens_np[si] = take
+                    fin_np[si] = s.prefilled + take >= s.request.prompt_len
+                    notes.append((si, take))
+                prefilling = chunk_np is not None
+                # decode-eligible AFTER the splice: done prefilling
+                # already, or finishing it in this very dispatch (so a
+                # short prompt still costs exactly 1 admit round +
+                # out_len decode rounds, matching the dense path)
+                active = np.array([
+                    s.busy and not s.done
+                    and (not s.prefilling
+                         or (fin_np is not None and fin_np[si]))
+                    for si, s in enumerate(sched.slots)
+                ], bool)
+                steps = 0
+                if active.any():
+                    steps = min(
+                        decode_block,
+                        min(s.request.out_len - s.generated
+                            for si, s in enumerate(sched.slots)
+                            if active[si]),
                     )
-                    bucket_args = self._bucket_args()
-                skey = jax.random.fold_in(key, call)
-                args = (
-                    self.params, cache, logits, pos,
-                    jnp.asarray(chunk_np) if prefilling else no_chunk,
-                    jnp.asarray(start_np) if prefilling else no_i32,
-                    jnp.asarray(lens_np) if prefilling else no_i32,
-                    jnp.asarray(fin_np) if prefilling else no_bool,
-                    jnp.asarray(table_np), jnp.asarray(active), skey,
-                    deadline, true_params, bucket_args,
-                )
-                # a round that splices prompt chunks is a prefill round
-                # even when finishing slots decode in the same dispatch
-                with tracer.span(
-                    "prefill_chunk" if prefilling else "decode_chunk",
-                    steps=steps, round=now, placed=len(placed),
-                ):
-                    if clock is None:
-                        with tracer.span("dispatch"):
-                            cache, logits, pos, _, oks = (
-                                self._serve_step_paged_fn(
-                                    *args, steps=steps
-                                )
-                            )
+                dispatching = prefilling or steps > 0
+                if dispatching:
+                    if clock is not None:
+                        deadline = jnp.float32(self.coded_head.deadline)
+                        true_params = (
+                            self._true_params
+                            if self._true_params is not None
+                            else self.coded_head.executor.worker_params
+                        )
+                        bucket_args = self._bucket_args()
+                    # a copy: on the CPU a device array may alias host
+                    # memory, and the loop rewrites the table after an
+                    # asynchronous dispatch has been handed it
+                    host = [table_np.copy(), active]
+                    if prefilling:
+                        host += [chunk_np, start_np, lens_np, fin_np]
+                        chunk_args = tuple(map(jnp.asarray, host[2:]))
                     else:
-                        with tracer.span("dispatch"):
-                            timing = clock.measure(
-                                lambda: self._serve_step_paged_fn(
-                                    *args, steps=steps
-                                ),
-                                key=skey, true_cluster=self._true_cluster,
-                            )
-                        cache, logits, pos, _, oks = timing.result
-                        if controller is not None:
-                            d = controller.observe_timing(timing)
-                            if (
-                                d is not None and d.replanned
-                                and self.coded_head
-                                    .executor.last_replan_structural
-                            ):
-                                clock.discard_next()
-                call += 1
-                decode_oks.append(oks)
+                        chunk_args = (no_chunk, no_i32, no_i32, no_bool)
+                    # the arguments before the step key
+                    args = (
+                        self.params, cache, logits, pos, *chunk_args,
+                        jnp.asarray(host[0]), jnp.asarray(active),
+                    )
+                    if tracer.enabled:
+                        psp.set(host_bytes=sum(a.nbytes for a in host))
+            if not dispatching:
+                if i < len(trace):
+                    now = max(now, trace[i].arrival)  # idle: next arrival
+                    continue
+                break
+            # a round that splices prompt chunks is a prefill round
+            # even when finishing slots decode in the same dispatch
+            with tracer.span(
+                "prefill_chunk" if prefilling else "decode_chunk"
+            ) as csp:
+                if tracer.enabled:
+                    csp.set(steps=steps, round=now, placed=len(placed))
+                # ``dispatch`` holds the round's launches: the step key's
+                # two small eager programs, then the call. With the
+                # device's queue full, the first launch of a round waits
+                with tracer.span("dispatch"):
+                    skey = jax.random.fold_in(key, call)
+                    step = functools.partial(
+                        self._serve_step_paged_fn, *args, skey, deadline,
+                        true_params, bucket_args, steps=steps,
+                    )
+                    if clock is None:
+                        cache, logits, pos, _, oks = step()
+                    else:
+                        timing = clock.measure(
+                            step, key=skey, true_cluster=self._true_cluster
+                        )
+                if clock is not None:
+                    cache, logits, pos, _, oks = timing.result
+                    if controller is not None:
+                        d = controller.observe_timing(timing)
+                        if (
+                            d is not None and d.replanned
+                            and self.coded_head
+                                .executor.last_replan_structural
+                        ):
+                            clock.discard_next()
+            call += 1
+            decode_oks.append(oks)
+            with tracer.span("retire"):
                 for si, take in notes:
                     sched.note_prefill(si, take)
                 if prefilling:  # the batched chunk pass costs one round
@@ -1197,26 +1290,33 @@ class Server:
                     sched.advance(steps)
                 for si, _fin in sched.retire_done(now):
                     table_np[si, :] = -1
-            elif i < len(trace):
-                now = max(now, trace[i].arrival)  # idle: jump to next arrival
-            else:
-                break
-        jax.block_until_ready(logits)
-        wall = time.perf_counter() - t0
-        report = ServeReport(
-            finished=tuple(sched.finished),
-            tokens=sum(
-                f.tokens for f in sched.finished if f.outcome == "done"
-            ),
-            rounds=now,
-            decode_rounds=decode_rounds,
-            prefill_rounds=prefill_rounds,
-            admitted=sched.admitted,
-            shed=sched.shed,
-            wall_s=wall,
-            fallback_rounds=_count_fallbacks(decode_oks),
-        )
-        metrics.emit(telemetry, phase="serve", rounds=float(now))
+        with tracer.span("finish"):
+            jax.block_until_ready(logits)
+            wall = time.perf_counter() - t0
+            scopes = None
+            if tracer.enabled and args is not None:
+                # the donated buffers of the last dispatch are gone; its
+                # outputs have the same shapes
+                scopes = self._program_scopes(
+                    (args[0], cache, logits, pos, *args[4:], skey, deadline,
+                     true_params, bucket_args),
+                    {int(f.shape[0]) for f in decode_oks},
+                )
+            report = ServeReport(
+                finished=tuple(sched.finished),
+                tokens=sum(
+                    f.tokens for f in sched.finished if f.outcome == "done"
+                ),
+                rounds=now,
+                decode_rounds=decode_rounds,
+                prefill_rounds=prefill_rounds,
+                admitted=sched.admitted,
+                shed=sched.shed,
+                wall_s=wall,
+                fallback_rounds=_count_fallbacks(decode_oks),
+                scopes=scopes,
+            )
+            metrics.emit(telemetry, phase="serve", rounds=float(now))
         return report
 
     # ------------------------------------------------------------ public
