@@ -137,8 +137,8 @@ def coded_logits_check(server, trace, erased, log) -> dict:
     never = jnp.float32(1e30)  # deadline every finite round time meets
     out = {}
     for case, dead in (("all_finish", ()), ("erased", tuple(erased))):
-        dec, ok = select(plain, jax.random.PRNGKey(0), never,
-                         erasure_case_params(head, dead))
+        dec, (ok, _) = select(plain, jax.random.PRNGKey(0), never,
+                              erasure_case_params(head, dead))
         require(bool(ok), f"coded decode not ok with workers {dead} erased")
         stats = compare_logits(dec, plain, vocab)
         log(f"coded vs plain logits [{case}: workers {list(dead)} erased, "

@@ -8,9 +8,10 @@ k coded inner products by solving ``G_S z = y~_S``.
 Generators provided:
 
 * ``systematic_gaussian`` — ``G = [I_k; P]`` with i.i.d. Gaussian parity
-  ``P`` (MDS with probability 1; decode touches only the missing
-  systematic rows, which keeps the solve small and well-conditioned when
-  few stragglers are erased).
+  ``P`` (MDS with probability 1). When no systematic row is erased the
+  decode is the first k coded rows as they are, with no solve; the numpy
+  oracle also solves only for the missing systematic rows when some are
+  erased.
 * ``chebyshev_vandermonde`` — Vandermonde on Chebyshev nodes (determinis-
   tic, every minor nonsingular; conditioning degrades with k, fine for
   k <= a few hundred as used in tests/examples).
@@ -21,7 +22,8 @@ TPU-tiled version of the same contraction.
 
 Decoding comes in two flavours: ``decode_systematic_jit`` — the
 fixed-shape, device-resident decode used by the serving pipeline (one
-compiled gather+solve per round, composable under ``jax.lax.scan``) —
+compiled program per round that runs the gather and solve only when the
+round erased a systematic row, composable under ``jax.lax.scan``) —
 and the numpy ``decode_systematic`` / ``decode_from_rows`` pair kept as
 reference oracles for tests and the legacy host-loop path.
 """
@@ -86,18 +88,39 @@ def decode_from_rows(generator_rows, coded_values):
     return sol
 
 
+def systematic_passthrough(generator, finished_mask):
+    """Traced bool: the decode is the first k coded rows as they are.
+
+    True when the generator's first k rows are the identity (a
+    systematic code) and every one of those rows survived, so that the
+    first k survivors in index order give ``G_S = I``.
+    """
+    g = jnp.asarray(generator)
+    k = g.shape[1]
+    mask = jnp.asarray(finished_mask, dtype=bool)
+    return jnp.all(g[:k] == jnp.eye(k, dtype=g.dtype)) & jnp.all(mask[:k])
+
+
 @jax.jit
 def decode_systematic_jit(generator, coded_values, finished_mask):
     """Fixed-shape, device-resident erasure decode (the serving hot path).
 
     Unlike ``decode_systematic`` (the numpy reference oracle below) this
-    never leaves the device and never branches on data: the surviving
-    coded rows are selected with a stable argsort on the erasure mask —
-    survivors first, in index order — and the first k of them are
-    gathered into a static ``(k, k)`` system solved on-device. For a
-    systematic generator with few erasures that system is mostly identity
-    rows, so it stays well-conditioned; one step of iterative refinement
-    recovers oracle-level accuracy at float32.
+    never leaves the device and has one branch, a ``lax.cond`` on a
+    scalar (``systematic_passthrough``):
+
+    * pass-through: the generator is systematic and no systematic row is
+      erased, so ``y[:k]`` is the answer; no factorisation or solve runs;
+    * dense solve, on every other step (any non-systematic generator,
+      or a systematic row erased): the surviving coded rows are selected
+      with a stable argsort on the erasure mask — survivors first, in
+      index order — and the first k of them are gathered into a static
+      ``(k, k)`` system, LU-factored and solved on-device. For a
+      systematic generator with few erasures that system is mostly
+      identity rows, so it stays well-conditioned; one step of iterative
+      refinement recovers oracle-level accuracy at float32.
+
+    Under ``vmap`` the ``cond`` becomes a select that runs both sides.
 
     Args:
       generator: (n, k) MDS generator used at encode time.
@@ -114,19 +137,27 @@ def decode_systematic_jit(generator, coded_values, finished_mask):
     n, k = g.shape
     y = jnp.asarray(coded_values)
     mask = jnp.asarray(finished_mask, dtype=bool)
-    # Survivors first, original order preserved -> static (k,) gather.
-    order = jnp.argsort(~mask, stable=True)
-    idx = order[:k]
-    g_s = g[idx]
-    y_s = y[idx].astype(g.dtype)
-    rhs = y_s if y_s.ndim == 2 else y_s[:, None]
-    lu, piv = jax.scipy.linalg.lu_factor(g_s)
-    z = jax.scipy.linalg.lu_solve((lu, piv), rhs)
-    resid = rhs - jnp.matmul(g_s, z, precision=jax.lax.Precision.HIGHEST)
-    z = z + jax.scipy.linalg.lu_solve((lu, piv), resid)  # refine
-    z = z if y_s.ndim == 2 else z[:, 0]
-    ok = jnp.sum(mask) >= k
-    return jnp.where(ok, z.astype(y.dtype), jnp.zeros_like(z, dtype=y.dtype)), ok
+
+    def passthrough():
+        return y[:k], jnp.bool_(True)
+
+    def solve():
+        # Survivors first, original order preserved -> static (k,) gather.
+        order = jnp.argsort(~mask, stable=True)
+        idx = order[:k]
+        g_s = g[idx]
+        y_s = y[idx].astype(g.dtype)
+        rhs = y_s if y_s.ndim == 2 else y_s[:, None]
+        lu, piv = jax.scipy.linalg.lu_factor(g_s)
+        z = jax.scipy.linalg.lu_solve((lu, piv), rhs)
+        resid = rhs - jnp.matmul(g_s, z, precision=jax.lax.Precision.HIGHEST)
+        z = z + jax.scipy.linalg.lu_solve((lu, piv), resid)  # refine
+        z = z if y_s.ndim == 2 else z[:, 0]
+        ok = jnp.sum(mask) >= k
+        zero = jnp.zeros_like(z, dtype=y.dtype)
+        return jnp.where(ok, z.astype(y.dtype), zero), ok
+
+    return jax.lax.cond(systematic_passthrough(g, mask), passthrough, solve)
 
 
 def decode_systematic(generator, coded_values, finished_mask, k: int):

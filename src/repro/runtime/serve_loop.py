@@ -42,7 +42,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.coding import decode_systematic_jit, encode, make_generator
+from repro.core.coding import (
+    decode_systematic_jit,
+    encode,
+    make_generator,
+    systematic_passthrough,
+)
 from repro.core.planner import DeploymentPlan
 from repro.core.runtime_model import ClusterSpec
 from repro.core.schemes import AllocationScheme
@@ -229,29 +234,32 @@ class CodedLMHead:
         return jnp.einsum("nk,bkr->nbr", self.generator_j, blocks,
                           precision=jax.lax.Precision.HIGHEST)
 
+    def alive_blocks_jit(self, finished_workers):
+        """(W,) worker finish mask -> (nb,) block-alive mask, through the
+        precomputed scatter map."""
+        return jnp.asarray(finished_workers, bool)[self.block_owner]
+
     def decode_logits_jit(self, products, finished_workers):
         """Fixed-shape on-device decode: (nb, B, R) + (W,) -> ((B, kb*R), ok).
 
         The worker finish mask gathers through the precomputed scatter
         map to an (nb,) block-erasure mask; ``decode_systematic_jit``
-        solves the static (kb, kb) system on-device. ``ok`` is a traced
-        bool — the caller folds the insufficient-survivors fallback in
-        with ``jnp.where`` instead of a Python branch.
+        passes the systematic blocks through, or solves the static
+        (kb, kb) system on-device when one of them is erased. ``ok`` is a
+        traced bool — the caller folds the insufficient-survivors
+        fallback in with ``jnp.where`` instead of a Python branch.
         """
-        alive = jnp.asarray(finished_workers, bool)[self.block_owner]
-        nb, b, r = products.shape
-        z, ok = decode_systematic_jit(
-            self.generator_j, products.reshape(nb, b * r), alive
+        return self.decode_logits_bucket_jit(
+            products, self.alive_blocks_jit(finished_workers)
         )
-        logits = z.reshape(self.kb, b, r).transpose(1, 0, 2).reshape(b, -1)
-        return logits, ok
 
     def decode_logits_bucket_jit(self, products, alive_blocks):
         """``decode_logits_jit`` with a precomputed (nb,) block-alive mask.
 
         Bucket-switch path: the erasure mask comes from the selected
         bucket's owner/alive arrays (``slot_mask_bucket_jit`` — capacity
-        padding rows always dead) instead of the static scatter map.
+        padding rows always dead, and they lie past the kb systematic
+        rows) instead of the static scatter map.
         """
         nb, b, r = products.shape
         z, ok = decode_systematic_jit(
@@ -329,6 +337,9 @@ class ServeReport:
     #: decode rounds whose coded head lacked survivors to decode, so the
     #: plain logits stood in (``_coded_select``'s ``ok`` was False)
     fallback_rounds: int = 0
+    #: decode rounds whose coded head erased no systematic block, so the
+    #: erasure decode passed them through with no solve
+    passthrough_rounds: int = 0
     #: traced paged runs: chunk size -> {instruction: scope} of each
     #: compiled program the run dispatched (``obs.trace.scope_map``), so
     #: a device profile's ops can be put down to the program's scopes
@@ -349,11 +360,14 @@ class ServeReport:
         return float(np.percentile(lat, q)) if lat.size else float("nan")
 
 
-def _count_fallbacks(decode_oks) -> int:
-    """Rounds with ``ok`` False over a run's per-dispatch flag arrays,
-    read back in one ``device_get`` after the run: dispatches never wait
-    on the host, and no new sequence of chunk sizes compiles anything."""
-    return sum(int(np.count_nonzero(~f)) for f in jax.device_get(decode_oks))
+def _count_rounds(decode_flags) -> tuple[int, int]:
+    """(rounds with ``ok`` False, rounds passed through) over a run's
+    per-dispatch ``(ok, passthrough)`` flag arrays, read back in one
+    ``device_get`` after the run: dispatches never wait on the host, and
+    no new sequence of chunk sizes compiles anything."""
+    flags = jax.device_get(decode_flags)
+    return (sum(int(np.count_nonzero(~ok)) for ok, _ in flags),
+            sum(int(np.count_nonzero(p)) for _, p in flags))
 
 
 class Server:
@@ -547,8 +561,12 @@ class Server:
         in-program, so a replan within bucket capacity never retraces
         this program (DESIGN.md §11).
 
-        Returns ``(logits, ok)``: ``ok`` is False on a round whose
-        survivors could not decode, where the plain logits stood in.
+        Returns ``(logits, (ok, passthrough))``: ``ok`` is False on a
+        round whose survivors could not decode, where the plain logits
+        stood in; ``passthrough`` is True on a round that erased no
+        systematic block, whose decode ran no solve. The two flags travel
+        as one value, so a wrapper that passes the select's second output
+        on needs to know neither.
         """
         head = self.coded_head
         vocab = self.model.config.vocab_size
@@ -570,19 +588,20 @@ class Server:
                     shifts=shifts,
                 )
                 alive = head.executor.slot_mask_bucket_jit(mask, sel)
-            with jax.named_scope(SCOPE_SOLVE):
-                dec, ok = head.decode_logits_bucket_jit(products, alive)
         else:
             with jax.named_scope(SCOPE_FINISH_MASK):
                 mask = head.finish_mask_jit(
                     step_key, deadline, mus=mus, alphas=alphas, shifts=shifts
                 )
-            with jax.named_scope(SCOPE_SOLVE):
-                dec, ok = head.decode_logits_jit(products, mask)
+        with jax.named_scope(SCOPE_SOLVE):
+            if bucket_args is None:
+                alive = head.alive_blocks_jit(mask)
+            dec, ok = head.decode_logits_bucket_jit(products, alive)
+            passthrough = systematic_passthrough(head.generator_j, alive)
         with jax.named_scope(SCOPE_SAMPLE):
             dec = dec[:, : logits.shape[-1]]
             dec = jnp.where(ids[None, :] < vocab, dec, NEG_INF)
-            return jnp.where(ok, dec, lf), ok
+            return jnp.where(ok, dec, lf), (ok, passthrough)
 
     def _gen_program(self, params, cache, prompts, key, deadline,
                      true_params=None, bucket_args=None, *, max_new):
@@ -714,9 +733,9 @@ class Server:
 
         def body(carry, t):
             cache, logits, pos = carry
-            sel, ok = logits, jnp.bool_(True)
+            sel, flags = logits, (jnp.bool_(True), jnp.bool_(False))
             if self.coded_head is not None:
-                sel, ok = self._coded_select(
+                sel, flags = self._coded_select(
                     logits, jax.random.fold_in(key, t), deadline, true_params,
                     bucket_args,
                 )
@@ -728,12 +747,12 @@ class Server:
                 active[:, None], nlog.astype(jnp.float32), logits
             )
             pos = jnp.where(active, pos + 1, pos)
-            return (cache, logits, pos), (tok, ok)
+            return (cache, logits, pos), (tok, *flags)
 
-        (cache, logits, pos), (toks, oks) = jax.lax.scan(
+        (cache, logits, pos), (toks, oks, passes) = jax.lax.scan(
             body, (cache, logits, pos), jnp.arange(steps, dtype=jnp.int32)
         )
-        return cache, logits, pos, toks, oks
+        return cache, logits, pos, toks, oks, passes
 
     def _serve_step_paged_program(self, params, cache, logits, pos,
                                   chunk_tokens, chunk_start, chunk_lens,
@@ -789,11 +808,11 @@ class Server:
 
         def body(carry, t):
             cache, logits, pos = carry
-            sel, ok = logits, jnp.bool_(True)
+            sel, flags = logits, (jnp.bool_(True), jnp.bool_(False))
             if self.coded_head is not None:
                 with jax.named_scope(SCOPE_FINISH_MASK):
                     step_key = jax.random.fold_in(key, t)
-                sel, ok = self._coded_select(
+                sel, flags = self._coded_select(
                     logits, step_key, deadline, true_params, bucket_args,
                 )
             with jax.named_scope(SCOPE_SAMPLE):
@@ -806,12 +825,12 @@ class Server:
                 active[:, None], nlog.astype(jnp.float32), logits
             )
             pos = jnp.where(active, pos + 1, pos)
-            return (cache, logits, pos), (tok, ok)
+            return (cache, logits, pos), (tok, *flags)
 
-        (cache, logits, pos), (toks, oks) = jax.lax.scan(
+        (cache, logits, pos), (toks, oks, passes) = jax.lax.scan(
             body, (cache, logits, pos), jnp.arange(steps, dtype=jnp.int32)
         )
-        return cache, logits, pos, toks, oks
+        return cache, logits, pos, toks, oks, passes
 
     def serve(self, trace, *, slots: int = 4, prompt_cap: int | None = None,
               max_out: int | None = None, decode_block: int = 4,
@@ -959,7 +978,8 @@ class Server:
 
         now, i, call = 0.0, 0, 0
         prefill_rounds = decode_rounds = 0
-        decode_oks = []  # per-dispatch (steps,) coded-decode ok flags
+        # per-dispatch (steps,) coded-decode ok and pass-through flags
+        decode_flags = []
         # constant "no admissions this round" arguments (hoisted so the
         # common no-admit dispatch ships no fresh host arrays)
         no_prompts = jnp.zeros((slots, prompt_cap), jnp.int32)
@@ -1014,12 +1034,13 @@ class Server:
                         csp.set(steps=steps, round=now, placed=len(placed))
                     if clock is None:
                         with tracer.span("dispatch"):
-                            cache, logits, pos, _, oks = self._serve_step_fn(
+                            out = self._serve_step_fn(
                                 self.params, cache, logits, pos, prompts,
                                 lengths, rows, jnp.asarray(active), skey,
                                 deadline, true_params, bucket_args,
                                 steps=steps,
                             )
+                            cache, logits, pos, _, oks, passes = out
                     else:
                         with tracer.span("dispatch"):
                             timing = clock.measure(
@@ -1033,7 +1054,7 @@ class Server:
                                 key=skey,
                                 true_cluster=self._true_cluster,
                             )
-                        cache, logits, pos, _, oks = timing.result
+                        cache, logits, pos, _, oks, passes = timing.result
                         if controller is not None:
                             d = controller.observe_timing(timing)
                             if (
@@ -1045,7 +1066,7 @@ class Server:
                                 # program: compile, not round latency
                                 clock.discard_next()
                 call += 1
-                decode_oks.append(oks)
+                decode_flags.append((oks, passes))
                 if placed:  # the fused admit pass costs its own round
                     now += 1.0
                     prefill_rounds += 1
@@ -1060,6 +1081,7 @@ class Server:
         with tracer.span("finish"):
             jax.block_until_ready(logits)
             wall = time.perf_counter() - t0
+            fallbacks, passthroughs = _count_rounds(decode_flags)
             report = ServeReport(
                 finished=tuple(sched.finished),
                 tokens=sum(
@@ -1071,7 +1093,8 @@ class Server:
                 admitted=sched.admitted,
                 shed=sched.shed,
                 wall_s=wall,
-                fallback_rounds=_count_fallbacks(decode_oks),
+                fallback_rounds=fallbacks,
+                passthrough_rounds=passthroughs,
             )
             sched.metrics.emit(telemetry, phase="serve", rounds=float(now))
         return report
@@ -1155,7 +1178,8 @@ class Server:
 
         now, i, call = 0.0, 0, 0
         prefill_rounds = decode_rounds = 0
-        decode_oks = []  # per-dispatch (steps,) coded-decode ok flags
+        # per-dispatch (steps,) coded-decode ok and pass-through flags
+        decode_flags = []
         args = None  # the last dispatch's arguments
         t0 = time.perf_counter()
         while i < len(trace) or not sched.idle:
@@ -1261,13 +1285,13 @@ class Server:
                         true_params, bucket_args, steps=steps,
                     )
                     if clock is None:
-                        cache, logits, pos, _, oks = step()
+                        cache, logits, pos, _, oks, passes = step()
                     else:
                         timing = clock.measure(
                             step, key=skey, true_cluster=self._true_cluster
                         )
                 if clock is not None:
-                    cache, logits, pos, _, oks = timing.result
+                    cache, logits, pos, _, oks, passes = timing.result
                     if controller is not None:
                         d = controller.observe_timing(timing)
                         if (
@@ -1277,7 +1301,7 @@ class Server:
                         ):
                             clock.discard_next()
             call += 1
-            decode_oks.append(oks)
+            decode_flags.append((oks, passes))
             with tracer.span("retire"):
                 for si, take in notes:
                     sched.note_prefill(si, take)
@@ -1293,6 +1317,7 @@ class Server:
         with tracer.span("finish"):
             jax.block_until_ready(logits)
             wall = time.perf_counter() - t0
+            fallbacks, passthroughs = _count_rounds(decode_flags)
             scopes = None
             if tracer.enabled and args is not None:
                 # the donated buffers of the last dispatch are gone; its
@@ -1300,7 +1325,7 @@ class Server:
                 scopes = self._program_scopes(
                     (args[0], cache, logits, pos, *args[4:], skey, deadline,
                      true_params, bucket_args),
-                    {int(f.shape[0]) for f in decode_oks},
+                    {int(ok.shape[0]) for ok, _ in decode_flags},
                 )
             report = ServeReport(
                 finished=tuple(sched.finished),
@@ -1313,7 +1338,8 @@ class Server:
                 admitted=sched.admitted,
                 shed=sched.shed,
                 wall_s=wall,
-                fallback_rounds=_count_fallbacks(decode_oks),
+                fallback_rounds=fallbacks,
+                passthrough_rounds=passthroughs,
                 scopes=scopes,
             )
             metrics.emit(telemetry, phase="serve", rounds=float(now))

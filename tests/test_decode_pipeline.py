@@ -22,9 +22,11 @@ from repro.core.coding import (
     decode_systematic_jit,
     encode,
     make_generator,
+    systematic_passthrough,
 )
 from repro.models.model import Model
 from repro.runtime.serve_loop import CodedLMHead, ServeConfig, Server
+from repro.serve import make_workload
 
 KEY = jax.random.PRNGKey(0)
 
@@ -75,6 +77,81 @@ def test_decode_jit_is_traceable_fixed_shape():
     z, ok = f(jnp.asarray(mask))
     assert bool(ok)
     np.testing.assert_allclose(np.asarray(z), x, rtol=1e-4, atol=1e-4)
+
+
+def _dense_decode(g, y, mask):
+    """The dense solve alone: first k survivors in index order, LU, two
+    solves and a refinement, zeros when fewer than k survive."""
+    g, y, mask = map(np.asarray, (g, y, mask))
+    k = g.shape[1]
+    idx = np.flatnonzero(mask)[:k]
+    if idx.size < k:
+        return np.zeros((k,) + y.shape[1:], y.dtype), False
+    return np.linalg.solve(g[idx].astype(np.float64), y[idx]), True
+
+
+def _erase(n, rows):
+    mask = np.ones(n, bool)
+    mask[list(rows)] = False
+    return mask
+
+
+@pytest.mark.parametrize("parity_erased", [0, 3, 16])
+@pytest.mark.parametrize("cols", [None, 5])
+def test_decode_jit_passes_systematic_rows_through(parity_erased, cols):
+    """No systematic row erased: the decode is y[:k], bit for bit, and the
+    numpy oracle gives the same."""
+    k, n = 32, 48
+    g = make_generator(n, k, KEY)
+    shape = (k,) if cols is None else (k, cols)
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(2), shape))
+    y = np.asarray(encode(g, jnp.asarray(x)))
+    rng = np.random.default_rng(parity_erased)
+    mask = _erase(n, k + rng.choice(n - k, size=parity_erased, replace=False))
+    assert bool(systematic_passthrough(g, mask))
+    z, ok = decode_systematic_jit(g, jnp.asarray(y), jnp.asarray(mask))
+    assert bool(ok)
+    np.testing.assert_array_equal(np.asarray(z), y[:k])
+    z_np, ok_np = decode_systematic(g, y, mask, k)
+    assert ok_np
+    np.testing.assert_array_equal(np.asarray(z), z_np)
+
+
+@pytest.mark.parametrize("erased", [[0], [1, 7, 30], list(range(16)),
+                                    list(range(17))])  # 17: < k survive
+@pytest.mark.parametrize("cols", [None, 5])
+def test_decode_jit_systematic_erasure_runs_the_dense_solve(erased, cols):
+    """A systematic row erased: the dense solve's answer, and zeros with
+    ok False when fewer than k rows survive."""
+    k, n = 32, 48
+    g = make_generator(n, k, KEY)
+    shape = (k,) if cols is None else (k, cols)
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(2), shape))
+    y = np.asarray(encode(g, jnp.asarray(x)))
+    mask = _erase(n, erased)
+    assert not bool(systematic_passthrough(g, mask))
+    z, ok = decode_systematic_jit(g, jnp.asarray(y), jnp.asarray(mask))
+    z_dense, ok_dense = _dense_decode(g, y, mask)
+    assert bool(ok) == ok_dense == (len(erased) <= n - k)
+    if ok_dense:
+        np.testing.assert_allclose(np.asarray(z), z_dense, rtol=1e-4,
+                                   atol=1e-4)
+    else:
+        np.testing.assert_array_equal(np.asarray(z), z_dense)
+
+
+def test_decode_jit_non_systematic_generator_always_solves():
+    """A generator whose first k rows are not the identity never passes
+    through, even with no erasure, and still decodes."""
+    k, n = 8, 12
+    g = make_generator(n, k, kind="chebyshev_vandermonde")
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (k,)))
+    y = encode(g, jnp.asarray(x))
+    mask = np.ones(n, bool)
+    assert not bool(systematic_passthrough(g, mask))
+    z, ok = decode_systematic_jit(g, y, jnp.asarray(mask))
+    assert bool(ok)
+    np.testing.assert_allclose(np.asarray(z), x, rtol=1e-3, atol=1e-3)
 
 
 # ------------------------------------------------------ fused master step
@@ -319,3 +396,81 @@ def test_jit_pipeline_first_token_is_coded():
     prompts = jax.random.randint(KEY, (1, 3), 0, c.vocab_size).astype(jnp.int32)
     server.generate(prompts, 4)
     assert len(calls) == 2  # token 0 + once inside the (traced-once) scan body
+
+
+# ------------------------------------------ pass-through count in serve
+def _count_server(deadline_safety=3.0):
+    c = ARCHS["qwen3-0.6b"].reduced()
+    m = Model(c)
+    params = m.init_params(KEY)
+    cluster = ClusterSpec.make([2, 2], [4.0, 0.8])
+    server = Server(m, params, cluster,
+                    ServeConfig(block_rows=64, deadline_safety=deadline_safety))
+    return c, server
+
+
+def _serve_counting(server, vocab):
+    """Serve a small trace; also count on the host, from each dispatch's
+    own keys and straggler parameters, the rounds that erase no
+    systematic block."""
+    head = server.coded_head
+    step = server._serve_step_paged_fn
+    expected = []
+
+    def count(*args, steps):
+        skey, deadline, (mus, alphas, shifts) = args[10:13]
+        for t in range(steps):
+            fin = head.finish_mask_jit(jax.random.fold_in(skey, t), deadline,
+                                       mus=mus, alphas=alphas, shifts=shifts)
+            alive = np.asarray(fin)[head.block_owner]
+            expected.append(bool(alive[: head.kb].all()))
+        return step(*args, steps=steps)
+
+    server._serve_step_paged_fn = count
+    wl = make_workload("poisson", num_requests=6, prompt_len=(4, 8),
+                       out_len=(4, 12), vocab=vocab)
+    rep = server.serve(wl.trace(seed=3), slots=2, decode_block=4)
+    assert len(expected) == rep.decode_rounds > 0
+    return rep, sum(expected)
+
+
+@pytest.mark.parametrize("case", ["planned", "no_miss", "systematic_out"])
+def test_serve_counts_passthrough_rounds(case):
+    """``passthrough_rounds`` is the number of decode rounds that erased
+    no systematic block; the rest ran the dense solve. A deadline no
+    worker misses passes every round through; a fleet whose systematic
+    blocks never arrive passes none."""
+    c, server = _count_server(deadline_safety=1.0)
+    head = server.coded_head
+    if case == "no_miss":
+        head.deadline = 1e9
+    if case == "systematic_out":
+        # workers 0-1 hold block 0, a systematic block, and never finish
+        assert head.block_owner[0] == 0
+        server.set_true_cluster(ClusterSpec.make([2, 2], [1e-9, 0.8]))
+    rep, passed = _serve_counting(server, c.vocab_size)
+    dense = rep.decode_rounds - passed
+    assert rep.passthrough_rounds == passed
+    assert rep.passthrough_rounds + dense == rep.decode_rounds
+    if case == "planned":
+        assert 0 < rep.passthrough_rounds < rep.decode_rounds
+    if case == "no_miss":
+        assert rep.passthrough_rounds == rep.decode_rounds
+    if case == "systematic_out":
+        assert rep.passthrough_rounds == 0
+
+
+def test_serve_non_systematic_head_never_passes_through():
+    """A coded head whose generator is not systematic takes the dense
+    solve on every round, though no block is erased."""
+    c, server = _count_server()
+    head = server.coded_head
+    head.generator = np.asarray(make_generator(
+        head.nb, head.kb, kind="chebyshev_vandermonde"))
+    head.generator_j = jnp.asarray(head.generator)
+    head.deadline = 1e9
+    wl = make_workload("poisson", num_requests=4, prompt_len=(4, 8),
+                       out_len=(2, 6), vocab=c.vocab_size)
+    rep = server.serve(wl.trace(seed=5), slots=2, decode_block=2)
+    assert rep.decode_rounds > 0 and rep.fallback_rounds == 0
+    assert rep.passthrough_rounds == 0
