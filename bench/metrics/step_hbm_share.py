@@ -1,9 +1,8 @@
 """Share of peak HBM bandwidth that decode steps reach: the least bytes
-each step must move (bench/counts.py: every weight once, each active
-slot's KV context) over the device time of the serve program's runs
+each step must move (the reference's ``Dims.decode_step_bytes``: every
+weight once, each active slot's KV context) over the device time of the serve program's runs
 dispatched under ``decode_chunk`` spans, over peak bytes/s."""
 
-import counts
 import xplane
 
 
@@ -17,7 +16,7 @@ def read(run):
     for r, d in zip(runs, run.dispatches):
         if d.prefill or not d.steps:
             continue
-        moved += sum(counts.decode_step_bytes(run.dims, c) for c in d.decode_contexts)
+        moved += sum(run.dims.decode_step_bytes(c) for c in d.decode_contexts)
         seconds += (r.end - r.start) * 1e-9
     if not seconds:
         return None

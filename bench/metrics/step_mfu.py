@@ -1,8 +1,8 @@
 """Model FLOP/s utilization of the whole serve step over the traced
-slice: operations of every prompt and output token processed
-(bench/counts.py), over the slice's seconds, over peak bf16 FLOP/s."""
+slice: operations of every prompt and output token processed (the
+reference's ``Dims.token_flops``), over the slice's seconds, over peak
+bf16 FLOP/s."""
 
-import counts
 import xplane
 
 
@@ -11,8 +11,8 @@ def read(run):
         return None
     flops = 0
     for d in run.dispatches:
-        flops += sum(counts.token_flops(run.dims, c) for c in d.prefill_contexts)
+        flops += sum(run.dims.token_flops(c) for c in d.prefill_contexts)
         for step in d.decode_contexts:
-            flops += sum(counts.token_flops(run.dims, c) for c in step)
+            flops += sum(run.dims.token_flops(c) for c in step)
     lo, hi = xplane.window(run.trace)
     return 100.0 * flops / ((hi - lo) * 1e-9) / run.peaks["bf16_flops_per_s"]

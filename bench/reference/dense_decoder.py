@@ -17,6 +17,12 @@ seed, layer by layer, by ``bench/weights.py``, in the served dtype and
 then widened to float32. ``precision="fp8"`` is the control: every matmul
 operand (weights and activations) rounded through float8 e4m3 with a
 per-tensor scale, the rest as above.
+
+As every module of ``bench/reference/``, it is the harness's one source
+of what the architecture is: ``Dims.of(cfg)`` (the sizes, the check of
+the program's registry entry, the byte and operation counts), ``STACKED``
+(the program's parameter-tree prefixes stacked over layers) and
+``forward``.
 """
 from __future__ import annotations
 
@@ -36,7 +42,11 @@ import weights as W  # noqa: E402
 #: architectures this reference knows, and whether each has query/key norms
 ARCHITECTURES = {"Qwen3ForCausalLM": True, "GraniteForCausalLM": False}
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+BYTES = {"bfloat16": 2, "float32": 4}
 E4M3_MAX = 448.0
+#: the program's parameter-tree prefixes whose leaves are stacked over
+#: layers on axis 0
+STACKED = ("blocks/",)
 
 
 def _pad_vocab(v: int) -> int:
@@ -101,6 +111,64 @@ class Dims:
             out["blocks/attn/q_norm"] = (hd,)
             out["blocks/attn/k_norm"] = (hd,)
         return out
+
+    def program_sizes(self) -> dict:
+        """``{ModelConfig attribute: value}`` the program's registry entry
+        must hold to run this file."""
+        return {
+            "d_model": self.d, "num_layers": self.layers, "num_heads": self.heads,
+            "num_kv_heads": self.kv, "d_ff": self.f, "vocab_size": self.vocab,
+            "resolved_head_dim": self.hd, "rope_theta": self.theta,
+            "qk_norm": self.qk_norm, "tie_embeddings": True, "family": "dense",
+            "activation": "silu", "sliding_window": None,
+        }
+
+    def constants_off(self) -> dict:
+        """``{what: (the program's, the file's)}`` for each constant the
+        program fixes and this file states otherwise."""
+        off = {}
+        # the program's RMSNorm eps and softmax scale are fixed: 1e-6, 1/sqrt(hd)
+        if self.eps != 1e-6 or abs(self.attn_scale * self.hd ** 0.5 - 1) > 1e-12:
+            off["eps/attention_multiplier"] = ((1e-6, "1/sqrt(hd)"),
+                                               (self.eps, self.attn_scale))
+        if (self.emb_mult, self.res_mult, self.logit_div) != (1.0, 1.0, 1.0):
+            off["multipliers"] = ((1.0, 1.0, 1.0),
+                                  (self.emb_mult, self.res_mult, self.logit_div))
+        return off
+
+    # Bytes and operations a step needs, from the shapes. These are the
+    # least work, whatever implements it: every weight read once at the
+    # served dtype and each active slot's KV context at the KV dtype for
+    # bytes; 2 operations per weight per token (the LM head included) plus
+    # 4 x context x heads x head_dim per layer per token for operations.
+    # The coded head's block mix and erasure solve count as nothing: they
+    # are redundancy, not model work.
+
+    def matmul_params(self) -> int:
+        """Weights that multiply a token's activations, the LM head included
+        (tied: the embedding table counts once, as the head)."""
+        attn = self.d * self.heads * self.hd * 2 + self.d * self.kv * self.hd * 2
+        mlp = 3 * self.d * self.f
+        return self.layers * (attn + mlp) + self.vocab * self.d
+
+    def params(self) -> int:
+        """Every parameter: matmul weights plus the norm scales."""
+        norms = 2 * self.d + (2 * self.hd if self.qk_norm else 0)
+        return self.matmul_params() + self.layers * norms + self.d
+
+    def weight_bytes(self) -> int:
+        return self.params() * BYTES[self.dtype]
+
+    def kv_bytes_per_token(self, kv_dtype: str = "bfloat16") -> int:
+        return self.layers * 2 * self.kv * self.hd * BYTES[kv_dtype]
+
+    def token_flops(self, context: int) -> int:
+        """Operations of one token that attends ``context`` positions."""
+        return 2 * self.matmul_params() + 4 * context * self.heads * self.hd * self.layers
+
+    def decode_step_bytes(self, contexts) -> int:
+        """Least bytes of one decode step over the active slots' contexts."""
+        return self.weight_bytes() + sum(contexts) * self.kv_bytes_per_token()
 
 
 def _served(x, dtype: str):

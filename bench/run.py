@@ -107,8 +107,9 @@ def load_cell(name: str, root: str = ROOT) -> tuple[dict, dict, dict, dict, dict
 def program_config(cfg: dict, base=None):
     """The program's ``ModelConfig`` for ``cfg``: its registry entry, in
     the configuration's dtype, checked against every size the file
-    states, so the file is what runs."""
-    from reference.dense_decoder import Dims
+    states, so the file is what runs. The reference the file names says
+    what to compare (``Dims.program_sizes``, ``Dims.constants_off``)."""
+    import correct
 
     if base is None:
         from repro.configs import get_arch
@@ -116,20 +117,10 @@ def program_config(cfg: dict, base=None):
         base = get_arch(cfg["system"]["arch"])
     mc = dataclasses.replace(base, param_dtype=cfg["torch_dtype"],
                              compute_dtype="bfloat16")
-    d = Dims.of(cfg)
-    stated = {
-        "d_model": d.d, "num_layers": d.layers, "num_heads": d.heads,
-        "num_kv_heads": d.kv, "d_ff": d.f, "vocab_size": d.vocab,
-        "resolved_head_dim": d.hd, "rope_theta": d.theta, "qk_norm": d.qk_norm,
-        "tie_embeddings": True, "family": "dense", "activation": "silu",
-        "sliding_window": None,
-    }
-    off = {k: (getattr(mc, k), v) for k, v in stated.items() if getattr(mc, k) != v}
-    # the program's RMSNorm eps and softmax scale are fixed: 1e-6, 1/sqrt(hd)
-    if d.eps != 1e-6 or abs(d.attn_scale * d.hd ** 0.5 - 1) > 1e-12:
-        off["eps/attention_multiplier"] = ((1e-6, "1/sqrt(hd)"), (d.eps, d.attn_scale))
-    if (d.emb_mult, d.res_mult, d.logit_div) != (1.0, 1.0, 1.0):
-        off["multipliers"] = ((1.0, 1.0, 1.0), (d.emb_mult, d.res_mult, d.logit_div))
+    d = correct.reference(cfg).Dims.of(cfg)
+    off = {k: (getattr(mc, k), v) for k, v in d.program_sizes().items()
+           if getattr(mc, k) != v}
+    off.update(d.constants_off())
     if off:
         raise SystemExit(f"program config differs from the file: {off}")
     return mc
@@ -139,6 +130,7 @@ def build(cfg: dict, mix: dict, seed: int, model_config):
     """Weights from the seed, the ``Server`` and its recorder."""
     import jax
 
+    import correct
     import weights
     from record import Recorder
     from repro.core.runtime_model import ClusterSpec
@@ -147,7 +139,8 @@ def build(cfg: dict, mix: dict, seed: int, model_config):
 
     model = Model(model_config)
     shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    params = jax.block_until_ready(weights.program_params(shapes, seed))
+    params = jax.block_until_ready(weights.program_params(
+        shapes, seed, correct.reference(cfg).STACKED))
     sysc = cfg["system"]
     server = Server(model, params, ClusterSpec.parse(sysc["fleet"]), ServeConfig(
         block_rows=sysc["block_rows"], deadline_safety=sysc["deadline_safety"],
@@ -199,7 +192,7 @@ def _load_metric(name: str):
 class TracedRun:
     """What the per-layer readers read (``bench/metrics/*.py``)."""
 
-    dims: object
+    dims: object  # the reference's ``Dims`` of the configuration
     peaks: dict
     compile_s: float
     slots: int
@@ -222,12 +215,10 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict, limits: dict, *,
     import numpy as np
 
     import correct
-    import counts
     import peaks as peaks_mod
     import traffic
     import xplane
     from record import replay_requests
-    from reference.dense_decoder import Dims
     from repro.runtime.compile_cache import enable_persistent_cache
     from spans import Tracer
 
@@ -235,7 +226,7 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict, limits: dict, *,
     log(f"compile cache: {enable_persistent_cache()}")
     clock = CompileClock()
     mc = program_config(cfg, model_config)
-    dims = Dims.of(cfg)
+    dims = correct.reference(cfg).Dims.of(cfg)
     t = time.perf_counter()
     server, rec = build(cfg, mix, args.seed, mc)
     log(f"set-up: weights + server {time.perf_counter() - t!r} s; coded head "
@@ -372,7 +363,7 @@ def run(args, bench: dict, cell: dict, cfg: dict, mix: dict, limits: dict, *,
         breakdown = {"device_ops": xplane.top_ops(tr), "idle_gaps": xplane.idle_gaps(tr)}
         log(f"traced slice: {len(dispatches)} of {hi - lo} dispatches, "
             f"{len(xplane.program_runs(tr, PROGRAM))} program runs in the trace, "
-            f"weights {counts.weight_bytes(dims)} B")
+            f"weights {dims.weight_bytes()} B")
     out = {"correct": ok, "attempted": attempted, "failed": attempted - done,
            "metrics": metrics, "device": device}
     if breakdown is not None:

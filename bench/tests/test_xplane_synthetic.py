@@ -47,3 +47,18 @@ def test_top_ops_and_idle_gaps():
     # host span open at its middle
     assert [g[0] for g in gaps] == ["admit", "replay", "decode_chunk", "replay"]
     assert [g[1] for g in gaps] == pytest.approx([450e-9, 140e-9, 50e-9, 50e-9])
+
+
+def test_idle_gaps_are_named_by_the_serve_loops_spans():
+    ops = [Event("a", 0, 100), Event("b", 200, 300), Event("c", 400, 500),
+           Event("d", 600, 700), Event("e", 800, 1000)]
+    spans = [Event("replay", 0, 1000), Event("serve_setup", 0, 160),
+             Event("prepare", 320, 390), Event("retire", 540, 560),
+             Event("finish", 700, 900),
+             # a host event of no kept span, inside the gap under prepare
+             Event("PjitFunction(_serve_step_paged_program)", 340, 360)]
+    # kept as ``xplane.load`` keeps them
+    host = [e for e in spans if e.name in xplane.HOST_SPANS]
+    gaps = xplane.idle_gaps(Trace([ops], [[]], host))
+    assert [g[0] for g in gaps] == ["serve_setup", "prepare", "retire", "finish"]
+    assert [g[1] for g in gaps] == pytest.approx([100e-9] * 4)

@@ -45,7 +45,7 @@ def model_config():
 
 
 def run(seed: int, *, limit: float = LIMIT, trace: int = 0, keep_trace=None,
-        seconds: float = 0.5, mix=None):
+        seconds: float = 0.5, mix=None, cfg=None):
     """One harness run of the tiny cell; returns its result line."""
     import jax
 
@@ -69,7 +69,7 @@ def run(seed: int, *, limit: float = LIMIT, trace: int = 0, keep_trace=None,
 
     record.Recorder.__call__ = blocking
     try:
-        return R.run(args, bench, cell, CFG, mix or MIX, {"logit_gap": limit},
+        return R.run(args, bench, cell, cfg or CFG, mix or MIX, {"logit_gap": limit},
                      model_config=model_config(), log=lambda m: None)
     finally:
         record.Recorder.__call__ = step

@@ -9,7 +9,8 @@ served dtype: a 16-bit integer from the seed's random bits times a power
 of two. So the two calls agree bit for bit, whatever XLA fuses.
 
 A leaf is named by its path in the tree (``blocks/attn/wq``); a leaf of
-the stacked layers is drawn per layer from ``fold_in(key, layer)``.
+the stacked layers (under a prefix the reference names in ``STACKED``)
+is drawn per layer from ``fold_in(key, layer)``.
 """
 from __future__ import annotations
 
@@ -42,10 +43,12 @@ def _path_id(path: str) -> np.uint32:
 def half_width(path: str, shape) -> float:
     """Power-of-two half-width of a weight leaf's uniform distribution,
     nearest to sqrt(3 / fan_in) (a unit-variance input keeps unit
-    variance), so scaling is exact."""
+    variance), so scaling is exact. The fan-in is the contracting axis,
+    the second to last: ``d_in`` of a ``(d_in, d_out)`` matrix and of a
+    layer's ``(experts, d_in, d_out)`` stack."""
     if path == "embed/table":
         return EMBED_HALF_WIDTH
-    fan_in = shape[0]
+    fan_in = shape[-2] if len(shape) >= 2 else shape[0]
     return 2.0 ** round(math.log2(math.sqrt(3.0 / fan_in)))
 
 
@@ -70,15 +73,15 @@ def _path_str(path) -> str:
     return "/".join(str(getattr(p, "key", p)) for p in path)
 
 
-def program_params(shapes, seed: int):
+def program_params(shapes, seed: int, stacked=("blocks/",)):
     """Fill the tree of ``ShapeDtypeStruct``s ``shapes`` (the program's
-    ``init_params`` layout) in one jitted call. Leaves under ``blocks``
-    are stacked over layers on axis 0."""
+    ``init_params`` layout) in one jitted call. Leaves under any prefix of
+    ``stacked`` are stacked over layers on axis 0."""
 
     def fill(key):
         def one(path, sds):
             name = _path_str(path)
-            if name.startswith("blocks/"):
+            if name.startswith(tuple(stacked)):
                 layers = jnp.arange(sds.shape[0], dtype=jnp.uint32)
                 vals = jax.vmap(
                     lambda i: leaf(key, name, sds.shape[1:], i)

@@ -14,8 +14,10 @@ import dataclasses
 import glob
 import os
 
-#: host spans kept: the harness's replay span and the program's own spans
-HOST_SPANS = ("replay", "admit", "prefill_chunk", "decode_chunk", "dispatch")
+#: host spans kept: the harness's replay span and the serve loop's own
+#: spans, which name the idle gaps under them
+HOST_SPANS = ("replay", "serve_setup", "admit", "prepare", "prefill_chunk",
+              "decode_chunk", "dispatch", "retire", "finish")
 #: ops that contain other ops on the same line (their time is counted
 #: again in the ops inside them)
 CONTAINERS = (" while(", " conditional(", " call(")
