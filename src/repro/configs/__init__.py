@@ -11,6 +11,7 @@ from repro.configs.base import (
 from repro.configs.granite_3_2b import CONFIG as GRANITE_3_2B
 from repro.configs.grok_1_314b import CONFIG as GROK_1_314B
 from repro.configs.h2o_danube_3_4b import CONFIG as H2O_DANUBE_3_4B
+from repro.configs.mellum2_12b_a2p5b import CONFIG as MELLUM2_12B_A2P5B
 from repro.configs.moonshot_v1_16b_a3b import CONFIG as MOONSHOT_V1_16B_A3B
 from repro.configs.paligemma_3b import CONFIG as PALIGEMMA_3B
 from repro.configs.qwen3_0p6b import CONFIG as QWEN3_0P6B
@@ -32,6 +33,7 @@ ARCHS: dict[str, ModelConfig] = {
         YI_9B,
         PALIGEMMA_3B,
         WHISPER_TINY,
+        MELLUM2_12B_A2P5B,
     )
 }
 
@@ -43,7 +45,7 @@ def get_arch(name: str) -> ModelConfig:
 
 
 def all_cells():
-    """Every (arch, shape) dry-run cell — 40 total."""
+    """Every (arch, shape) dry-run cell."""
     for cfg in ARCHS.values():
         for shape in shapes_for(cfg):
             yield cfg, shape

@@ -2,10 +2,27 @@
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import jax.numpy as jnp
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+
+
+class Yarn(NamedTuple):
+    """YaRN scaling of rotary embeddings (arXiv:2309.00071), as
+    ``transformers``' ``_compute_yarn_parameters`` reads a config's
+    ``rope_scaling``: frequencies interpolated by ``factor`` below the
+    ``beta_slow`` rotation count and kept above ``beta_fast`` (a linear
+    ramp between, over ``original_max_position`` positions), and cos and
+    sin both multiplied by ``attention_factor``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    #: None: YaRN's default, 0.1 ln(factor) + 1
+    attention_factor: float | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -21,12 +38,20 @@ class ModelConfig:
     head_dim: int | None = None
     qk_norm: bool = False
     sliding_window: int | None = None
+    #: window and full layers mixed: with a ``sliding_window``, every k-th
+    #: layer (the last of each period of k) attends in full, the others
+    #: through the window; 0 = every layer windowed
+    full_attn_every: int = 0
     rope_theta: float = 10_000.0
+    #: YaRN on the full-attention layers' RoPE (None: plain RoPE)
+    yarn: Yarn | None = None
     activation: str = "silu"
     # --- MoE ---
+    #: experts held here: ids 0 .. num_experts - 1 of the router's
     num_experts: int = 0
+    #: the router's outputs (0: num_experts, every expert held)
+    routed_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
     # --- SSM / hybrid ---
     ssm_state: int = 0
     mamba_head_dim: int = 64
@@ -76,9 +101,33 @@ class ModelConfig:
         return DTYPES[self.compute_dtype]
 
     @property
+    def router_width(self) -> int:
+        return self.routed_experts or self.num_experts
+
+    @property
+    def layer_period(self) -> int:
+        """Layers in one period of the attention pattern."""
+        return self.full_attn_every or 1
+
+    def layer_window(self, i: int) -> int | None:
+        """Attention window of layer ``i`` (None: full attention)."""
+        if self.full_attn_every and (i + 1) % self.full_attn_every == 0:
+            return None
+        return self.sliding_window
+
+    def layer_yarn(self, i: int) -> Yarn | None:
+        """YaRN of layer ``i``'s RoPE: the full-attention layers'."""
+        return self.yarn if self.layer_window(i) is None else None
+
+    @property
+    def rolling_window(self) -> int | None:
+        """The window when every layer has it, so a decode cache can roll."""
+        return None if self.full_attn_every else self.sliding_window
+
+    @property
     def sub_quadratic(self) -> bool:
         """Eligible for the 500k-context decode shape (O(1)/O(window) state)."""
-        return self.family in ("ssm", "hybrid") or self.sliding_window is not None
+        return self.family in ("ssm", "hybrid") or self.rolling_window is not None
 
     @property
     def has_decode(self) -> bool:
@@ -97,6 +146,7 @@ class ModelConfig:
             vocab_size=512,
             head_dim=32,
             num_experts=min(self.num_experts, 8),
+            routed_experts=min(self.routed_experts, 16),
             top_k=min(self.top_k, 2),
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             mamba_head_dim=32,

@@ -1,4 +1,9 @@
-"""moonshot-v1-16b-a3b — Moonlight MoE, 64 experts top-6 [hf:moonshotai/Moonlight-16B-A3B]."""
+"""moonshot-v1-16b-a3b — a 64-expert top-6 MoE at Moonlight's sizes
+[hf:moonshotai/Moonlight-16B-A3B].
+
+Not Moonlight's architecture: its GQA block stands in for Moonlight's
+latent attention (MLA), and it has no shared experts.
+"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(

@@ -36,11 +36,19 @@ def gather_kv(pool, table):
     return pool[_phys(table, sink)].reshape(s, mb * bl, *pool.shape[2:])
 
 
-def valid_mask(table, block_len, q_pos):
-    """(S, MB), BL, (S,) -> (S, MB*BL) attendable-entry mask."""
+def valid_mask(table, block_len, q_pos, window=None):
+    """(S, MB), BL, (S, ...) -> (S, ..., MB*BL) attendable-entry mask:
+    allocated, at or before the query's position, and with a ``window``
+    fewer than ``window`` positions behind it. Logical entry j of a
+    slot's table holds its token at position j."""
     alloc = jnp.repeat(table >= 0, block_len, axis=1)
     j = jnp.arange(alloc.shape[1])
-    return alloc & (j[None, :] <= q_pos[:, None])
+    q = q_pos[..., None]
+    alloc = alloc.reshape(alloc.shape[:1] + (1,) * (q.ndim - 2) + alloc.shape[1:])
+    valid = alloc & (j <= q)
+    if window is not None:
+        valid &= q - j < window
+    return valid
 
 
 def scatter_decode(k_pool, v_pool, k_new, v_new, table, pos, active):
@@ -83,7 +91,7 @@ def scatter_chunk(k_pool, v_pool, k_new, v_new, table, start, chunk_len):
     )
 
 
-def paged_decode_attend(q, k_pool, v_pool, table, pos):
+def paged_decode_attend(q, k_pool, v_pool, table, pos, window=None):
     """Single-query paged attention over the gathered pool.
 
     q: (S, KV, G, hd) post-rope; pos: (S,) write positions (already
@@ -98,13 +106,13 @@ def paged_decode_attend(q, k_pool, v_pool, table, pos):
     k = gather_kv(k_pool, table)
     v = gather_kv(v_pool, table)
     sc = jnp.einsum("bkgh,bskh->bkgs", q, k).astype(jnp.float32) * scale
-    valid = valid_mask(table, bl, pos)
+    valid = valid_mask(table, bl, pos, window)
     sc = jnp.where(valid[:, None, None, :], sc, NEG_INF)
     w = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
     return jnp.einsum("bkgs,bskh->bkgh", w, v)
 
 
-def paged_chunk_attend(q, k_pool, v_pool, table, q_pos):
+def paged_chunk_attend(q, k_pool, v_pool, table, q_pos, window=None):
     """Chunked-prefill paged attention: C queries per slot.
 
     q: (S, C, KV, G, hd) post-rope; q_pos: (S, C) absolute positions.
@@ -116,9 +124,7 @@ def paged_chunk_attend(q, k_pool, v_pool, table, q_pos):
     k = gather_kv(k_pool, table)
     v = gather_kv(v_pool, table)
     sc = jnp.einsum("bqkgh,bskh->bkgqs", q, k).astype(jnp.float32) * scale
-    alloc = jnp.repeat(table >= 0, bl, axis=1)  # (S, L)
-    j = jnp.arange(alloc.shape[1])
-    valid = alloc[:, None, :] & (j[None, None, :] <= q_pos[:, :, None])
+    valid = valid_mask(table, bl, q_pos, window)  # (S, C, L)
     sc = jnp.where(valid[:, None, None, :, :], sc, NEG_INF)
     w = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskh->bkgqh", w, v)

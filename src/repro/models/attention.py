@@ -180,7 +180,7 @@ def attention(
     params, x, positions, *,
     num_heads, num_kv_heads, head_dim,
     causal=True, window=None, use_rope=True, rope_theta=10_000.0,
-    xkv=None, kv_positions=None, q_block=512, kv_block=1024,
+    yarn=None, xkv=None, kv_positions=None, q_block=512, kv_block=1024,
     causal_skip=False, return_kv=False,
 ):
     """Full attention layer (train/prefill). x: (B, S, D).
@@ -200,9 +200,9 @@ def attention(
     q, k = _maybe_qk_norm(params, q, k)
     if use_rope:
         q = rope(q, jnp.broadcast_to(positions, x.shape[:1] + positions.shape[-1:]),
-                 rope_theta)
+                 rope_theta, yarn)
         k = rope(k, jnp.broadcast_to(kv_positions, xkv.shape[:1] + kv_positions.shape[-1:]),
-                 rope_theta)
+                 rope_theta, yarn)
     k_cache, v_cache = k, v  # pre-padding views (the decode-cache payload)
     b, s = x.shape[:2]
     skv = k.shape[1]
@@ -272,7 +272,7 @@ def _quantize_kv(t):
 def decode_attention(
     params, x, cache, pos, *,
     num_heads, num_kv_heads, head_dim,
-    window=None, use_rope=True, rope_theta=10_000.0,
+    window=None, use_rope=True, rope_theta=10_000.0, yarn=None,
 ):
     """Single-token decode. x: (B, 1, D); pos: scalar int32 (uniform batch).
 
@@ -286,8 +286,8 @@ def decode_attention(
     q, k_new = _maybe_qk_norm(params, q, k_new)
     if use_rope:
         p = jnp.full((1,), pos, dtype=jnp.int32)
-        q = rope(q, jnp.broadcast_to(p, (b, 1)), rope_theta)
-        k_new = rope(k_new, jnp.broadcast_to(p, (b, 1)), rope_theta)
+        q = rope(q, jnp.broadcast_to(p, (b, 1)), rope_theta, yarn)
+        k_new = rope(k_new, jnp.broadcast_to(p, (b, 1)), rope_theta, yarn)
     cache_len = cache["k"].shape[1]
     slot = jnp.mod(pos, cache_len).astype(jnp.int32)
     zero = jnp.zeros((), jnp.int32)  # all indices same dtype (x64-safe)
@@ -328,7 +328,8 @@ def decode_attention(
 def decode_attention_paged(
     params, x, cache, table, pos, active, *,
     num_heads, num_kv_heads, head_dim,
-    use_rope=True, rope_theta=10_000.0, use_kernel=False,
+    use_rope=True, rope_theta=10_000.0, yarn=None, window=None,
+    use_kernel=False,
 ):
     """Per-slot decode against a shared KV block pool (DESIGN.md §13).
 
@@ -340,7 +341,8 @@ def decode_attention_paged(
     ``active``: (S,) bool — inactive rows write to the sink so frozen
     slots can never corrupt reassigned blocks. The attend math mirrors
     ``decode_attention_slots`` exactly so paged decode logits bit-match
-    the dense oracle under an order-preserving layout.
+    the dense oracle under an order-preserving layout. ``window``: a
+    query at position i attends keys j with i - j < window.
     """
     from repro.kernels.paged_attention import ops as paged_ops
 
@@ -349,19 +351,22 @@ def decode_attention_paged(
     q, k_new = _maybe_qk_norm(params, q, k_new)
     if use_rope:
         p = pos[:, None].astype(jnp.int32)
-        q = rope(q, p, rope_theta)
-        k_new = rope(k_new, p, rope_theta)
+        q = rope(q, p, rope_theta, yarn)
+        k_new = rope(k_new, p, rope_theta, yarn)
     k_pool, v_pool = paged_ops.scatter_decode(
         cache["k"], cache["v"], k_new[:, 0], v_new[:, 0], table, pos, active
     )
     g = num_heads // num_kv_heads
     qr = q.reshape(b, num_kv_heads, g, head_dim)
     if use_kernel:
+        if window is not None:
+            raise NotImplementedError("the paged decode kernel has no window")
         out = paged_ops.paged_decode_attend_kernel(
             qr, k_pool, v_pool, table, pos
         )
     else:
-        out = paged_ops.paged_decode_attend(qr, k_pool, v_pool, table, pos)
+        out = paged_ops.paged_decode_attend(qr, k_pool, v_pool, table, pos,
+                                            window=window)
     out = out.reshape(b, 1, num_heads * head_dim)
     y = out @ params["wo"].astype(x.dtype)
     return y, {"k": k_pool, "v": v_pool}
@@ -370,7 +375,7 @@ def decode_attention_paged(
 def prefill_attention_paged(
     params, x, cache, table, start, chunk_len, *,
     num_heads, num_kv_heads, head_dim,
-    use_rope=True, rope_theta=10_000.0,
+    use_rope=True, rope_theta=10_000.0, yarn=None, window=None,
 ):
     """One chunked-prefill pass of C prompt tokens per slot into the pool.
 
@@ -389,14 +394,15 @@ def prefill_attention_paged(
     q, k_new = _maybe_qk_norm(params, q, k_new)
     p = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]  # (S, C)
     if use_rope:
-        q = rope(q, p, rope_theta)
-        k_new = rope(k_new, p, rope_theta)
+        q = rope(q, p, rope_theta, yarn)
+        k_new = rope(k_new, p, rope_theta, yarn)
     k_pool, v_pool = paged_ops.scatter_chunk(
         cache["k"], cache["v"], k_new, v_new, table, start, chunk_len
     )
     g = num_heads // num_kv_heads
     qr = q.reshape(b, c, num_kv_heads, g, head_dim)
-    out = paged_ops.paged_chunk_attend(qr, k_pool, v_pool, table, p)
+    out = paged_ops.paged_chunk_attend(qr, k_pool, v_pool, table, p,
+                                       window=window)
     out = out.reshape(b, c, num_heads * head_dim).astype(x.dtype)
     y = out @ params["wo"].astype(x.dtype)
     return y, {"k": k_pool, "v": v_pool}
@@ -405,7 +411,7 @@ def prefill_attention_paged(
 def decode_attention_slots(
     params, x, cache, pos_map, pos, slot, *,
     num_heads, num_kv_heads, head_dim,
-    use_rope=True, rope_theta=10_000.0,
+    use_rope=True, rope_theta=10_000.0, yarn=None, window=None,
 ):
     """Per-slot decode: every batch row advances at its OWN position.
 
@@ -419,16 +425,16 @@ def decode_attention_slots(
     empty — the caller computes the post-write map once, it is shared by
     every layer); pos: (B,) this step's write positions; slot: (B,)
     cache indices to write (``pos % S``). Returns (y, {"k", "v"}).
-    Rolling/sliding-window caches and int8 KV are not supported here —
-    the slot server allocates full-context caches per slot.
+    Rolling caches and int8 KV are not supported here — the slot server
+    allocates full-context caches per slot, and a ``window`` masks them.
     """
     b = x.shape[0]
     q, k_new, v_new = _qkv(params, x, x, num_heads, num_kv_heads, head_dim)
     q, k_new = _maybe_qk_norm(params, q, k_new)
     if use_rope:
         p = pos[:, None].astype(jnp.int32)  # (B, 1) per-slot positions
-        q = rope(q, p, rope_theta)
-        k_new = rope(k_new, p, rope_theta)
+        q = rope(q, p, rope_theta, yarn)
+        k_new = rope(k_new, p, rope_theta, yarn)
     bidx = jnp.arange(b)
     k = cache["k"].at[bidx, slot].set(k_new[:, 0])
     v = cache["v"].at[bidx, slot].set(v_new[:, 0])
@@ -437,6 +443,8 @@ def decode_attention_slots(
     qr = q.reshape(b, num_kv_heads, g, head_dim)
     sc = jnp.einsum("bkgh,bskh->bkgs", qr, k).astype(jnp.float32) * scale
     valid = (pos_map >= 0) & (pos_map <= pos[:, None])  # (B, S)
+    if window is not None:
+        valid &= pos[:, None] - pos_map < window
     sc = jnp.where(valid[:, None, None, :], sc, NEG_INF)
     w = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgs,bskh->bkgh", w, v)

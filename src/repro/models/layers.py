@@ -90,22 +90,61 @@ def embed(params, tokens, compute_dtype):
 
 
 def unembed(params, x, logit_dtype=jnp.float32):
-    """Tied LM head: x @ table^T. logit_dtype bf16 halves the dominant
-    (B, S, V) activation bytes; the contraction still accumulates f32."""
+    """LM head: x @ table^T for the tied embedding (``{"table": (V, D)}``),
+    x @ w for an untied output head (``{"w": (D, V)}``). logit_dtype bf16
+    halves the dominant (B, S, V) activation bytes; the contraction still
+    accumulates f32."""
+    if "w" in params:
+        spec, w = "...d,dv->...v", params["w"]
+    else:
+        spec, w = "...d,vd->...v", params["table"]
     return jnp.einsum(
-        "...d,vd->...v", x, params["table"].astype(x.dtype),
-        preferred_element_type=jnp.float32,
+        spec, x, w.astype(x.dtype), preferred_element_type=jnp.float32,
     ).astype(logit_dtype)
 
 
-def rope(x, positions, theta: float = 10_000.0):
-    """Rotary embeddings. x: (..., S, H, hd); positions: (..., S)."""
+def yarn_frequencies(head_dim: int, theta: float, yarn) -> tuple[np.ndarray, float]:
+    """(inverse frequencies (head_dim / 2,), cos/sin factor) of YaRN, after
+    ``transformers``' ``_compute_yarn_parameters`` (truncated correction
+    range), in float64 on the host."""
+    half = head_dim // 2
+    base = theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    extrapolated, interpolated = 1.0 / base, 1.0 / (yarn.factor * base)
+
+    def correction_dim(rotations):
+        return (head_dim * np.log(yarn.original_max_position
+                                  / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(np.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp  # share of the extrapolated (unscaled) frequency
+    inv = interpolated * (1 - keep) + extrapolated * keep
+    factor = yarn.attention_factor
+    if factor is None:
+        factor = 0.1 * np.log(yarn.factor) + 1.0 if yarn.factor > 1 else 1.0
+    return inv, float(factor)
+
+
+def rope(x, positions, theta: float = 10_000.0, yarn=None):
+    """Rotary embeddings. x: (..., S, H, hd); positions: (..., S).
+    ``yarn`` (a ``configs.base.Yarn``) rescales the frequencies and
+    multiplies cos and sin (``yarn_frequencies``)."""
     hd = x.shape[-1]
     half = hd // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        inv, mult = yarn_frequencies(hd, theta, yarn)
+        freq = jnp.asarray(inv, jnp.float32)
     angles = positions[..., :, None].astype(jnp.float32) * freq  # (..., S, half)
     cos = jnp.cos(angles)[..., :, None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., :, None, :]
+    if yarn is not None:
+        cos, sin = cos * mult, sin * mult
     x1, x2 = x[..., :half], x[..., half:]
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin

@@ -49,7 +49,9 @@ from repro.obs.trace import (
     SCOPE_ATTENTION,
     SCOPE_LAYERS,
     SCOPE_MLP,
+    SCOPE_MOE,
     SCOPE_UNEMBED,
+    SCOPE_WINDOW_ATTENTION,
 )
 
 PyTree = Any
@@ -120,7 +122,10 @@ class Model:
                         qk_norm=c.qk_norm,
                     ),
                     "ln2": L.init_rmsnorm(c.d_model, dt),
-                    "moe": moe_mod.init_moe(k2, c.d_model, c.d_ff, c.num_experts, dt),
+                    "moe": moe_mod.init_moe(
+                        k2, c.d_model, c.d_ff, c.num_experts, dt,
+                        router_width=c.router_width,
+                    ),
                 }
 
             params["blocks"] = _stack_init(moe_block, kblocks, c.num_layers)
@@ -191,7 +196,23 @@ class Model:
             params["enc_norm"] = L.init_layernorm(c.d_model, dt)
         else:
             raise ValueError(f"unknown family {c.family}")
+        if not c.tie_embeddings:
+            # untied output head, (D, V) like any linear layer
+            params["lm_head"] = {"w": L._dense_init(kfinal, (c.d_model, pv), dt)}
         return params
+
+    def head_params(self, params):
+        """The LM head's parameters: the tied embedding or ``lm_head``."""
+        return params["embed"] if self.config.tie_embeddings else params["lm_head"]
+
+    def head_table(self, params):
+        """The LM head as a (V_padded, D) table (logits = h @ table^T)."""
+        head = self.head_params(params)
+        return head["table"] if "table" in head else head["w"].T
+
+    def _unembed(self, params, x):
+        return L.unembed(self.head_params(params), x,
+                         DTYPES_LOGITS[self.config.logits_dtype])
 
     def _is_slstm(self, layer_idx: int) -> bool:
         c = self.config
@@ -206,35 +227,34 @@ class Model:
         return jnp.where(ids < v, logits, -1e30)
 
     # -------------------------------------------------------- primitives
-    def _dense_apply(self, p, x, positions, *, causal=True):
+    def _attn_kw(self, j: int) -> dict:
+        """Static attention settings of layer position ``j`` in the
+        period: its window and its RoPE scaling."""
+        c = self.config
+        return {"window": c.layer_window(j), "yarn": c.layer_yarn(j)}
+
+    def _block_apply(self, p, x, positions, *, causal=True, j=0):
+        """One pre-norm block (attention, then MLP or experts), as layer
+        position ``j`` of the period (train/prefill)."""
         c = self.config
         h = x + attn_mod.attention(
             p["attn"], L.rmsnorm(p["ln1"], x), positions,
             num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
             head_dim=c.resolved_head_dim, causal=causal,
-            window=c.sliding_window, rope_theta=c.rope_theta,
-            q_block=c.attn_q_block, kv_block=c.attn_kv_block,
-            causal_skip=c.causal_block_skip,
+            rope_theta=c.rope_theta, q_block=c.attn_q_block,
+            kv_block=c.attn_kv_block, causal_skip=c.causal_block_skip,
+            **self._attn_kw(j),
         )
-        h = h + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], h))
-        return h
+        return h + self._ffn(p, h)[0]
 
-    def _moe_apply(self, p, x, positions):
+    def _ffn(self, p, h, valid=None):
+        """The block's second half (without its residual): the MLP, or
+        the expert layer with its counts (``moe.moe_ffn``; None else)."""
         c = self.config
-        h = x + attn_mod.attention(
-            p["attn"], L.rmsnorm(p["ln1"], x), positions,
-            num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
-            head_dim=c.resolved_head_dim, causal=True,
-            window=c.sliding_window, rope_theta=c.rope_theta,
-            q_block=c.attn_q_block, kv_block=c.attn_kv_block,
-            causal_skip=c.causal_block_skip,
-        )
-        h = h + moe_mod.moe_ffn(
-            p["moe"], L.rmsnorm(p["ln2"], h),
-            num_experts=c.num_experts, top_k=c.top_k,
-            capacity_factor=c.capacity_factor,
-        )
-        return h
+        x = L.rmsnorm(p["ln2"], h)
+        if c.family == "moe":
+            return moe_mod.moe_ffn(p["moe"], x, top_k=c.top_k, valid=valid)
+        return L.mlp(p["mlp"], x), None
 
     def _mamba_apply(self, p, x):
         c = self.config
@@ -245,33 +265,55 @@ class Model:
         )
 
     # ----------------------------------------------------------- forward
-    def _stack_apply(self, fn, x, stacked):
-        """Apply fn(layer_params, h) over stacked layers.
+    def _scan_layers(self, body, carry, xs):
+        """``body(j, carry, layer_xs) -> (carry, y)`` over the stacked
+        layers, ``j`` being the layer's static position in the attention
+        pattern's period; returns (carry, ys stacked over layers).
 
-        scan_layers=True: lax.scan (one-layer HLO, fast compile).
-        scan_layers=False: unrolled python loop — used by the dry-run so
-        XLA cost analysis sees every layer (a while body is counted once).
+        scan_layers=True: a ``lax.scan`` over layers (one-layer HLO, fast
+        compile), or over periods with the period's layers unrolled in
+        the body, so that each layer type has its own static window and
+        RoPE. scan_layers=False: an unrolled python loop — used by the
+        dry-run so XLA cost analysis sees every layer (a while body is
+        counted once).
         """
-        if self.config.scan_layers:
-            x, _ = jax.lax.scan(lambda h, p: (fn(p, h), None), x, stacked)
-            return x
-        for i in range(self.config.num_layers):
-            p = jax.tree.map(lambda t: t[i], stacked)
-            x = fn(p, x)
-        return x
+        c = self.config
+        n, per = c.num_layers, c.layer_period
+        stack = lambda ys: jax.tree.map(lambda *t: jnp.stack(t), *ys)
+        if not c.scan_layers:
+            ys = []
+            for i in range(n):
+                carry, y = body(i % per, carry, jax.tree.map(lambda t: t[i], xs))
+                ys.append(y)
+            return carry, stack(ys)
+        if per == 1:
+            return jax.lax.scan(functools.partial(body, 0), carry, xs)
+        if n % per:
+            raise ValueError(f"{n} layers are not whole periods of {per}")
+
+        def period(carry, xp):
+            ys = []
+            for j in range(per):
+                carry, y = body(j, carry, jax.tree.map(lambda t: t[j], xp))
+                ys.append(y)
+            return carry, stack(ys)
+
+        fold = lambda t: t.reshape(n // per, per, *t.shape[1:])
+        carry, ys = jax.lax.scan(period, carry, jax.tree.map(fold, xs))
+        return carry, jax.tree.map(lambda t: t.reshape(n, *t.shape[2:]), ys)
 
     def _backbone(self, params, x, positions):
         """(B, S, D) -> (B, S, D) through all blocks (train/prefill)."""
         c = self.config
 
-        if c.family in ("dense", "vlm"):
-            fn = lambda p, h: self._dense_apply(p, h, positions)
-            fn = jax.checkpoint(fn) if c.remat else fn
-            x = self._stack_apply(fn, x, params["blocks"])
-        elif c.family == "moe":
-            fn = lambda p, h: self._moe_apply(p, h, positions)
-            fn = jax.checkpoint(fn) if c.remat else fn
-            x = self._stack_apply(fn, x, params["blocks"])
+        if c.family in ("dense", "vlm", "moe"):
+            fns = [functools.partial(self._block_apply, positions=positions, j=j)
+                   for j in range(c.layer_period)]
+            if c.remat:
+                fns = [jax.checkpoint(fn) for fn in fns]
+            x, _ = self._scan_layers(
+                lambda j, h, p: (fns[j](p, h), None), x, params["blocks"]
+            )
         elif c.family == "hybrid":
             shared = params["shared_attn"]
             every = max(c.attn_every, 1)
@@ -279,7 +321,7 @@ class Model:
             def layer(p, h, i):
                 h = jax.lax.cond(
                     i % every == 0,
-                    lambda hh: self._dense_apply(shared, hh, positions),
+                    lambda hh: self._block_apply(shared, hh, positions),
                     lambda hh: hh,
                     h,
                 )
@@ -359,7 +401,9 @@ class Model:
             return h
 
         fn = jax.checkpoint(dec_apply) if c.remat else dec_apply
-        return self._stack_apply(fn, x, params["blocks"])
+        x, _ = self._scan_layers(lambda _j, h, p: (fn(p, h), None), x,
+                                 params["blocks"])
+        return x
 
     # ------------------------------------------------------------ logits
     def lm_logits(self, params, tokens, extras: dict | None = None):
@@ -391,7 +435,7 @@ class Model:
 
         norm = L.layernorm if c.family == "audio" else L.rmsnorm
         x = norm(params["final_norm"], x)
-        logits = L.unembed(params["embed"], x, DTYPES_LOGITS[c.logits_dtype])
+        logits = self._unembed(params, x)
         return self._mask_pad_logits(logits)
 
     # -------------------------------------------------------------- loss
@@ -420,14 +464,14 @@ class Model:
     def init_cache(self, batch: int, cache_len: int, extras: dict | None = None):
         """Decode state.
 
-        cache_len: KV capacity. Sliding-window models may pass
-        min(cache_len, window) to get the rolling cache.
+        cache_len: KV capacity. A model whose every layer is windowed gets
+        the rolling cache of min(cache_len, window).
         """
         c = self.config
         dt = c.cdtype
         hd = c.resolved_head_dim
-        if c.sliding_window is not None:
-            cache_len = min(cache_len, c.sliding_window)
+        if c.rolling_window is not None:
+            cache_len = min(cache_len, c.rolling_window)
 
         def kv(n_layers, length):
             if c.kv_quant:  # int8 + per-(token, head) f16 scales (§Perf)
@@ -503,48 +547,18 @@ class Model:
         hd = c.resolved_head_dim
         x = L.embed(params["embed"], tokens[:, None], c.cdtype)  # (B, 1, D)
 
-        def attn_decode(p, h, kv_slice):
+        def body(j, h, inp):
+            p, kv_slice = inp
             y, new = attn_mod.decode_attention(
                 p["attn"], L.rmsnorm(p["ln1"], h), kv_slice, pos,
                 num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
-                head_dim=hd, window=c.sliding_window, rope_theta=c.rope_theta,
+                head_dim=hd, rope_theta=c.rope_theta, **self._attn_kw(j),
             )
             h = h + y
-            return h, new
+            return h + self._ffn(p, h)[0], new
 
-        def _kv_stack_apply(body, h, blocks, kv):
-            """Scan-or-unroll a decode body carrying per-layer KV slices."""
-            if c.scan_layers:
-                return jax.lax.scan(body, h, (blocks, kv))
-            news = []
-            for i in range(c.num_layers):
-                inp = jax.tree.map(lambda t: t[i], (blocks, kv))
-                h, new = body(h, inp)
-                news.append(new)
-            stacked = jax.tree.map(lambda *ts: jnp.stack(ts), *news)
-            return h, stacked
-
-        if c.family in ("dense", "vlm"):
-            def body(h, inp):
-                p, kv_slice = inp
-                h, new = attn_decode(p, h, kv_slice)
-                h = h + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], h))
-                return h, new
-
-            x, new_kv = _kv_stack_apply(body, x, params["blocks"], cache["kv"])
-            cache = {**cache, "kv": new_kv}
-        elif c.family == "moe":
-            def body(h, inp):
-                p, kv_slice = inp
-                h, new = attn_decode(p, h, kv_slice)
-                h = h + moe_mod.moe_ffn(
-                    p["moe"], L.rmsnorm(p["ln2"], h),
-                    num_experts=c.num_experts, top_k=c.top_k,
-                    capacity_factor=c.capacity_factor,
-                )
-                return h, new
-
-            x, new_kv = _kv_stack_apply(body, x, params["blocks"], cache["kv"])
+        if c.family in ("dense", "vlm", "moe"):
+            x, new_kv = self._scan_layers(body, x, (params["blocks"], cache["kv"]))
             cache = {**cache, "kv": new_kv}
         elif c.family == "hybrid":
             shared = params["shared_attn"]
@@ -628,7 +642,7 @@ class Model:
             enc_out = cache["enc_out"]
             enc_pos = jnp.arange(enc_out.shape[1], dtype=jnp.int32)
 
-            def body(h, inp):
+            def body(_j, h, inp):
                 p, kv_slice = inp
                 y, new = attn_mod.decode_attention(
                     p["self_attn"], L.layernorm(p["ln1"], h), kv_slice, pos,
@@ -647,14 +661,14 @@ class Model:
                 h = h + L.mlp(p["mlp"], L.layernorm(p["ln2"], h))
                 return h, new
 
-            x, new_kv = _kv_stack_apply(body, x, params["blocks"], cache["kv"])
+            x, new_kv = self._scan_layers(body, x, (params["blocks"], cache["kv"]))
             cache = {**cache, "kv": new_kv}
         else:
             raise ValueError(c.family)
 
         norm = L.layernorm if c.family == "audio" else L.rmsnorm
         x = norm(params["final_norm"], x)
-        logits = L.unembed(params["embed"], x, DTYPES_LOGITS[c.logits_dtype])
+        logits = self._unembed(params, x)
         logits = self._mask_pad_logits(logits[:, 0])
         return logits, cache
 
@@ -678,11 +692,6 @@ class Model:
         if c.kv_quant:
             raise NotImplementedError(
                 "slot-resident decode does not support int8 KV caches yet"
-            )
-        if c.sliding_window is not None:
-            raise NotImplementedError(
-                "slot-resident decode allocates full-context caches; "
-                "sliding-window models are not supported yet"
             )
 
     def init_slot_cache(self, batch: int, cache_len: int):
@@ -733,53 +742,24 @@ class Model:
         x = L.embed(params["embed"], tokens, c.cdtype)
         positions = jnp.arange(s, dtype=jnp.int32)
 
-        def attn_with_kv(p, h):
+        def body(j, h, p):
             y, k, v = attn_mod.attention(
                 p["attn"], L.rmsnorm(p["ln1"], h), positions,
                 num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
-                head_dim=hd, causal=True, window=c.sliding_window,
-                rope_theta=c.rope_theta, q_block=c.attn_q_block,
-                kv_block=c.attn_kv_block, causal_skip=c.causal_block_skip,
-                return_kv=True,
+                head_dim=hd, causal=True, rope_theta=c.rope_theta,
+                q_block=c.attn_q_block, kv_block=c.attn_kv_block,
+                causal_skip=c.causal_block_skip, return_kv=True,
+                **self._attn_kw(j),
             )
-            return h + y, k, v
+            h = h + y
+            return h + self._ffn(p, h)[0], (k, v)
 
-        if c.family == "moe":
-            def block(p, h):
-                h, k, v = attn_with_kv(p, h)
-                h = h + moe_mod.moe_ffn(
-                    p["moe"], L.rmsnorm(p["ln2"], h),
-                    num_experts=c.num_experts, top_k=c.top_k,
-                    capacity_factor=c.capacity_factor,
-                )
-                return h, k, v
-        else:
-            def block(p, h):
-                h, k, v = attn_with_kv(p, h)
-                h = h + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], h))
-                return h, k, v
-
-        if c.scan_layers:
-            def body(h, p):
-                h, k, v = block(p, h)
-                return h, (k, v)
-
-            x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-        else:
-            k_list, v_list = [], []
-            for i in range(c.num_layers):
-                p = jax.tree.map(lambda t: t[i], params["blocks"])
-                x, k, v = block(p, x)
-                k_list.append(k)
-                v_list.append(v)
-            ks, vs = jnp.stack(k_list), jnp.stack(v_list)
+        x, (ks, vs) = self._scan_layers(body, x, params["blocks"])
 
         last = jnp.clip(length - 1, 0, s - 1).astype(jnp.int32)
         x_last = x[jnp.arange(b), last][:, None]  # (B, 1, D)
         x_last = L.rmsnorm(params["final_norm"], x_last)
-        logits = L.unembed(
-            params["embed"], x_last, DTYPES_LOGITS[c.logits_dtype]
-        )[:, 0]
+        logits = self._unembed(params, x_last)[:, 0]
         return self._mask_pad_logits(logits), ks, vs
 
     def decode_step_slots(self, params, cache, tokens, pos):
@@ -801,44 +781,21 @@ class Model:
         # one shared position map: every layer writes the same positions
         pos_map = kv["pos"].at[bidx, slot].set(pos)
 
-        def attn_decode(p, h, kv_slice):
+        def body(j, h, inp):
+            p, kv_slice = inp
             y, new = attn_mod.decode_attention_slots(
                 p["attn"], L.rmsnorm(p["ln1"], h), kv_slice, pos_map, pos,
                 slot, num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
-                head_dim=hd, rope_theta=c.rope_theta,
+                head_dim=hd, rope_theta=c.rope_theta, **self._attn_kw(j),
             )
-            return h + y, new
-
-        if c.family == "moe":
-            def body(h, inp):
-                p, kv_slice = inp
-                h, new = attn_decode(p, h, kv_slice)
-                h = h + moe_mod.moe_ffn(
-                    p["moe"], L.rmsnorm(p["ln2"], h),
-                    num_experts=c.num_experts, top_k=c.top_k,
-                    capacity_factor=c.capacity_factor,
-                )
-                return h, new
-        else:
-            def body(h, inp):
-                p, kv_slice = inp
-                h, new = attn_decode(p, h, kv_slice)
-                h = h + L.mlp(p["mlp"], L.rmsnorm(p["ln2"], h))
-                return h, new
+            h = h + y
+            return h + self._ffn(p, h)[0], new
 
         layer_kv = {"k": kv["k"], "v": kv["v"]}
-        if c.scan_layers:
-            x, new_kv = jax.lax.scan(body, x, (params["blocks"], layer_kv))
-        else:
-            news = []
-            for i in range(c.num_layers):
-                inp = jax.tree.map(lambda t: t[i], (params["blocks"], layer_kv))
-                x, new = body(x, inp)
-                news.append(new)
-            new_kv = jax.tree.map(lambda *ts: jnp.stack(ts), *news)
+        x, new_kv = self._scan_layers(body, x, (params["blocks"], layer_kv))
 
         x = L.rmsnorm(params["final_norm"], x)
-        logits = L.unembed(params["embed"], x, DTYPES_LOGITS[c.logits_dtype])
+        logits = self._unembed(params, x)
         return self._mask_pad_logits(logits[:, 0]), {
             "kv": {**new_kv, "pos": pos_map}
         }
@@ -859,56 +816,71 @@ class Model:
         there, so a frozen slot can never corrupt a block that was freed
         and reassigned. No position array: validity is derived from the
         per-dispatch block tables and positions (runtime arguments).
+
+        An expert model's state also carries ``"moe"``: int32 (2,), the
+        decode steps' token-choice pairs that landed on held experts and
+        the held experts they reached, summed over steps and layers
+        (``moe.moe_ffn``'s counts of the active slots).
         """
         c = self.config
         self._check_slot_support()
         hd = c.resolved_head_dim
         shape = (c.num_layers, num_blocks + 1, block_len, c.num_kv_heads, hd)
-        return {
+        cache = {
             "kv": {
                 "k": jnp.zeros(shape, c.cdtype),
                 "v": jnp.zeros(shape, c.cdtype),
             }
         }
-
-    def _paged_stack_apply(self, body, x, blocks, cache):
-        """Scan-or-unroll over layers carrying per-layer pool slices."""
-        layer_kv = {"k": cache["kv"]["k"], "v": cache["kv"]["v"]}
-        with jax.named_scope(SCOPE_LAYERS):
-            if self.config.scan_layers:
-                x, new_kv = jax.lax.scan(body, x, (blocks, layer_kv))
-            else:
-                news = []
-                for i in range(self.config.num_layers):
-                    inp = jax.tree.map(lambda t: t[i], (blocks, layer_kv))
-                    x, new = body(x, inp)
-                    news.append(new)
-                new_kv = jax.tree.map(lambda *ts: jnp.stack(ts), *news)
-        return x, {"kv": new_kv}
-
-    def _paged_block_body(self, attn_fn):
-        """Residual block body around a paged attention fn (dense/moe),
-        each half in its named scope (DESIGN.md §14)."""
-        c = self.config
         if c.family == "moe":
-            def ffn(p, h):
-                return moe_mod.moe_ffn(
-                    p["moe"], L.rmsnorm(p["ln2"], h),
-                    num_experts=c.num_experts, top_k=c.top_k,
-                    capacity_factor=c.capacity_factor,
-                )
-        else:
-            def ffn(p, h):
-                return L.mlp(p["mlp"], L.rmsnorm(p["ln2"], h))
+            cache["moe"] = jnp.zeros((2,), jnp.int32)
+        return cache
 
-        def body(h, inp):
-            p, kv_slice = inp
-            with jax.named_scope(SCOPE_ATTENTION):
-                h, new = attn_fn(p, h, kv_slice)
-            with jax.named_scope(SCOPE_MLP):
-                h = h + ffn(p, h)
-            return h, new
-        return body
+    def _paged_layers(self, attn_fn, x, blocks, cache, valid=None):
+        """The layers over the pool: ``attn_fn(j, p, h, kv_slice) -> (h,
+        new kv_slice)`` then the FFN, each half in its named scope
+        (DESIGN.md §14), a window layer's attention in its own. Returns
+        (x, new cache); ``valid`` rows' expert counts are added to the
+        cache's ``"moe"``, which is otherwise passed on.
+
+        An expert layer's weights stay stacked over layers and are sliced
+        inside ``model/moe``: the scan's own slicing of its inputs would
+        copy every held expert of the layer (of the period, and again of
+        the layer, under a period scan) in ``model/layers``, away from the
+        layer's time."""
+        c = self.config
+        moe = c.family == "moe"
+        layer_kv = {"k": cache["kv"]["k"], "v": cache["kv"]["v"]}
+        xs = (blocks, layer_kv)
+        if moe:
+            experts = blocks["moe"]
+            xs = ({k: v for k, v in blocks.items() if k != "moe"}, layer_kv,
+                  jnp.arange(c.num_layers, dtype=jnp.int32))
+
+        def body(j, h, inp):
+            p, kv_slice, *layer = inp
+            window = self._attn_kw(j)["window"]
+            scope = SCOPE_ATTENTION if window is None else SCOPE_WINDOW_ATTENTION
+            with jax.named_scope(scope):
+                h, new = attn_fn(j, p, h, kv_slice)
+            with jax.named_scope(SCOPE_MOE if moe else SCOPE_MLP):
+                if moe:
+                    p = {**p, "moe": jax.tree.map(
+                        lambda t: jax.lax.dynamic_index_in_dim(
+                            t, layer[0], keepdims=False), experts)}
+                y, counts = self._ffn(p, h, valid)
+                h = h + y
+            return h, new if counts is None else (new, counts)
+
+        with jax.named_scope(SCOPE_LAYERS):
+            x, out = self._scan_layers(body, x, xs)
+        if not moe:
+            return x, {"kv": out}
+        new_kv, counts = out
+        total = cache["moe"]
+        if valid is not None:
+            total = total + jnp.sum(counts, axis=0, dtype=jnp.int32)
+        return x, {"kv": new_kv, "moe": total}
 
     def decode_step_paged(self, params, cache, tokens, pos, table, active,
                           *, use_kernel: bool = False):
@@ -928,22 +900,20 @@ class Model:
         table = jnp.asarray(table, jnp.int32)
         active = jnp.asarray(active, bool)
 
-        def attn_fn(p, h, kv_slice):
+        def attn_fn(j, p, h, kv_slice):
             y, new = attn_mod.decode_attention_paged(
                 p["attn"], L.rmsnorm(p["ln1"], h), kv_slice, table, pos,
                 active, num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
                 head_dim=hd, rope_theta=c.rope_theta, use_kernel=use_kernel,
+                **self._attn_kw(j),
             )
             return h + y, new
 
-        x, new_cache = self._paged_stack_apply(
-            self._paged_block_body(attn_fn), x, params["blocks"], cache
-        )
+        x, new_cache = self._paged_layers(attn_fn, x, params["blocks"], cache,
+                                          valid=active[:, None])
         with jax.named_scope(SCOPE_UNEMBED):
             x = L.rmsnorm(params["final_norm"], x)
-            logits = L.unembed(
-                params["embed"], x, DTYPES_LOGITS[c.logits_dtype]
-            )
+            logits = self._unembed(params, x)
             return self._mask_pad_logits(logits[:, 0]), new_cache
 
     def prefill_paged(self, params, cache, tokens, start, chunk_len, table):
@@ -954,7 +924,8 @@ class Model:
         (right-padded; rows with ``chunk_len == 0`` are slots not
         prefilling this round). KV for the chunk is scattered into the
         slot's pool blocks, every query attends the slot's full gathered
-        history (earlier chunks included), and the returned logits are
+        history (earlier chunks included; a window layer's, the last
+        ``window`` positions of it), and the returned logits are
         taken at each row's last real chunk position — for the chunk
         that COMPLETES a prompt these are the request's pending first-
         decode logits, exactly like the dense splice. Returns
@@ -969,25 +940,21 @@ class Model:
         chunk_len = jnp.asarray(chunk_len, jnp.int32)
         table = jnp.asarray(table, jnp.int32)
 
-        def attn_fn(p, h, kv_slice):
+        def attn_fn(j, p, h, kv_slice):
             y, new = attn_mod.prefill_attention_paged(
                 p["attn"], L.rmsnorm(p["ln1"], h), kv_slice, table, start,
                 chunk_len, num_heads=c.num_heads,
                 num_kv_heads=c.num_kv_heads, head_dim=hd,
-                rope_theta=c.rope_theta,
+                rope_theta=c.rope_theta, **self._attn_kw(j),
             )
             return h + y, new
 
-        x, new_cache = self._paged_stack_apply(
-            self._paged_block_body(attn_fn), x, params["blocks"], cache
-        )
+        x, new_cache = self._paged_layers(attn_fn, x, params["blocks"], cache)
         with jax.named_scope(SCOPE_UNEMBED):
             last = jnp.clip(chunk_len - 1, 0, cc - 1)
             x_last = x[jnp.arange(b), last][:, None]  # (S, 1, D)
             x_last = L.rmsnorm(params["final_norm"], x_last)
-            logits = L.unembed(
-                params["embed"], x_last, DTYPES_LOGITS[c.logits_dtype]
-            )[:, 0]
+            logits = self._unembed(params, x_last)[:, 0]
             return self._mask_pad_logits(logits), new_cache
 
     # --------------------------------------------------------- analytics
@@ -998,13 +965,14 @@ class Model:
         return sum(int(np.prod(t.shape)) for t in jax.tree.leaves(shapes))
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: top_k of num_experts FFNs)."""
+        """Params touched per token (MoE: on average top_k of the router's
+        experts, of which these held ones are a share)."""
         total = self.param_count()
         c = self.config
         if c.family != "moe" or not c.num_experts:
             return total
         expert_p = 3 * c.d_model * c.d_ff * c.num_experts * c.num_layers
-        active = expert_p * c.top_k / c.num_experts
+        active = expert_p * c.top_k / c.router_width
         return int(total - expert_p + active)
 
 

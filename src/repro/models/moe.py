@@ -1,95 +1,90 @@
-"""Mixture-of-Experts FFN: top-k routing, sort-based capacity dispatch.
+"""Mixture-of-Experts FFN: a router over every expert, the held experts'
+part of the result, no token ever dropped.
 
-Design notes (TPU/XLA):
-* Dispatch is sort-based (GShard-style one-hot (T, E, C) tensors would be
-  O(T*E*C) memory — hopeless at 32k sequences). Tokens*slots are sorted
-  by expert id and scattered into an (E, C) buffer with
-  ``C = ceil(T*K/E * capacity_factor)``; overflow tokens are dropped
-  (standard capacity dropping) and their combine weight is zero.
-* Expert weights are stacked (E, ...) so the expert dimension shards on
-  the ``model`` mesh axis (expert parallelism). XLA inserts the
-  all-to-all-equivalent collectives at the einsum boundaries.
-* FLOPs scale with T*K*cf (active experts), not T*E — keeps the
-  roofline's MODEL_FLOPS/HLO_FLOPs ratio honest.
+Expert parallelism divides a layer's experts over chips; this layer is
+one chip's part. It holds experts ``0 .. E_held - 1`` of the router's
+``R`` (``E_held == R`` when every expert is held):
+
+* routing: float32 softmax over all ``R`` router logits, top-k, and the
+  k chosen probabilities renormalised to sum to 1;
+* the output is the sum, over a token's chosen experts that are held
+  here, of gate x SwiGLU_e(x). What the other chips' experts add is left
+  out, as it would arrive from them.
+
+Dispatch is by sort, not capacity: the T*k token-choice pairs are sorted
+by expert (pairs for experts held elsewhere last), and each held expert
+multiplies exactly its own rows in one grouped matmul per projection
+(``jax.lax.ragged_dot``). Every pair gets its row, so a token's output
+never depends on what other tokens route, and FLOPs scale with the
+pairs that land here, not with T*E.
+
+Expert weights are stacked (E_held, ...) so the expert dimension shards
+on the ``model`` mesh axis.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.models.layers import _dense_init
 
 
-def init_moe(key, d_model, d_ff, num_experts, dtype):
+def init_moe(key, d_model, d_ff, num_experts, dtype, router_width=None):
+    """``num_experts`` held experts behind a router of ``router_width``
+    outputs (default: every expert held)."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
     return {
-        "w_router": _dense_init(k1, (d_model, num_experts), jnp.float32),
+        "w_router": _dense_init(k1, (d_model, router_width or num_experts), dtype),
         "w_gate": _dense_init(k2, (num_experts, d_model, d_ff), dtype),
         "w_up": _dense_init(k3, (num_experts, d_model, d_ff), dtype),
         "w_down": _dense_init(k4, (num_experts, d_ff, d_model), dtype),
     }
 
 
-def moe_ffn(params, x, *, num_experts, top_k, capacity_factor=1.25):
-    """x: (B, S, D) -> (B, S, D). Static shapes throughout."""
+def route(params, xf, top_k):
+    """(T, D) -> gates (T, k) float32, experts (T, k) int32."""
+    logits = jnp.dot(xf, params["w_router"].astype(xf.dtype),
+                     preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, top_k)
+    return gates / jnp.sum(gates, axis=-1, keepdims=True), experts
+
+
+def moe_ffn(params, x, *, top_k, valid=None):
+    """x: (B, S, D) -> ((B, S, D), counts).
+
+    ``counts`` (int32 (2,)): the token-choice pairs that landed on held
+    experts, and the held experts that received at least one, over the
+    rows where ``valid`` (B, S) is true (every row by default)."""
     b, s, d = x.shape
     t = b * s
-    e = num_experts
-    k = top_k
+    held = params["w_gate"].shape[0]
     xf = x.reshape(t, d)
+    gates, experts = route(params, xf, top_k)
 
-    # --- routing ---
-    logits = (xf.astype(jnp.float32) @ params["w_router"]).astype(jnp.float32)
-    gate_vals, expert_idx = jax.lax.top_k(logits, k)  # (T, K)
-    gates = jax.nn.softmax(gate_vals, axis=-1)  # renormalized over selected
+    e_flat = experts.reshape(-1)  # (T*k,), token-major
+    mine = e_flat < held
+    group = jnp.where(mine, e_flat, held)  # held elsewhere: sorted last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+    tok = order // top_k
+    rows = xf[tok]  # (T*k, D), grouped by expert
 
-    # --- capacity-bounded placement ---
-    cap = int(np.ceil(t * k / e * capacity_factor))
-    e_flat = expert_idx.reshape(-1)  # (T*K,)
-    tok_flat = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
-    gate_flat = gates.reshape(-1)
-    order = jnp.argsort(e_flat)  # stable: ties keep token order
-    e_sorted = e_flat[order]
-    tok_sorted = tok_flat[order]
-    gate_sorted = gate_flat[order]
-    # rank of each entry within its expert bucket
-    start_of = jnp.searchsorted(e_sorted, jnp.arange(e), side="left")
-    rank = jnp.arange(t * k, dtype=jnp.int32) - start_of[e_sorted]
-    keep = rank < cap
-    slot = jnp.where(keep, e_sorted * cap + rank, e * cap)  # overflow -> trash row
+    def grouped(lhs, w):
+        return jax.lax.ragged_dot(lhs, w.astype(lhs.dtype), sizes)
 
-    # gather tokens into (E*C + 1, D) buffer (last row = trash)
-    buf = jnp.zeros((e * cap + 1, d), dtype=x.dtype)
-    buf = buf.at[slot].set(xf[tok_sorted], mode="drop", unique_indices=True)
-    expert_in = buf[: e * cap].reshape(e, cap, d)
+    h = jax.nn.silu(grouped(rows, params["w_gate"])) * grouped(rows, params["w_up"])
+    y = grouped(h, params["w_down"])  # (T*k, D)
+    w = jnp.where(mine[order], gates.reshape(-1)[order], 0.0)
+    # rows of pairs held elsewhere lie past the groups: masked, never read
+    contrib = jnp.where(mine[order][:, None], y.astype(jnp.float32) * w[:, None], 0.0)
+    out = jnp.zeros((t, d), jnp.float32).at[tok].add(contrib)
 
-    # --- expert computation (SwiGLU) ---
-    g = jax.nn.silu(
-        jnp.einsum("ecd,edf->ecf", expert_in, params["w_gate"].astype(x.dtype))
-    )
-    u = jnp.einsum("ecd,edf->ecf", expert_in, params["w_up"].astype(x.dtype))
-    h = jnp.einsum("ecf,efd->ecd", g * u, params["w_down"].astype(x.dtype))
-    h = h.reshape(e * cap, d)
-
-    # --- combine back to tokens, weighted by gates ---
-    vals = jnp.where(keep, gate_sorted, 0.0).astype(x.dtype)[:, None] * h[
-        jnp.minimum(slot, e * cap - 1)
-    ]
-    out = jnp.zeros((t, d), dtype=x.dtype).at[tok_sorted].add(
-        jnp.where(keep[:, None], vals, 0), mode="drop"
-    )
-    return out.reshape(b, s, d)
-
-
-def aux_load_balance_loss(params, x, *, num_experts, top_k):
-    """Switch-style auxiliary loss: E * sum_e f_e * p_e (optional)."""
-    b, s, d = x.shape
-    xf = x.reshape(-1, d)
-    logits = (xf.astype(jnp.float32) @ params["w_router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    _, expert_idx = jax.lax.top_k(logits, top_k)
-    onehot = jax.nn.one_hot(expert_idx, num_experts, dtype=jnp.float32)
-    frac = jnp.mean(jnp.sum(onehot, axis=1), axis=0)  # tokens per expert
-    prob = jnp.mean(probs, axis=0)
-    return num_experts * jnp.sum(frac * prob) / top_k
+    counted = mine
+    if valid is not None:
+        counted &= jnp.repeat(jnp.asarray(valid, bool).reshape(t), top_k)
+    hits = jnp.zeros((held + 1,), jnp.int32).at[
+        jnp.where(counted, e_flat, held)].add(1)[:held]
+    counts = jnp.stack([jnp.sum(counted, dtype=jnp.int32),
+                        jnp.sum(hits > 0, dtype=jnp.int32)])
+    return out.astype(x.dtype).reshape(b, s, d), counts

@@ -30,7 +30,7 @@ its top-level spans: ``serve_setup``, then per round ``admit`` |
 (the host decode path) | ``replan`` | ``bucket_switch`` |
 ``adapt_update``.
 
-Device scopes (``SCOPES``): the serve program wraps its pieces in
+Device scopes (``SCOPES``, ``LAYER_SCOPES``): the serve program wraps its pieces in
 ``jax.named_scope``, which changes only the compiled instructions'
 ``op_name`` metadata. A device profile names ops by instruction alone,
 so ``scope_map`` reads the compiled program's text into an instruction
@@ -47,7 +47,7 @@ from typing import NamedTuple
 import jax
 
 __all__ = ["Span", "SpanTracer", "NullTracer", "NULL_TRACER", "SCOPES",
-           "scope_of", "scope_map"]
+           "LAYER_SCOPES", "scope_of", "scope_map"]
 
 #: the prompt-chunk splice (the ``lax.cond`` branch of the serve program)
 SCOPE_PREFILL = "prefill"
@@ -56,8 +56,13 @@ SCOPE_PREFILL = "prefill"
 SCOPE_LAYERS = "model/layers"
 #: per layer: KV write into the pool, the gather, and the attend
 SCOPE_ATTENTION = "model/attention"
-#: per layer: the MLP (or expert FFN) and its norm
+#: the same, in a layer that attends through a window
+SCOPE_WINDOW_ATTENTION = "model/window_attention"
+#: per layer: the MLP and its norm
 SCOPE_MLP = "model/mlp"
+#: per layer: the routed expert layer and its norm (the slice of the
+#: layer's stacked expert weights, router, sort, grouped matmuls, combine)
+SCOPE_MOE = "model/moe"
 #: final norm and the logits matmul
 SCOPE_UNEMBED = "model/unembed"
 #: ``CodedLMHead.encode_logits``: the (nb x kb) float32 block mix
@@ -68,11 +73,15 @@ SCOPE_FINISH_MASK = "coded_head/finish_mask"
 SCOPE_SOLVE = "coded_head/solve"
 #: the fallback ``where`` and the ``argmax`` that picks each token
 SCOPE_SAMPLE = "sample"
+#: the scopes of a dense, full-attention model's serve program
 SCOPES = (SCOPE_PREFILL, SCOPE_LAYERS, SCOPE_ATTENTION, SCOPE_MLP,
           SCOPE_UNEMBED, SCOPE_MIX, SCOPE_FINISH_MASK, SCOPE_SOLVE,
           SCOPE_SAMPLE)
+#: named as well by a model with window layers or routed experts (whose
+#: layers name ``model/moe`` in place of ``model/mlp``)
+LAYER_SCOPES = (SCOPE_WINDOW_ATTENTION, SCOPE_MOE)
 
-_SCOPE_SEGMENTS = tuple((s, tuple(s.split("/"))) for s in SCOPES)
+_SCOPE_SEGMENTS = tuple((s, tuple(s.split("/"))) for s in SCOPES + LAYER_SCOPES)
 #: one instruction of an HLO module's text: its name and the rest
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?(%?[^\s=]+)\s*=\s*(.*)$", re.M)
 _OP_NAME = re.compile(r'\bop_name="([^"]*)"')
@@ -82,7 +91,8 @@ _OPERAND = re.compile(r"%[^\s,()]+")
 
 
 def scope_of(op_name: str) -> str | None:
-    """The innermost of ``SCOPES`` on an op's name stack, or None.
+    """The innermost of ``SCOPES`` or ``LAYER_SCOPES`` on an op's name
+    stack, or None.
     Matches whole segments, so ``jit(prefill)`` is not ``prefill``."""
     parts = op_name.split("/")
     found = None

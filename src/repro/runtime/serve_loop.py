@@ -1,7 +1,7 @@
 """Serving loop with the paper's coded matvec as the LM-head path.
 
 Decode-time logits are exactly the paper's workload: ``logits = E h``
-with ``E in R^{V x D}`` (the tied embedding) and one ``h in R^D`` per
+with ``E in R^{V x D}`` (the tied embedding or an untied output head) and one ``h in R^D`` per
 sequence — a matrix-vector product whose rows can be MDS-coded and
 spread over heterogeneous workers.
 
@@ -340,6 +340,11 @@ class ServeReport:
     #: decode rounds whose coded head erased no systematic block, so the
     #: erasure decode passed them through with no solve
     passthrough_rounds: int = 0
+    #: paged runs of an expert model: the decode steps' token-choice pairs
+    #: of active slots that landed on the experts held here, and over
+    #: steps and layers the held experts that received at least one
+    held_expert_pairs: int = 0
+    held_experts_hit: int = 0
     #: traced paged runs: chunk size -> {instruction: scope} of each
     #: compiled program the run dispatched (``obs.trace.scope_map``), so
     #: a device profile's ops can be put down to the program's scopes
@@ -360,14 +365,17 @@ class ServeReport:
         return float(np.percentile(lat, q)) if lat.size else float("nan")
 
 
-def _count_rounds(decode_flags) -> tuple[int, int]:
-    """(rounds with ``ok`` False, rounds passed through) over a run's
-    per-dispatch ``(ok, passthrough)`` flag arrays, read back in one
-    ``device_get`` after the run: dispatches never wait on the host, and
-    no new sequence of chunk sizes compiles anything."""
-    flags = jax.device_get(decode_flags)
+def _count_rounds(decode_flags, moe=None) -> tuple[int, int, int, int]:
+    """(rounds with ``ok`` False, rounds passed through, held-expert
+    pairs, held experts hit) over a run's per-dispatch ``(ok,
+    passthrough)`` flag arrays and the final decode state's ``"moe"``
+    counts (None: no experts), read back in one ``device_get`` after the
+    run: dispatches never wait on the host, and no new sequence of chunk
+    sizes compiles anything."""
+    flags, moe = jax.device_get((decode_flags, moe))
+    pairs, hit = (0, 0) if moe is None else map(int, moe)
     return (sum(int(np.count_nonzero(~ok)) for ok, _ in flags),
-            sum(int(np.count_nonzero(p)) for _, p in flags))
+            sum(int(np.count_nonzero(p)) for _, p in flags), pairs, hit)
 
 
 class Server:
@@ -393,7 +401,7 @@ class Server:
         self.cfg = cfg or ServeConfig()
         self.coded_head = (
             CodedLMHead(
-                params["embed"]["table"], cluster,
+                model.head_table(params), cluster,
                 block_rows=self.cfg.block_rows,
                 scheme=self.cfg.scheme,
                 deadline_safety=self.cfg.deadline_safety,
@@ -510,12 +518,13 @@ class Server:
     # ------------------------------------------------------- jit pipeline
     def _can_batch_prefill(self) -> bool:
         """True when ``Model.prefill`` covers this model (same support
-        envelope as the slot/paged paths)."""
+        envelope as the slot/paged paths) and the decode cache it splices
+        into holds whole prompts (it does not roll)."""
         c = self.model.config
         return (
             c.family in ("dense", "vlm", "moe")
             and not c.kv_quant
-            and c.sliding_window is None
+            and c.rolling_window is None
         )
 
     def _prefill_into_cache(self, params, cache, prompts):
@@ -1081,7 +1090,7 @@ class Server:
         with tracer.span("finish"):
             jax.block_until_ready(logits)
             wall = time.perf_counter() - t0
-            fallbacks, passthroughs = _count_rounds(decode_flags)
+            fallbacks, passthroughs, _, _ = _count_rounds(decode_flags)
             report = ServeReport(
                 finished=tuple(sched.finished),
                 tokens=sum(
@@ -1317,7 +1326,8 @@ class Server:
         with tracer.span("finish"):
             jax.block_until_ready(logits)
             wall = time.perf_counter() - t0
-            fallbacks, passthroughs = _count_rounds(decode_flags)
+            fallbacks, passthroughs, pairs, hit = _count_rounds(
+                decode_flags, cache.get("moe"))
             scopes = None
             if tracer.enabled and args is not None:
                 # the donated buffers of the last dispatch are gone; its
@@ -1340,6 +1350,8 @@ class Server:
                 wall_s=wall,
                 fallback_rounds=fallbacks,
                 passthrough_rounds=passthroughs,
+                held_expert_pairs=pairs,
+                held_experts_hit=hit,
                 scopes=scopes,
             )
             metrics.emit(telemetry, phase="serve", rounds=float(now))

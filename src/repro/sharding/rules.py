@@ -27,8 +27,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # (path regex, spec WITHOUT the stacked-layer dim). Longest match wins.
 _RULES: tuple[tuple[str, tuple], ...] = (
-    # embeddings / lm head (tied)
+    # embeddings / lm head (tied), untied lm head (D, V)
     (r"embed/table$", ("model", "data")),
+    (r"lm_head/w$", ("data", "model")),
     # attention
     (r"(attn|self_attn|cross_attn)/wq$", ("data", "model")),
     (r"(attn|self_attn|cross_attn)/wk$", ("data", "model")),

@@ -13,7 +13,15 @@ from repro.configs import ARCHS
 from repro.core import ClusterSpec
 from repro.launch import serve as serve_cli
 from repro.models.model import Model
-from repro.obs.trace import NULL_TRACER, SCOPES, SpanTracer
+from repro.obs.trace import (
+    LAYER_SCOPES,
+    NULL_TRACER,
+    SCOPE_MLP,
+    SCOPE_MOE,
+    SCOPE_WINDOW_ATTENTION,
+    SCOPES,
+    SpanTracer,
+)
 from repro.runtime.serve_loop import ServeConfig, Server
 from repro.serve import Request, make_workload
 
@@ -59,7 +67,23 @@ class _Events:
         return False
 
 
-def test_every_scope_names_ops_of_the_compiled_paged_program(server):
+@pytest.fixture(scope="module")
+def expert_server():
+    """Window and full layers, routed experts, an untied head."""
+    c = ARCHS["mellum2-12b-a2.5b"].reduced()
+    m = Model(c)
+    return Server(m, m.init_params(KEY), ClusterSpec.make([2, 2], [4.0, 0.8]),
+                  ServeConfig(block_rows=64))
+
+
+#: the scopes each served model's blocks leave out
+ABSENT = {"server": {SCOPE_WINDOW_ATTENTION, SCOPE_MOE},
+          "expert_server": {SCOPE_MLP}}
+
+
+@pytest.mark.parametrize("which", sorted(ABSENT))
+def test_every_scope_names_ops_of_the_compiled_paged_program(which, request):
+    server = request.getfixturevalue(which)
     vocab = server.model.config.vocab_size
     trace = _trace(vocab, [3, 5, 2, 4])
     untraced = server.serve(trace, slots=2, decode_block=2)
@@ -71,7 +95,7 @@ def test_every_scope_names_ops_of_the_compiled_paged_program(server):
     assert ev.n == 0 and server.serve_traces == traces
     assert rep.scopes and all(rep.scopes.values())
     named = set().union(*(m.values() for m in rep.scopes.values()))
-    assert named == set(SCOPES)
+    assert named == set(SCOPES + LAYER_SCOPES) - ABSENT[which]
     # instruction names as a device profile gives them
     assert all(k.startswith("%") for m in rep.scopes.values() for k in m)
 
