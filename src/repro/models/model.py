@@ -804,9 +804,10 @@ class Model:
     # Paged serving (DESIGN.md §13): physical KV memory is a fixed pool
     # of (block_len,)-token blocks shared across slots, and each slot
     # maps logical positions to pool blocks through a block table. The
-    # program's shapes depend only on (num_blocks, block_len, S) — never
-    # on any request's length — so admitting an arbitrarily long prompt
-    # (prefilled chunk-by-chunk across admit rounds) retraces nothing.
+    # program's shapes depend only on (num_blocks, block_len, S) and the
+    # table's width — never on a prompt's length — so admitting a long
+    # prompt (prefilled chunk-by-chunk across admit rounds) within the
+    # width retraces nothing.
 
     def init_paged_cache(self, num_blocks: int, block_len: int):
         """KV block pool for ``decode_step_paged``/``prefill_paged``.

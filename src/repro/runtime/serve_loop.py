@@ -345,6 +345,11 @@ class ServeReport:
     #: steps and layers the held experts that received at least one
     held_expert_pairs: int = 0
     held_experts_hit: int = 0
+    #: paged runs: the block-table width every dispatch of the call ran
+    #: with (its largest reservation, capped at the pool), and the pool's
+    #: size in blocks; None on dense runs
+    table_blocks: int | None = None
+    pool_blocks: int | None = None
     #: traced paged runs: chunk size -> {instruction: scope} of each
     #: compiled program the run dispatched (``obs.trace.scope_map``), so
     #: a device profile's ops can be put down to the program's scopes
@@ -771,10 +776,13 @@ class Server:
         """One fused PAGED serve iteration: prefill chunk + decode chunk.
 
         The paged twin of ``_serve_step_program`` (DESIGN.md §13). Shapes
-        depend only on ``(num_blocks, block_len, S)`` and the prefill
-        chunk width — never on any request's prompt length — so admitting
-        a 4x-longer prompt retraces nothing: it just runs more admit
-        rounds of the SAME program.
+        depend only on ``(num_blocks, block_len, S)``, the block-table
+        width (the call's largest reservation, capped at the pool) and
+        the prefill chunk width — never on a prompt length as such — so
+        within a call admitting a 4x-longer prompt retraces nothing: it
+        just runs more admit rounds of the SAME program. A later call
+        whose largest reservation differs compiles one program for its
+        width.
 
         **Prefill chunk** (``lax.cond``-gated): ``chunk_tokens`` is the
         (S, C) batch of this round's prompt chunks, row s covering
@@ -1119,8 +1127,12 @@ class Server:
         ``BlockPool`` (full reservation at admission, freed at
         retirement); prompts prefill in ``chunk``-token pieces across
         admit rounds, so one compiled program per decode-chunk size
-        covers EVERY prompt length; and rounds where every busy slot is
-        still mid-prompt dispatch a prefill-only pass (``steps=0``).
+        covers EVERY prompt length of the call; and rounds where every
+        busy slot is still mid-prompt dispatch a prefill-only pass
+        (``steps=0``). The block tables are as wide as the call's largest
+        reservation (capped at the pool), so a decode step gathers that
+        many blocks a slot; a call with another largest reservation
+        compiles its own programs.
 
         Its top-level spans (§14) tile the call: ``serve_setup`` (held
         open in ``setup`` until the pool and initial arrays exist), then
@@ -1134,15 +1146,16 @@ class Server:
                     else self.cfg.prefill_chunk if self.cfg.prefill_chunk
                     is not None else prompt_cap)
         bl = int(block_len if block_len is not None else self.cfg.block_len)
+        # the call's largest reservation (``SlotScheduler.blocks_needed``)
+        largest = max(
+            -(-(r.prompt_len + r.out_len + 1) // bl) for r in trace
+        )
         nb = num_blocks if num_blocks is not None else self.cfg.num_blocks
         if nb is None:
             # dense-equivalent capacity: every slot can hold the trace's
             # largest request, so the pool never sheds — sizing DOWN from
             # this is the knob that turns on memory admission control
-            per_req = max(
-                -(-(r.prompt_len + r.out_len + 1) // bl) for r in trace
-            )
-            nb = slots * per_req
+            nb = slots * largest
         nb = int(nb)
         cache = self.model.init_paged_cache(nb, bl)
         kv = cache["kv"]
@@ -1176,10 +1189,12 @@ class Server:
             (slots, padded_vocab(self.model.config.vocab_size)), jnp.float32
         )
         pos = jnp.zeros((slots,), jnp.int32)
-        # host mirror of the device block tables, width = pool size (a
-        # slot can never hold more than every block): shapes depend only
-        # on (num_blocks, block_len, S)
-        table_np = np.full((slots, nb), -1, np.int32)
+        # host mirror of the device block tables, as wide as the call's
+        # largest reservation (no slot ever holds more; a reservation
+        # past the pool is shed), so every decode step gathers, scores
+        # and masks that many blocks a slot, not the whole pool
+        width = min(nb, largest)
+        table_np = np.full((slots, width), -1, np.int32)
         no_chunk = jnp.zeros((slots, chunk), jnp.int32)
         no_i32 = jnp.zeros((slots,), jnp.int32)
         no_bool = jnp.zeros((slots,), bool)
@@ -1352,6 +1367,8 @@ class Server:
                 passthrough_rounds=passthroughs,
                 held_expert_pairs=pairs,
                 held_experts_hit=hit,
+                table_blocks=width,
+                pool_blocks=nb,
                 scopes=scopes,
             )
             metrics.emit(telemetry, phase="serve", rounds=float(now))

@@ -78,6 +78,33 @@ def test_paged_chunk_attend_matches_ref():
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("window", [None, 6])
+def test_narrow_table_attends_like_the_pool_wide_table(window):
+    """A table as wide as the largest reservation attends like the same
+    table padded with unmapped (-1) entries to the whole pool: the
+    entries it drops were masked to zero weight."""
+    rng = np.random.default_rng(5)
+    q, k_pool, v_pool, narrow, pos = _rand_paged(5, nb=12)
+    narrow = narrow[:, :3]
+    wide = np.full((narrow.shape[0], 12), -1, np.int32)
+    wide[:, :3] = narrow
+    args = lambda t: (jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(t))
+    got = pa.paged_decode_attend(jnp.asarray(q), *args(narrow),
+                                 jnp.asarray(pos), window)
+    want = pa.paged_decode_attend(jnp.asarray(q), *args(wide),
+                                  jnp.asarray(pos), window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    c = 3
+    qc = rng.standard_normal((narrow.shape[0], c, 2, 2, 8)).astype(np.float32)
+    q_pos = jnp.asarray(np.array([2, 6, 0], np.int32)[:, None]
+                        + np.arange(c, dtype=np.int32)[None, :])
+    got = pa.paged_chunk_attend(jnp.asarray(qc), *args(narrow), q_pos, window)
+    want = pa.paged_chunk_attend(jnp.asarray(qc), *args(wide), q_pos, window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_scatter_routes_inactive_rows_to_sink():
     """Frozen/padded rows must write ONLY the sink block — a freed block
     reassigned to another stream can never be corrupted by them."""
@@ -213,23 +240,33 @@ def test_paged_serve_matches_dense_across_erasure_grid(safety, seed):
     key = jax.random.PRNGKey(seed)
     rep_d = server.serve(trace, slots=2, decode_block=2, paged=False, key=key)
     rep_p = server.serve(trace, slots=2, decode_block=2, paged=True, key=key)
-    assert rep_p.tokens == rep_d.tokens
-    assert rep_p.rounds == rep_d.rounds
-    assert rep_p.decode_rounds == rep_d.decode_rounds
-    assert rep_p.admitted == rep_d.admitted and rep_p.shed == rep_d.shed
+    # a pool three times the default: the tables stay as wide as the
+    # largest reservation, and the run is the same
+    rep_w = server.serve(trace, slots=2, decode_block=2, paged=True, key=key,
+                         num_blocks=3 * rep_p.pool_blocks)
+    assert rep_w.table_blocks == rep_p.table_blocks
+    assert rep_d.table_blocks is None and rep_d.pool_blocks is None
     done_d = {f.request.rid: f for f in rep_d.finished if f.outcome == "done"}
-    done_p = {f.request.rid: f for f in rep_p.finished if f.outcome == "done"}
-    assert done_d.keys() == done_p.keys()
-    for rid, f in done_d.items():
-        assert done_p[rid].finish_round == f.finish_round
-        assert done_p[rid].tokens == f.tokens
+    assert done_d
+    for rep in (rep_p, rep_w):
+        assert rep.tokens == rep_d.tokens
+        assert rep.rounds == rep_d.rounds
+        assert rep.decode_rounds == rep_d.decode_rounds
+        assert rep.admitted == rep_d.admitted and rep.shed == rep_d.shed
+        done = {f.request.rid: f for f in rep.finished if f.outcome == "done"}
+        assert done_d.keys() == done.keys()
+        for rid, f in done_d.items():
+            assert done[rid].finish_round == f.finish_round
+            assert done[rid].tokens == f.tokens
 
 
 # ------------------------------------------------ serve(): retrace pinning
 def test_paged_serve_one_trace_across_8x_prompt_spread():
     """Prompt lengths spread 8x within and across traces: ONE compiled
-    program total (decode_block=1 => a single steps variant). Shapes
-    depend only on (num_blocks, block_len, S) — never a prompt length."""
+    program total (decode_block=1 => a single steps variant). Both
+    traces' largest reservation is 9 blocks, so both calls' tables are
+    9 wide: shapes depend only on (num_blocks, block_len, S) and that
+    width — never a prompt length as such."""
     c = ARCHS["qwen3-0.6b"].reduced()
     m = Model(c)
     params = m.init_params(KEY)
@@ -249,6 +286,47 @@ def test_paged_serve_one_trace_across_8x_prompt_spread():
               for i, p in enumerate([32, 4, 24, 6])]
     server.serve(trace2, prompt_cap=32, **kw)
     assert server.serve_traces == 1
+
+
+def test_paged_serve_compiles_once_per_table_width():
+    """Two calls with the same largest reservation share one program; a
+    call with a longer one compiles exactly one more, for its width."""
+    c = ARCHS["qwen3-0.6b"].reduced()
+    m = Model(c)
+    params = m.init_params(KEY)
+    server = Server(m, params, ClusterSpec.make([2, 2], [4.0, 0.8]),
+                    ServeConfig(block_rows=64))
+    bl = 4
+    kw = dict(slots=2, decode_block=1, paged=True, block_len=bl,
+              num_blocks=2 * -(-(48 + 3 + 1) // bl), prompt_cap=48)
+    widths = []
+    for plens in ([4, 32, 8], [32, 6, 16, 4], [4, 48, 8]):
+        trace = [_req(i, arrival=2.0 * i, out_len=3, plen=p)
+                 for i, p in enumerate(plens)]
+        rep = server.serve(trace, **kw)
+        assert sum(f.outcome == "done" for f in rep.finished) == len(plens)
+        widths.append((rep.table_blocks, server.serve_traces))
+    assert widths == [(9, 1), (9, 1), (13, 2)]
+
+
+# --------------------------------------- serve(): block-table width report
+@pytest.mark.parametrize("nb,width", [(2 * 9, 9), (9 + 3, 9), (7, 7)])
+def test_paged_serve_reports_table_width(nb, width):
+    """The tables are as wide as the call's largest reservation (9
+    blocks here), capped at the pool: a pool of slots x largest, one
+    smaller than that (the width is still the largest reservation, not
+    nb / slots), and one smaller than the largest (which is then shed)."""
+    c = ARCHS["qwen3-0.6b"].reduced()
+    m = Model(c)
+    params = m.init_params(KEY)
+    server = Server(m, params, ClusterSpec.make([2, 2], [4.0, 0.8]),
+                    ServeConfig(block_rows=64))
+    trace = [_req(i, out_len=3, plen=p, cls="batch")
+             for i, p in enumerate([5, 32, 10])]
+    rep = server.serve(trace, slots=2, decode_block=2, paged=True,
+                       block_len=4, num_blocks=nb)
+    assert (rep.table_blocks, rep.pool_blocks) == (width, nb)
+    assert rep.shed == (1 if nb < 9 else 0)
 
 
 def test_long_prompt_admits_via_chunked_prefill_where_dense_raises():
