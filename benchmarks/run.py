@@ -21,10 +21,7 @@ from benchmarks import (
     fig_adapt,
     fig_comm,
     fig_grad,
-    perf_gate,
     roofline,
-    serve_frontend,
-    serve_throughput,
 )
 
 
@@ -34,7 +31,7 @@ def main():
                     help="comma-separated subset to run, e.g. fig4,fig9")
     ap.add_argument("--skip", default=None,
                     help="comma-separated subset to leave out, e.g. "
-                         "serve_throughput,roofline")
+                         "fig_adapt,roofline")
     ap.add_argument("--list", action="store_true",
                     help="print registered benchmark names and exit")
     args = ap.parse_args()
@@ -43,10 +40,6 @@ def main():
         "fig6": fig6, "fig7": fig7, "fig8": fig8, "fig9": fig9,
         "fig_comm": fig_comm, "fig_grad": fig_grad, "fig_adapt": fig_adapt,
         "alloc_fastpath": alloc_fastpath, "roofline": roofline,
-        "serve_throughput": serve_throughput,
-        "serve_frontend": serve_frontend,
-        # after serve_throughput: gates the measurement it just re-based
-        "perf_gate": perf_gate,
     }
     if args.list:
         print("\n".join(mods))

@@ -10,9 +10,7 @@ erasures; logits are recovered from any k surviving coded blocks. The
 demo verifies coded output == uncoded output even with stragglers.
 
 The whole generation — prefill, straggler-mask sampling, erasure decode,
-fallback — is ONE compiled program (jax.lax.scan; see DESIGN.md §4);
-pass ServeConfig(jit_pipeline=False) to see the legacy per-token host
-loop it replaced.
+fallback — is ONE compiled program (jax.lax.scan; see DESIGN.md §4).
 """
 import time
 import jax

@@ -7,10 +7,10 @@ a frozen slot can never corrupt a block that was freed and reassigned to
 another stream. The sink is never referenced by any block table, so the
 gather+mask path never reads it as valid history.
 
-The decode attend mirrors ``models.attention.decode_attention_slots``
-operation-for-operation (same einsums, same f32 promotion points, same
-softmax) so that with an equivalent layout (blocks in logical order) the
-paged decode logits BIT-MATCH the dense slot-cache oracle.
+The served logits these attends produce are checked against the plain
+float32 references in ``bench/reference/`` (``tests/test_routed_window.py``
+compares every pending-logits row of a served trace), and each attend
+against the numpy oracle in ``ref.py``.
 """
 from __future__ import annotations
 
@@ -95,13 +95,13 @@ def paged_decode_attend(q, k_pool, v_pool, table, pos, window=None):
     """Single-query paged attention over the gathered pool.
 
     q: (S, KV, G, hd) post-rope; pos: (S,) write positions (already
-    scattered). Mirrors ``decode_attention_slots``'s attend math exactly
-    (bit-parity with the dense oracle under an order-preserving layout).
-    Returns (S, KV, G, hd) in v's dtype.
+    scattered). Returns (S, KV, G, hd) in v's dtype; checked against
+    ``ref.paged_decode_attend_ref`` and, served, against the float32
+    references.
     """
     bl = k_pool.shape[1]
-    # python-float scale (f64 sqrt), matching decode_attention_slots
-    # bit-for-bit — a traced f32 rsqrt can differ in the last ulp
+    # python-float scale (f64 sqrt), the same constant as the chunk
+    # attend's — a traced f32 rsqrt can differ in the last ulp
     scale = 1.0 / np.sqrt(q.shape[-1])
     k = gather_kv(k_pool, table)
     v = gather_kv(v_pool, table)

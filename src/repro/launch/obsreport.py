@@ -9,7 +9,7 @@ module turns one run's stream into the page an operator actually reads:
 * **overview** — event counts by type, log-line count, span coverage;
 * **span waterfall** — per-span-name wall-time totals from the
   ``SpanTracer`` records (`admit`/`prefill_chunk`/`decode_chunk`/
-  `dispatch`/`erasure_solve`/`replan`/...), unicode share bars;
+  `dispatch`/`replan`/...), unicode share bars;
 * **request latency** — p50/p95/p99 per deadline class from
   ``request_done``, shed counts by reason from ``request_evicted``;
 * **replan timeline** — every ``adapt_decision`` / ``replan`` /
@@ -21,8 +21,8 @@ module turns one run's stream into the page an operator actually reads:
   events;
 * **metrics** — the final ``metrics_snapshot`` (counters, gauges,
   histogram percentiles);
-* optionally the perf gate's per-phase XLA profile summary
-  (``--profile-summary artifacts/bench/perf_gate.json``).
+* optionally a per-phase XLA profile summary
+  (``--profile-summary``, the output of ``repro.obs.profile.summarize``).
 
 ``--require-spans`` makes the exit status assert observability itself:
 a stream with no ``span`` events means the loop ran untraced (or the
@@ -388,9 +388,9 @@ def main(argv=None):
     ap.add_argument("--html", default=None, metavar="PATH",
                     help="also write a self-contained HTML page")
     ap.add_argument("--profile-summary", default=None, metavar="JSON",
-                    help="bench record (perf_gate.json / "
-                         "serve_throughput.json) whose profile_summary "
-                         "to append")
+                    help="JSON of a repro.obs.profile.summarize "
+                         "summary (bare, or under a profile_summary "
+                         "key) to append")
     ap.add_argument("--require-spans", action="store_true",
                     help="exit non-zero when the stream has no span "
                          "events (the run was not traced)")

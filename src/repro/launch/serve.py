@@ -59,9 +59,6 @@ def main(argv=None):
     ap.add_argument("--use-kernel", action="store_true",
                     help="route the coded block mix through the Pallas "
                          "coded_matvec kernel")
-    ap.add_argument("--legacy-decode", action="store_true",
-                    help="per-token host loop with numpy decode (the path "
-                         "the jit pipeline replaces; for A/B timing)")
     ap.add_argument("--scenario", default=None, choices=scenario_names(),
                     help="cluster-dynamics scenario: serve rounds against "
                          "a drifting TRUE fleet (requires --coded)")
@@ -94,10 +91,6 @@ def main(argv=None):
                     help="in-flight stream slots for --trace; 'auto' asks "
                          "the AdaptiveController for a width from measured "
                          "round latency (requires --coded)")
-    ap.add_argument("--dense-kv", action="store_true",
-                    help="serve --trace from the dense per-slot KV cache "
-                         "(the parity oracle) instead of the paged block "
-                         "pool (DESIGN.md §13)")
     ap.add_argument("--block-len", type=int, default=None,
                     help="tokens per physical KV block for paged --trace "
                          "serving (default 16)")
@@ -135,10 +128,6 @@ def main(argv=None):
     if args.trace is not None and args.scenario is not None:
         raise SystemExit("--trace and --scenario are separate serving "
                          "modes; pick one")
-    if args.trace is not None and args.legacy_decode:
-        raise SystemExit("--trace requires the jit pipeline "
-                         "(continuous batching splices into compiled "
-                         "programs); drop --legacy-decode")
     if args.scenario is not None and not args.coded:
         raise SystemExit("--scenario requires --coded (a fleet to perturb)")
     if args.adapt_every is not None and args.scenario is None:
@@ -147,9 +136,6 @@ def main(argv=None):
     if args.measure_times and not args.coded:
         raise SystemExit("--measure-times requires --coded (round times "
                          "are decomposed over the coded fleet)")
-    if args.measure_times and args.legacy_decode:
-        raise SystemExit("--measure-times times compiled dispatches; "
-                         "drop --legacy-decode")
     if args.slots == "auto":
         if not args.coded:
             raise SystemExit("--slots auto derives the width from the coded "
@@ -183,7 +169,6 @@ def main(argv=None):
         model, params, cluster,
         ServeConfig(max_decode_steps=args.max_new, scheme=scheme,
                     use_kernel=args.use_kernel,
-                    jit_pipeline=not args.legacy_decode,
                     bucket_quantum=args.bucket_quantum),
     )
     if server.coded_head is not None:
@@ -277,8 +262,8 @@ def _serve_trace(server, args, config):
             trace, slots=slots,
             admission_threshold=args.admission_threshold,
             telemetry=tel, clock=clock, tracer=tracer,
-            paged=not args.dense_kv, block_len=args.block_len,
-            num_blocks=args.num_blocks, prefill_chunk=args.prefill_chunk,
+            block_len=args.block_len, num_blocks=args.num_blocks,
+            prefill_chunk=args.prefill_chunk,
         )
     if tracer is not None and rep.scopes is not None:
         os.makedirs(args.profile, exist_ok=True)

@@ -333,16 +333,16 @@ def decode_attention_paged(
 ):
     """Per-slot decode against a shared KV block pool (DESIGN.md §13).
 
-    Like ``decode_attention_slots`` but the KV state is a fixed pool of
-    physical blocks shared across slots: ``cache`` holds ``{"k", "v"}``
-    of shape (num_blocks + 1, block_len, KV, hd) (last block = write
-    sink), ``table``: (S, max_blocks) maps each slot's logical blocks to
-    pool blocks (−1 = unallocated), ``pos``: (S,) write positions,
-    ``active``: (S,) bool — inactive rows write to the sink so frozen
-    slots can never corrupt reassigned blocks. The attend math mirrors
-    ``decode_attention_slots`` exactly so paged decode logits bit-match
-    the dense oracle under an order-preserving layout. ``window``: a
-    query at position i attends keys j with i - j < window.
+    Every batch row (slot) advances at its OWN position, and the KV
+    state is a fixed pool of physical blocks shared across slots:
+    ``cache`` holds ``{"k", "v"}`` of shape (num_blocks + 1, block_len,
+    KV, hd) (last block = write sink), ``table``: (S, max_blocks) maps
+    each slot's logical blocks to pool blocks (−1 = unallocated),
+    ``pos``: (S,) write positions, ``active``: (S,) bool — inactive rows
+    write to the sink so frozen slots can never corrupt reassigned
+    blocks. ``window``: a query at position i attends keys j with
+    i - j < window. The served logits are checked against the plain
+    float32 references (``tests/test_routed_window.py``).
     """
     from repro.kernels.paged_attention import ops as paged_ops
 
@@ -406,48 +406,3 @@ def prefill_attention_paged(
     out = out.reshape(b, c, num_heads * head_dim).astype(x.dtype)
     y = out @ params["wo"].astype(x.dtype)
     return y, {"k": k_pool, "v": v_pool}
-
-
-def decode_attention_slots(
-    params, x, cache, pos_map, pos, slot, *,
-    num_heads, num_kv_heads, head_dim,
-    use_rope=True, rope_theta=10_000.0, yarn=None, window=None,
-):
-    """Per-slot decode: every batch row advances at its OWN position.
-
-    The continuous-batching serve loop keeps one independent request per
-    batch slot, so unlike ``decode_attention`` (uniform scalar ``pos``
-    for the whole batch) each row writes its new KV at, and attends up
-    to, its own absolute position.
-
-    x: (B, 1, D); cache: {"k", "v"} of shape (B, S, KV, hd);
-    pos_map: (B, S) absolute position held by each cache entry (−1 =
-    empty — the caller computes the post-write map once, it is shared by
-    every layer); pos: (B,) this step's write positions; slot: (B,)
-    cache indices to write (``pos % S``). Returns (y, {"k", "v"}).
-    Rolling caches and int8 KV are not supported here — the slot server
-    allocates full-context caches per slot, and a ``window`` masks them.
-    """
-    b = x.shape[0]
-    q, k_new, v_new = _qkv(params, x, x, num_heads, num_kv_heads, head_dim)
-    q, k_new = _maybe_qk_norm(params, q, k_new)
-    if use_rope:
-        p = pos[:, None].astype(jnp.int32)  # (B, 1) per-slot positions
-        q = rope(q, p, rope_theta, yarn)
-        k_new = rope(k_new, p, rope_theta, yarn)
-    bidx = jnp.arange(b)
-    k = cache["k"].at[bidx, slot].set(k_new[:, 0])
-    v = cache["v"].at[bidx, slot].set(v_new[:, 0])
-    g = num_heads // num_kv_heads
-    scale = 1.0 / np.sqrt(head_dim)
-    qr = q.reshape(b, num_kv_heads, g, head_dim)
-    sc = jnp.einsum("bkgh,bskh->bkgs", qr, k).astype(jnp.float32) * scale
-    valid = (pos_map >= 0) & (pos_map <= pos[:, None])  # (B, S)
-    if window is not None:
-        valid &= pos[:, None] - pos_map < window
-    sc = jnp.where(valid[:, None, None, :], sc, NEG_INF)
-    w = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bkgs,bskh->bkgh", w, v)
-    out = out.reshape(b, 1, num_heads * head_dim)
-    y = out @ params["wo"].astype(x.dtype)
-    return y, {"k": k, "v": v}

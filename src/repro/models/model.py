@@ -672,53 +672,25 @@ class Model:
         logits = self._mask_pad_logits(logits[:, 0])
         return logits, cache
 
-    # ------------------------------------------------- slot-resident decode
-    # The continuous-batching serve front-end (runtime/serve_loop.py
-    # ``Server.serve``, DESIGN.md §10) keeps one independent request per
-    # batch slot: each slot has its own sequence length, so the cache
-    # carries per-slot absolute positions and ``decode_step_slots`` takes
-    # a (B,) position vector instead of ``decode_step``'s uniform scalar.
-    # ``prefill`` fills a newly admitted request's per-layer KV from ONE
-    # batched forward pass (the cache-returning path §4 called for)
-    # instead of a per-position decode scan.
+    # ------------------------------------------------- batched prefill
+    # ``prefill`` fills a batch of prompts' per-layer KV from ONE batched
+    # forward pass (the cache-returning path §4 called for) instead of a
+    # per-position decode scan; ``Server.generate`` splices it into its
+    # decode cache. ``_check_slot_support`` is the support envelope it
+    # shares with the paged serve path below (DESIGN.md §10, §13): the
+    # attention-cache families, without int8 KV.
 
     def _check_slot_support(self) -> None:
         c = self.config
         if c.family not in ("dense", "vlm", "moe"):
             raise NotImplementedError(
-                f"slot-resident decode supports the attention-cache "
+                f"prefill and paged decode support the attention-cache "
                 f"families (dense/vlm/moe), not {c.family!r}"
             )
         if c.kv_quant:
             raise NotImplementedError(
-                "slot-resident decode does not support int8 KV caches yet"
+                "prefill and paged decode do not support int8 KV caches yet"
             )
-
-    def init_slot_cache(self, batch: int, cache_len: int):
-        """Decode state for ``decode_step_slots``: per-slot positions.
-
-        Layout matches ``init_cache``'s attention families except ``pos``
-        is (B, cache_len) — each slot tracks its own absolute positions
-        (−1 = empty). Shared across layers (every layer writes the same
-        positions), so the serve loop can splice a prefilled request into
-        one slot with a single row update.
-        """
-        c = self.config
-        self._check_slot_support()
-        hd = c.resolved_head_dim
-        return {
-            "kv": {
-                "k": jnp.zeros(
-                    (c.num_layers, batch, cache_len, c.num_kv_heads, hd),
-                    c.cdtype,
-                ),
-                "v": jnp.zeros(
-                    (c.num_layers, batch, cache_len, c.num_kv_heads, hd),
-                    c.cdtype,
-                ),
-                "pos": jnp.full((batch, cache_len), -1, jnp.int32),
-            }
-        }
 
     def prefill(self, params, tokens, length):
         """Batched prefill: one pass -> (last logits, per-layer K/V).
@@ -761,44 +733,6 @@ class Model:
         x_last = L.rmsnorm(params["final_norm"], x_last)
         logits = self._unembed(params, x_last)[:, 0]
         return self._mask_pad_logits(logits), ks, vs
-
-    def decode_step_slots(self, params, cache, tokens, pos):
-        """One token per slot, each at its OWN position.
-
-        tokens: (B,) int32; pos: (B,) int32 absolute write positions
-        (frozen slots simply rewrite the same entry — idempotent).
-        Returns (logits (B, V_padded), new_cache).
-        """
-        c = self.config
-        self._check_slot_support()
-        hd = c.resolved_head_dim
-        x = L.embed(params["embed"], tokens[:, None], c.cdtype)
-        kv = cache["kv"]
-        b, cache_len = kv["pos"].shape
-        pos = jnp.asarray(pos, jnp.int32)
-        bidx = jnp.arange(b)
-        slot = jnp.mod(pos, cache_len).astype(jnp.int32)
-        # one shared position map: every layer writes the same positions
-        pos_map = kv["pos"].at[bidx, slot].set(pos)
-
-        def body(j, h, inp):
-            p, kv_slice = inp
-            y, new = attn_mod.decode_attention_slots(
-                p["attn"], L.rmsnorm(p["ln1"], h), kv_slice, pos_map, pos,
-                slot, num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
-                head_dim=hd, rope_theta=c.rope_theta, **self._attn_kw(j),
-            )
-            h = h + y
-            return h + self._ffn(p, h)[0], new
-
-        layer_kv = {"k": kv["k"], "v": kv["v"]}
-        x, new_kv = self._scan_layers(body, x, (params["blocks"], layer_kv))
-
-        x = L.rmsnorm(params["final_norm"], x)
-        logits = self._unembed(params, x)
-        return self._mask_pad_logits(logits[:, 0]), {
-            "kv": {**new_kv, "pos": pos_map}
-        }
 
     # --------------------------------------------------- paged KV decode
     # Paged serving (DESIGN.md §13): physical KV memory is a fixed pool
@@ -889,9 +823,9 @@ class Model:
 
         tokens: (S,) int32; pos: (S,) write positions; table: (S, MB)
         block table; active: (S,) bool (inactive rows write to the
-        sink). Returns (logits (S, V_padded), new_cache). The attend
-        math bit-matches ``decode_step_slots`` under an order-preserving
-        block layout.
+        sink). Returns (logits (S, V_padded), new_cache). Served logits
+        are checked against the plain float32 references in
+        ``bench/reference/`` (``tests/test_routed_window.py``).
         """
         c = self.config
         self._check_slot_support()

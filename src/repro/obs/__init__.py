@@ -10,9 +10,8 @@ Four pieces, one import point:
 * :mod:`repro.obs.schema` — the central event-schema registry every
   ``Telemetry.event`` emitter declares through (validated by tier-1
   tests, rendered into DESIGN.md §8);
-* :mod:`repro.obs.profile` — XLA chrome-trace capture summarizer for
-  ``benchmarks/perf_gate.py --profile`` (per-phase top-K op
-  attribution and golden diffs).
+* :mod:`repro.obs.profile` — XLA chrome-trace capture summarizer
+  (per-phase top-K op attribution and summary diffs).
 """
 from repro.obs.metrics import (  # noqa: F401
     Counter,

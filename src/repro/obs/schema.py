@@ -243,20 +243,6 @@ _SCHEMAS = (
         ],
     ),
     _schema(
-        "perf_gate",
-        "`benchmarks/perf_gate.py` (§12) — one record per gated metric",
-        [
-            ("metric", "gated metric name"),
-            ("measured", "fresh measurement"),
-            ("golden", "committed golden value"),
-            ("bound", "one-sided tolerance edge"),
-            ("tolerance", "allowed relative regression"),
-            ("passed", "bool"),
-            ("enforced", "bool: ratio metrics always, absolutes only "
-                         "under `--absolute`"),
-        ],
-    ),
-    _schema(
         "span",
         "`repro.obs.trace.SpanTracer` (§14) — one finished wall-clock "
         "span from the serve/train/executor/controller loops",
@@ -264,8 +250,7 @@ _SCHEMAS = (
             ("span", "span name (`serve_setup` \\| `admit` \\| "
                      "`prepare` \\| `prefill_chunk` \\| `decode_chunk` "
                      "\\| `dispatch` \\| `retire` \\| `finish` \\| "
-                     "`erasure_solve` \\| `replan` \\| `bucket_switch` "
-                     "\\| `adapt_update`)"),
+                     "`replan` \\| `bucket_switch` \\| `adapt_update`)"),
             ("t0_s", "`perf_counter` at span entry"),
             ("dur_s", "span wall duration, seconds"),
             ("depth", "nesting depth (0 = top-level)"),

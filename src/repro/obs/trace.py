@@ -26,9 +26,8 @@ attribute lookup and never allocates (call sites pass no attributes to
 Span taxonomy (DESIGN.md §14). One ``Server.serve`` call is tiled by
 its top-level spans: ``serve_setup``, then per round ``admit`` |
 ``prepare`` | ``prefill_chunk`` or ``decode_chunk`` (holding
-``dispatch``) | ``retire``, then ``finish``. Elsewhere: ``erasure_solve``
-(the host decode path) | ``replan`` | ``bucket_switch`` |
-``adapt_update``.
+``dispatch``) | ``retire``, then ``finish``. Elsewhere: ``replan`` |
+``bucket_switch`` | ``adapt_update``.
 
 Device scopes (``SCOPES``, ``LAYER_SCOPES``): the serve program wraps its pieces in
 ``jax.named_scope``, which changes only the compiled instructions'

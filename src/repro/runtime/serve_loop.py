@@ -12,16 +12,16 @@ paper's load allocation, in block units) and returns the (R,)-per-block
 products ``E~_i h``. Any ``kb`` coded block-products reconstruct all
 logits — workers missing the deadline (T* x safety) are erasures.
 
-Jit-native decode pipeline (DESIGN.md §4): the whole generation —
-prefill, per-token decode, straggler-mask sampling, erasure decode and
-the insufficient-survivors fallback — is ONE compiled program driven by
-``jax.lax.scan``. The coded head precomputes its worker->block scatter
-map at init, samples finish masks inside the jitted step from
-``fold_in``'d keys, and decodes with the fixed-shape
-``decode_systematic_jit``; nothing touches the host between tokens. The
-legacy per-token host loop (numpy ``np.linalg.solve`` decode) survives
-behind ``ServeConfig(jit_pipeline=False)`` as the reference/baseline
-path for ``benchmarks/serve_throughput.py``.
+Two entry points, each one compiled program per shape (DESIGN.md §4,
+§10, §13). ``Server.serve`` runs continuous batching over a paged KV
+block pool: each dispatch is ``_serve_step_paged_program``, a gated
+chunked-prefill splice followed by a decode chunk. ``Server.generate``
+compiles a whole fixed-batch generation — prefill, per-token decode,
+straggler-mask sampling, erasure decode and the insufficient-survivors
+fallback — into one program driven by ``jax.lax.scan``. In both, the
+coded head samples finish masks inside the program from ``fold_in``'d
+keys and decodes with the fixed-shape ``decode_systematic_jit``; nothing
+touches the host between tokens.
 
 Substrate integration: the head's per-round mechanics — plan, (nb, kb)
 generator, deadline, straggler-mask sampling, worker->block scatter map,
@@ -33,7 +33,6 @@ trainer consumes, so the per-worker block counts follow the configured
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import time
@@ -76,13 +75,11 @@ class ServeConfig:
     max_decode_steps: int = 32
     scheme: str | AllocationScheme = "optimal"  # registry name or object
     use_kernel: bool = False  # Pallas coded-matvec kernel for the block mix
-    jit_pipeline: bool = True  # False: legacy per-token host loop (numpy)
-    # paged KV serving (DESIGN.md §13): ``serve`` runs on a block-pooled
-    # cache with chunked prefill; ``paged=False`` keeps the dense
-    # slot-cache path (the bit-parity oracle).
+    # True only: ``serve`` runs on the paged KV pool (DESIGN.md §13). The
+    # field remains because the benchmark harness passes ``paged=True``.
     paged: bool = True
     block_len: int = 16  # tokens per physical KV block
-    num_blocks: int | None = None  # pool size; None = dense-equivalent auto
+    num_blocks: int | None = None  # pool size; None = never-shed auto
     prefill_chunk: int | None = None  # admission chunk; None = prompt_cap
     # plan bucketing (DESIGN.md §11): set ``bucket_quantum`` to quantize
     # integer loads onto bucket shapes and replan in-program via a
@@ -90,6 +87,13 @@ class ServeConfig:
     bucket_quantum: int | None = None
     bucket_capacity: int = 8
     bucket_headroom: float = 1.5
+
+    def __post_init__(self):
+        if self.paged is not True:
+            raise ValueError(
+                "ServeConfig(paged=False): the dense slot-cache serve path "
+                "was removed; serve runs on the paged KV pool only"
+            )
 
     def bucket_config(self) -> BucketConfig | None:
         if self.bucket_quantum is None:
@@ -292,8 +296,7 @@ class CodedLMHead:
     def decode_logits(self, products, finished_workers) -> tuple[np.ndarray, bool]:
         """Recover (B, Vp) logits from surviving coded block-products.
 
-        Numpy reference oracle for ``decode_logits_jit`` (and the legacy
-        ``jit_pipeline=False`` serving path).
+        Numpy reference oracle for ``decode_logits_jit``.
         """
         products = np.asarray(products)  # (nb, B, R)
         fin = np.asarray(finished_workers, bool)
@@ -345,12 +348,12 @@ class ServeReport:
     #: steps and layers the held experts that received at least one
     held_expert_pairs: int = 0
     held_experts_hit: int = 0
-    #: paged runs: the block-table width every dispatch of the call ran
-    #: with (its largest reservation, capped at the pool), and the pool's
-    #: size in blocks; None on dense runs
+    #: the block-table width every dispatch of the call ran with (its
+    #: largest reservation, capped at the pool), and the pool's size in
+    #: blocks
     table_blocks: int | None = None
     pool_blocks: int | None = None
-    #: traced paged runs: chunk size -> {instruction: scope} of each
+    #: traced runs: chunk size -> {instruction: scope} of each
     #: compiled program the run dispatched (``obs.trace.scope_map``), so
     #: a device profile's ops can be put down to the program's scopes
     scopes: dict | None = None
@@ -386,17 +389,18 @@ def _count_rounds(decode_flags, moe=None) -> tuple[int, int, int, int]:
 class Server:
     """Batched decode with an optional coded LM head.
 
-    The default path compiles a whole ``generate`` call — prefill scan,
-    decode scan, coded erasure decode per token — into one XLA program;
+    ``generate`` compiles a whole fixed-batch call — prefill, decode
+    scan, coded erasure decode per token — into one XLA program;
     ``self.traces`` counts (re)traces so tests can assert that repeat
     calls with the same shapes never re-enter Python between tokens.
 
-    ``serve`` is the continuous-batching mode (DESIGN.md §10): a
-    slot-resident decode state driven by a ``SlotScheduler``, where
-    request admits/evicts are pure buffer updates into ONE fused
-    fixed-shape compiled program — a ``lax.cond``-gated prefill splice
+    ``serve`` is continuous batching (DESIGN.md §10, §13): a
+    ``SlotScheduler`` places requests into slots backed by a paged KV
+    block pool, and every round dispatches ONE fused fixed-shape
+    compiled program — a ``lax.cond``-gated chunked-prefill splice
     followed by a decode chunk (``serve_traces`` counts its (re)traces,
-    one per distinct chunk size — slot swaps must not add any).
+    one per distinct chunk size and table width — slot swaps and prompt
+    lengths must not add any).
     """
 
     def __init__(self, model: Model, params, cluster: ClusterSpec | None = None,
@@ -415,8 +419,6 @@ class Server:
             if cluster is not None
             else None
         )
-        self._decode = jax.jit(model.decode_step)
-        self._prefill_fn = jax.jit(self._prefill_into_cache)
         self.traces = 0
         self.serve_traces = 0
         #: span tracer (§14); ``serve(tracer=...)`` rebinds it, and the
@@ -439,10 +441,6 @@ class Server:
         # cache/logits/pos are donated: the serve loop threads them
         # through every dispatch and never reuses the old buffers, so XLA
         # can update the KV cache in place instead of copying it per call
-        self._serve_step_fn = jax.jit(
-            self._serve_step_program, static_argnames=("steps",),
-            donate_argnums=(1, 2, 3),
-        )
         #: the paged program the loop dispatches through
         #: ``_serve_step_paged_fn``, which a caller may wrap; this keeps
         #: the jitted function itself, whose compiled text names scopes
@@ -523,8 +521,8 @@ class Server:
     # ------------------------------------------------------- jit pipeline
     def _can_batch_prefill(self) -> bool:
         """True when ``Model.prefill`` covers this model (same support
-        envelope as the slot/paged paths) and the decode cache it splices
-        into holds whole prompts (it does not roll)."""
+        envelope as the paged path) and the decode cache it splices into
+        holds whole prompts (it does not roll)."""
         c = self.model.config
         return (
             c.family in ("dense", "vlm", "moe")
@@ -535,11 +533,10 @@ class Server:
     def _prefill_into_cache(self, params, cache, prompts):
         """Batched prefill spliced into an ``init_cache`` decode state.
 
-        The generate-path counterpart of the serve splice: ONE
-        ``Model.prefill`` pass computes every layer's prompt K/V and the
-        last-position logits, which land in cache positions
-        ``[0, s0)`` / the shared position map. Traceable — used inline by
-        ``_gen_program`` and jitted standalone by the legacy host loop.
+        ONE ``Model.prefill`` pass computes every layer's prompt K/V and
+        the last-position logits, which land in cache positions
+        ``[0, s0)`` / the shared position map. Traced inline by
+        ``_gen_program``.
         """
         b, s0 = prompts.shape
         logits, ks, vs = self.model.prefill(
@@ -627,9 +624,7 @@ class Server:
         dt = DTYPES_LOGITS[c.logits_dtype]
 
         if self._can_batch_prefill():
-            # one batched forward fills the whole prompt's KV (§4) — the
-            # same ``Model.prefill`` splice the serve path uses, so both
-            # generation paths share one prefill implementation
+            # one batched forward fills the whole prompt's KV (§4)
             logits, cache = self._prefill_into_cache(params, cache, prompts)
             logits = logits.astype(dt)
         else:
@@ -656,7 +651,7 @@ class Server:
             )[0]
 
         # every sampled token goes through the coded head, including the
-        # first post-prefill one (the old host loop skipped it)
+        # first post-prefill one
         tok0 = jnp.argmax(step_logits(logits, 0), -1).astype(jnp.int32)
 
         def body(carry, t):
@@ -673,116 +668,21 @@ class Server:
         return jnp.concatenate([prompts, tok0[:, None], toks.T], axis=1)
 
     # ------------------------------------------- continuous batching mode
-    def _serve_step_program(self, params, cache, logits, pos, prompts,
-                            lengths, row_of_slot, active, key, deadline,
-                            true_params=None, bucket_args=None, *, steps):
-        """One fused serve iteration: optional admit splice + decode chunk.
-
-        **Admit splice** (``lax.cond``-gated — the batched prefill costs
-        nothing on rounds without admissions): ``prompts`` is the
-        (S, prompt_cap) right-padded admission batch (row r = r-th
-        request placed this round), ``row_of_slot`` maps slot -> admission
-        row with −1 for slots keeping their current stream. Every splice
-        target is a TRACED argument, so admitting into any slot pattern
-        reuses the same compiled program. No token is sampled at admit —
-        the prefill logits become the slot's pending-logits state and the
-        chunk below samples from them, so every emitted token goes
-        through the coded head at the same amortized place and a
-        request's ``work`` is exactly 1 prefill round + ``out_len``
-        decode rounds.
-
-        **Decode chunk**: ``steps`` slot-decode rounds as one scan; each
-        round samples every slot's next token from its pending logits
-        (one coded round across the batch) and advances the model one
-        step. ``active``: (S,) bool — frozen slots (done or empty)
-        rewrite their current KV entry in place (idempotent) and keep
-        logits/pos, so the program's shape never depends on which slots
-        are live.
-        """
-        self.serve_traces += 1  # python side effect: runs only while tracing
-        row_of_slot = jnp.asarray(row_of_slot, jnp.int32)
-        fresh = row_of_slot >= 0  # (S,)
-
-        def splice(ops):
-            cache, logits, pos = ops
-            plog, ks, vs = self.model.prefill(params, prompts, lengths)
-            kv = cache["kv"]
-            s_slots, cache_len = kv["pos"].shape
-            prompt_cap = prompts.shape[1]
-            row = jnp.clip(row_of_slot, 0, None)
-            # prefilled K/V land in cache positions [0, prompt_cap) of
-            # their slot; the padded tail stays masked via pos = -1
-            k_new = jnp.zeros_like(kv["k"]).at[:, :, :prompt_cap].set(
-                ks[:, row]
-            )
-            v_new = jnp.zeros_like(kv["v"]).at[:, :, :prompt_cap].set(
-                vs[:, row]
-            )
-            fkv = fresh[None, :, None, None, None]
-            plen = jnp.asarray(lengths, jnp.int32)[row]  # (S,)
-            seq = jnp.arange(prompt_cap, dtype=jnp.int32)
-            pos_rows = jnp.where(
-                seq[None, :] < plen[:, None], seq[None, :], -1
-            )
-            pos_new = jnp.full((s_slots, cache_len), -1, jnp.int32)
-            pos_new = pos_new.at[:, :prompt_cap].set(pos_rows)
-            new_cache = {
-                "kv": {
-                    "k": jnp.where(fkv, k_new, kv["k"]),
-                    "v": jnp.where(fkv, v_new, kv["v"]),
-                    "pos": jnp.where(fresh[:, None], pos_new, kv["pos"]),
-                }
-            }
-            new_logits = jnp.where(
-                fresh[:, None], plog[row].astype(jnp.float32), logits
-            )
-            new_pos = jnp.where(fresh, plen, pos)
-            return new_cache, new_logits, new_pos
-
-        cache, logits, pos = jax.lax.cond(
-            jnp.any(fresh), splice, lambda ops: ops,
-            (cache, jnp.asarray(logits, jnp.float32),
-             jnp.asarray(pos, jnp.int32)),
-        )
-
-        def body(carry, t):
-            cache, logits, pos = carry
-            sel, flags = logits, (jnp.bool_(True), jnp.bool_(False))
-            if self.coded_head is not None:
-                sel, flags = self._coded_select(
-                    logits, jax.random.fold_in(key, t), deadline, true_params,
-                    bucket_args,
-                )
-            tok = jnp.argmax(sel, -1).astype(jnp.int32)
-            nlog, cache = self.model.decode_step_slots(
-                params, cache, tok, pos
-            )
-            logits = jnp.where(
-                active[:, None], nlog.astype(jnp.float32), logits
-            )
-            pos = jnp.where(active, pos + 1, pos)
-            return (cache, logits, pos), (tok, *flags)
-
-        (cache, logits, pos), (toks, oks, passes) = jax.lax.scan(
-            body, (cache, logits, pos), jnp.arange(steps, dtype=jnp.int32)
-        )
-        return cache, logits, pos, toks, oks, passes
-
     def _serve_step_paged_program(self, params, cache, logits, pos,
                                   chunk_tokens, chunk_start, chunk_lens,
                                   finishing, tables, active, key, deadline,
                                   true_params=None, bucket_args=None, *,
                                   steps):
-        """One fused PAGED serve iteration: prefill chunk + decode chunk.
+        """One fused serve iteration: prefill chunk + decode chunk.
 
-        The paged twin of ``_serve_step_program`` (DESIGN.md §13). Shapes
-        depend only on ``(num_blocks, block_len, S)``, the block-table
-        width (the call's largest reservation, capped at the pool) and
-        the prefill chunk width — never on a prompt length as such — so
-        within a call admitting a 4x-longer prompt retraces nothing: it
-        just runs more admit rounds of the SAME program. A later call
-        whose largest reservation differs compiles one program for its
-        width.
+        The program every ``serve`` round dispatches (DESIGN.md §13).
+        Shapes depend only on ``(num_blocks, block_len, S)``, the
+        block-table width (the call's largest reservation, capped at the
+        pool) and the prefill chunk width — never on a prompt length as
+        such — so within a call admitting a 4x-longer prompt retraces
+        nothing: it just runs more admit rounds of the SAME program. A
+        later call whose largest reservation differs compiles one program
+        for its width.
 
         **Prefill chunk** (``lax.cond``-gated): ``chunk_tokens`` is the
         (S, C) batch of this round's prompt chunks, row s covering
@@ -791,12 +691,17 @@ class Server:
         not prefilling). KV scatters into the slot's pool blocks through
         ``tables``; ``finishing`` marks slots whose prompt COMPLETES
         this round — their last-chunk logits become the slot's pending
-        logits and ``pos`` jumps to the prompt length, exactly like the
-        dense splice. Mid-prompt chunks update only the pool.
+        logits and ``pos`` jumps to the prompt length. No token is sampled
+        at admit: the chunk below samples from the pending logits, so
+        every emitted token goes through the coded head. Mid-prompt
+        chunks update only the pool.
 
-        **Decode chunk**: as in the dense program, but each step runs
-        ``decode_step_paged`` — inactive slots (empty / done / still
-        prefilling) write to the pool's sink block and keep logits/pos.
+        **Decode chunk**: ``steps`` rounds as one scan; each samples every
+        slot's next token from its pending logits (one coded round across
+        the batch) and runs ``decode_step_paged`` — inactive slots (empty
+        / done / still prefilling) write to the pool's sink block and keep
+        logits/pos, so the program's shape never depends on which slots
+        are live.
         """
         self.serve_traces += 1  # python side effect: runs only while tracing
         chunk_lens = jnp.asarray(chunk_lens, jnp.int32)
@@ -850,10 +755,9 @@ class Server:
         return cache, logits, pos, toks, oks, passes
 
     def serve(self, trace, *, slots: int = 4, prompt_cap: int | None = None,
-              max_out: int | None = None, decode_block: int = 4,
-              queue_cap: int = 64, admission_threshold: float = 1.0,
-              controller=None, round_latency=None, telemetry=None,
-              clock=None, key=None, paged: bool | None = None,
+              decode_block: int = 4, queue_cap: int = 64,
+              admission_threshold: float = 1.0, controller=None,
+              round_latency=None, telemetry=None, clock=None, key=None,
               block_len: int | None = None, num_blocks: int | None = None,
               prefill_chunk: int | None = None,
               tracer=None) -> ServeReport:
@@ -862,12 +766,26 @@ class Server:
         ``trace``: iterable of ``serve.workload.Request`` (arrivals in
         rounds). The scheduler (host) decides placements; the device side
         is ONE fused fixed-shape compiled program per chunk size — a
-        ``lax.cond``-gated admit/prefill splice followed by the
-        slot-decode chunk — whose arguments carry all per-round
-        variation, so admits and evicts never retrace and an admission
-        costs no extra dispatch. The program returns nothing the
-        scheduler needs, so chunks dispatch asynchronously; the one
-        ``block_until_ready`` sits at the end of the run.
+        ``lax.cond``-gated chunked-prefill splice followed by the decode
+        chunk — whose arguments carry all per-round variation, so admits
+        and evicts never retrace and an admission costs no extra
+        dispatch. The program returns nothing the scheduler needs, so
+        chunks dispatch asynchronously; the one ``block_until_ready``
+        sits at the end of the run.
+
+        KV lives in a block pool shared by the slots (DESIGN.md §13):
+        ``(num_blocks, block_len)`` with a full reservation at admission
+        and release at retirement. Prompts prefill in ``prefill_chunk``
+        -token pieces (default ``prompt_cap``, itself defaulting to the
+        trace's longest prompt) across admit rounds, so one compiled
+        program per decode-chunk size covers every prompt length of the
+        call, and rounds where every busy slot is still mid-prompt
+        dispatch a prefill-only pass (``steps=0``). The block tables are
+        as wide as the call's largest reservation (capped at the pool),
+        so a decode step gathers that many blocks a slot; a call with
+        another largest reservation compiles its own programs.
+        ``num_blocks=None`` sizes the pool so the trace can never exhaust
+        it; an explicit pool turns on memory admission control.
 
         Admission control is wired to ``controller.coverage_latency``
         when an ``AdaptiveController`` is given (or any ``round_latency``
@@ -884,16 +802,13 @@ class Server:
         ``controller.observe_timing`` so admission control and replans
         run on wall-clock evidence. Requires a coded head.
 
-        ``paged`` (default from ``ServeConfig.paged``) serves from the
-        block-pooled KV cache with chunked prefill (DESIGN.md §13):
-        ``prompt_cap`` then only sets the default admission chunk width
-        (``prefill_chunk``) — prompts longer than the chunk are admitted
-        and prefilled across successive admit rounds instead of raising,
-        and the cache shape is ``(num_blocks, block_len)``, independent
-        of any prompt length. ``num_blocks=None`` sizes the pool so the
-        trace can never exhaust it (dense-equivalent capacity);
-        an explicit pool turns on memory admission control.
+        Its top-level spans (§14) tile the call: ``serve_setup`` (until
+        the pool and initial arrays exist), then per round ``admit``,
+        ``prepare`` (the round's host arrays and their transfer), the
+        chunk span around ``dispatch``, and ``retire``; then ``finish``.
         """
+        from repro.serve.scheduler import BlockPool, SlotScheduler
+
         if clock is not None and self.coded_head is None:
             raise ValueError("clock (measured serving) requires a coded head")
 
@@ -909,31 +824,13 @@ class Server:
         if self.coded_head is not None:
             self.coded_head.executor.tracer = tracer
 
-        # the ``serve_setup`` span (§14) runs from here through the
-        # loop's own set-up (KV cache, initial arrays), which closes it
-        with contextlib.ExitStack() as setup:
-            setup.enter_context(tracer.span("serve_setup"))
-            paged = self.cfg.paged if paged is None else paged
+        with tracer.span("serve_setup"):
             trace = sorted(trace, key=lambda r: (r.arrival, r.rid))
             if not trace:
                 raise ValueError("serve needs a non-empty request trace")
             prompt_cap = int(
                 prompt_cap if prompt_cap is not None
                 else max(r.prompt_len for r in trace)
-            )
-            if not paged:
-                # dense slot caches are (S, prompt_cap + max_out + 1): a
-                # longer prompt cannot be represented. Paged mode has no
-                # such bound — long prompts prefill chunk-by-chunk.
-                too_long = [r.rid for r in trace
-                            if r.prompt_len > prompt_cap]
-                if too_long:
-                    raise ValueError(
-                        f"requests {too_long} exceed prompt_cap={prompt_cap}"
-                    )
-            max_out = int(
-                max_out if max_out is not None
-                else max(r.out_len for r in trace)
             )
             if round_latency is None and controller is not None:
                 round_latency = controller.coverage_latency
@@ -942,263 +839,64 @@ class Server:
                 reference = float(round_latency())
                 if not np.isfinite(reference) or reference <= 0:
                     reference = 1.0
-            common = dict(
-                setup=setup, slots=slots, prompt_cap=prompt_cap,
-                max_out=max_out, decode_block=decode_block,
-                queue_cap=queue_cap, admission_threshold=admission_threshold,
-                controller=controller, round_latency=round_latency,
-                reference=reference, telemetry=telemetry, clock=clock,
-                key=key,
+            chunk = int(prefill_chunk if prefill_chunk is not None
+                        else self.cfg.prefill_chunk if self.cfg.prefill_chunk
+                        is not None else prompt_cap)
+            bl = int(block_len if block_len is not None
+                     else self.cfg.block_len)
+            # the call's largest reservation (``SlotScheduler.blocks_needed``)
+            largest = max(
+                -(-(r.prompt_len + r.out_len + 1) // bl) for r in trace
             )
-            if paged:
-                return self._serve_paged(
-                    trace, block_len=block_len, num_blocks=num_blocks,
-                    prefill_chunk=prefill_chunk, **common,
+            nb = num_blocks if num_blocks is not None else self.cfg.num_blocks
+            if nb is None:
+                # every slot can hold the trace's largest request, so the
+                # pool never sheds — sizing DOWN from this is the knob
+                # that turns on memory admission control
+                nb = slots * largest
+            nb = int(nb)
+            cache = self.model.init_paged_cache(nb, bl)
+            kv = cache["kv"]
+            bytes_per_block = (kv["k"].nbytes + kv["v"].nbytes) // (nb + 1)
+            # one registry for pool + scheduler: the run snapshots as a unit
+            metrics = MetricsRegistry()
+            pool = BlockPool(
+                nb, bl, bytes_per_block=bytes_per_block, telemetry=telemetry,
+                metrics=metrics,
+            )
+            sched = SlotScheduler(
+                slots, queue_cap=queue_cap,
+                admission_threshold=admission_threshold,
+                round_latency=round_latency, reference_latency=reference,
+                telemetry=telemetry, pool=pool, chunk=chunk, metrics=metrics,
+            )
+            key = key if key is not None else jax.random.PRNGKey(0)
+            deadline = jnp.float32(
+                self.coded_head.deadline if self.coded_head is not None
+                else 0.0
+            )
+            true_params = None
+            if self.coded_head is not None:
+                true_params = (
+                    self._true_params
+                    if self._true_params is not None
+                    else self.coded_head.executor.worker_params
                 )
-            return self._serve_dense(trace, **common)
-
-    def _serve_dense(self, trace, *, setup, slots, prompt_cap, max_out,
-                     decode_block, queue_cap, admission_threshold,
-                     controller, round_latency, reference, telemetry, clock,
-                     key) -> ServeReport:
-        """Dense-slot host loop behind ``serve(paged=False)``: per-slot
-        caches of ``prompt_cap + max_out + 1`` positions. ``setup`` holds
-        the open ``serve_setup`` span, closed once the cache exists."""
-        from repro.serve.scheduler import SlotScheduler
-
-        tracer = self.tracer  # resolved by serve()
-        # +1: a finished (frozen) slot idempotently rewrites the entry at
-        # its final pos, which sits one past its last sampled token
-        cache_len = prompt_cap + max_out + 1
-        sched = SlotScheduler(
-            slots, queue_cap=queue_cap,
-            admission_threshold=admission_threshold,
-            round_latency=round_latency, reference_latency=reference,
-            telemetry=telemetry, metrics=MetricsRegistry(),
-        )
-        key = key if key is not None else jax.random.PRNGKey(0)
-        deadline = jnp.float32(
-            self.coded_head.deadline if self.coded_head is not None else 0.0
-        )
-        true_params = None
-        if self.coded_head is not None:
-            true_params = (
-                self._true_params
-                if self._true_params is not None
-                else self.coded_head.executor.worker_params
+            bucket_args = self._bucket_args()
+            logits = jnp.zeros(
+                (slots, padded_vocab(self.model.config.vocab_size)),
+                jnp.float32,
             )
-        bucket_args = self._bucket_args()
-        cache = self.model.init_slot_cache(slots, cache_len)
-        logits = jnp.zeros((slots, padded_vocab(self.model.config.vocab_size)),
-                           jnp.float32)
-        pos = jnp.zeros((slots,), jnp.int32)
-
-        now, i, call = 0.0, 0, 0
-        prefill_rounds = decode_rounds = 0
-        # per-dispatch (steps,) coded-decode ok and pass-through flags
-        decode_flags = []
-        # constant "no admissions this round" arguments (hoisted so the
-        # common no-admit dispatch ships no fresh host arrays)
-        no_prompts = jnp.zeros((slots, prompt_cap), jnp.int32)
-        no_lengths = jnp.zeros((slots,), jnp.int32)
-        no_rows = jnp.full((slots,), -1, jnp.int32)
-        setup.close()
-        t0 = time.perf_counter()
-        while i < len(trace) or not sched.idle:
-            with tracer.span("admit") as asp:
-                while i < len(trace) and trace[i].arrival <= now + 1e-9:
-                    sched.offer(trace[i], now)
-                    i += 1
-                placed = sched.fill_slots(now)
-                if tracer.enabled:
-                    asp.set(round=now, placed=len(placed))
-                if placed:
-                    prompts_np = np.zeros((slots, prompt_cap), np.int32)
-                    lengths_np = np.zeros((slots,), np.int32)
-                    rows_np = np.full((slots,), -1, np.int32)
-                    for r, (si, req) in enumerate(placed):
-                        prompts_np[r, : req.prompt_len] = req.prompt
-                        lengths_np[r] = req.prompt_len
-                        rows_np[si] = r
-                    prompts = jnp.asarray(prompts_np)
-                    lengths = jnp.asarray(lengths_np)
-                    rows = jnp.asarray(rows_np)
-                else:
-                    prompts, lengths, rows = no_prompts, no_lengths, no_rows
-            active = [s.busy and not s.done for s in sched.slots]
-            if any(active):
-                # chunk exactly to the next finish event: slots free the
-                # round their stream completes, with zero overshoot (at
-                # most ``decode_block`` step-count variants ever compile)
-                steps = min(
-                    decode_block,
-                    min(s.request.out_len - s.generated
-                        for s in sched.slots if s.busy and not s.done),
-                )
-                if clock is not None:
-                    # a measured replan may have moved the plan between
-                    # dispatches: refresh the per-round runtime args
-                    deadline = jnp.float32(self.coded_head.deadline)
-                    true_params = (
-                        self._true_params
-                        if self._true_params is not None
-                        else self.coded_head.executor.worker_params
-                    )
-                    bucket_args = self._bucket_args()
-                skey = jax.random.fold_in(key, call)
-                with tracer.span("decode_chunk") as csp:
-                    if tracer.enabled:
-                        csp.set(steps=steps, round=now, placed=len(placed))
-                    if clock is None:
-                        with tracer.span("dispatch"):
-                            out = self._serve_step_fn(
-                                self.params, cache, logits, pos, prompts,
-                                lengths, rows, jnp.asarray(active), skey,
-                                deadline, true_params, bucket_args,
-                                steps=steps,
-                            )
-                            cache, logits, pos, _, oks, passes = out
-                    else:
-                        with tracer.span("dispatch"):
-                            timing = clock.measure(
-                                lambda: self._serve_step_fn(
-                                    self.params, cache, logits, pos,
-                                    prompts, lengths, rows,
-                                    jnp.asarray(active), skey, deadline,
-                                    true_params, bucket_args,
-                                    steps=steps,
-                                ),
-                                key=skey,
-                                true_cluster=self._true_cluster,
-                            )
-                        cache, logits, pos, _, oks, passes = timing.result
-                        if controller is not None:
-                            d = controller.observe_timing(timing)
-                            if (
-                                d is not None and d.replanned
-                                and self.coded_head
-                                    .executor.last_replan_structural
-                            ):
-                                # next dispatch retraces the re-jitted
-                                # program: compile, not round latency
-                                clock.discard_next()
-                call += 1
-                decode_flags.append((oks, passes))
-                if placed:  # the fused admit pass costs its own round
-                    now += 1.0
-                    prefill_rounds += 1
-                now += float(steps)
-                decode_rounds += steps
-                sched.advance(steps)
-                sched.retire_done(now)
-            elif i < len(trace):
-                now = max(now, trace[i].arrival)  # idle: jump to next arrival
-            else:
-                break
-        with tracer.span("finish"):
-            jax.block_until_ready(logits)
-            wall = time.perf_counter() - t0
-            fallbacks, passthroughs, _, _ = _count_rounds(decode_flags)
-            report = ServeReport(
-                finished=tuple(sched.finished),
-                tokens=sum(
-                    f.tokens for f in sched.finished if f.outcome == "done"
-                ),
-                rounds=now,
-                decode_rounds=decode_rounds,
-                prefill_rounds=prefill_rounds,
-                admitted=sched.admitted,
-                shed=sched.shed,
-                wall_s=wall,
-                fallback_rounds=fallbacks,
-                passthrough_rounds=passthroughs,
-            )
-            sched.metrics.emit(telemetry, phase="serve", rounds=float(now))
-        return report
-
-
-    def _serve_paged(self, trace, *, setup, slots, prompt_cap, max_out,
-                     decode_block, queue_cap, admission_threshold,
-                     controller, round_latency, reference, telemetry, clock,
-                     key, block_len, num_blocks, prefill_chunk) -> ServeReport:
-        """Paged-KV host loop behind ``serve(paged=True)`` (DESIGN.md §13).
-
-        Differences from the dense loop: physical KV lives in a shared
-        ``BlockPool`` (full reservation at admission, freed at
-        retirement); prompts prefill in ``chunk``-token pieces across
-        admit rounds, so one compiled program per decode-chunk size
-        covers EVERY prompt length of the call; and rounds where every
-        busy slot is still mid-prompt dispatch a prefill-only pass
-        (``steps=0``). The block tables are as wide as the call's largest
-        reservation (capped at the pool), so a decode step gathers that
-        many blocks a slot; a call with another largest reservation
-        compiles its own programs.
-
-        Its top-level spans (§14) tile the call: ``serve_setup`` (held
-        open in ``setup`` until the pool and initial arrays exist), then
-        per round ``admit``, ``prepare`` (the round's host arrays and
-        their transfer), the chunk span around ``dispatch``, and
-        ``retire``; then ``finish``.
-        """
-        from repro.serve.scheduler import BlockPool, SlotScheduler
-
-        chunk = int(prefill_chunk if prefill_chunk is not None
-                    else self.cfg.prefill_chunk if self.cfg.prefill_chunk
-                    is not None else prompt_cap)
-        bl = int(block_len if block_len is not None else self.cfg.block_len)
-        # the call's largest reservation (``SlotScheduler.blocks_needed``)
-        largest = max(
-            -(-(r.prompt_len + r.out_len + 1) // bl) for r in trace
-        )
-        nb = num_blocks if num_blocks is not None else self.cfg.num_blocks
-        if nb is None:
-            # dense-equivalent capacity: every slot can hold the trace's
-            # largest request, so the pool never sheds — sizing DOWN from
-            # this is the knob that turns on memory admission control
-            nb = slots * largest
-        nb = int(nb)
-        cache = self.model.init_paged_cache(nb, bl)
-        kv = cache["kv"]
-        bytes_per_block = (kv["k"].nbytes + kv["v"].nbytes) // (nb + 1)
-        tracer = self.tracer  # resolved by serve()
-        # one registry for pool + scheduler: the run snapshots as a unit
-        metrics = MetricsRegistry()
-        pool = BlockPool(
-            nb, bl, bytes_per_block=bytes_per_block, telemetry=telemetry,
-            metrics=metrics,
-        )
-        sched = SlotScheduler(
-            slots, queue_cap=queue_cap,
-            admission_threshold=admission_threshold,
-            round_latency=round_latency, reference_latency=reference,
-            telemetry=telemetry, pool=pool, chunk=chunk, metrics=metrics,
-        )
-        key = key if key is not None else jax.random.PRNGKey(0)
-        deadline = jnp.float32(
-            self.coded_head.deadline if self.coded_head is not None else 0.0
-        )
-        true_params = None
-        if self.coded_head is not None:
-            true_params = (
-                self._true_params
-                if self._true_params is not None
-                else self.coded_head.executor.worker_params
-            )
-        bucket_args = self._bucket_args()
-        logits = jnp.zeros(
-            (slots, padded_vocab(self.model.config.vocab_size)), jnp.float32
-        )
-        pos = jnp.zeros((slots,), jnp.int32)
-        # host mirror of the device block tables, as wide as the call's
-        # largest reservation (no slot ever holds more; a reservation
-        # past the pool is shed), so every decode step gathers, scores
-        # and masks that many blocks a slot, not the whole pool
-        width = min(nb, largest)
-        table_np = np.full((slots, width), -1, np.int32)
-        no_chunk = jnp.zeros((slots, chunk), jnp.int32)
-        no_i32 = jnp.zeros((slots,), jnp.int32)
-        no_bool = jnp.zeros((slots,), bool)
-        setup.close()
+            pos = jnp.zeros((slots,), jnp.int32)
+            # host mirror of the device block tables, as wide as the call's
+            # largest reservation (no slot ever holds more; a reservation
+            # past the pool is shed), so every decode step gathers, scores
+            # and masks that many blocks a slot, not the whole pool
+            width = min(nb, largest)
+            table_np = np.full((slots, width), -1, np.int32)
+            no_chunk = jnp.zeros((slots, chunk), jnp.int32)
+            no_i32 = jnp.zeros((slots,), jnp.int32)
+            no_bool = jnp.zeros((slots,), bool)
 
         now, i, call = 0.0, 0, 0
         prefill_rounds = decode_rounds = 0
@@ -1246,7 +944,7 @@ class Server:
                 # decode-eligible AFTER the splice: done prefilling
                 # already, or finishing it in this very dispatch (so a
                 # short prompt still costs exactly 1 admit round +
-                # out_len decode rounds, matching the dense path)
+                # out_len decode rounds)
                 active = np.array([
                     s.busy and not s.done
                     and (not s.prefilling
@@ -1385,8 +1083,6 @@ class Server:
         b, s0 = prompts.shape
         cache_len = cache_len or (s0 + max_new)
         cache = self.model.init_cache(b, cache_len, extras)
-        if not self.cfg.jit_pipeline:
-            return self._generate_hostloop(prompts, max_new, key, cache)
         deadline = jnp.float32(
             self.coded_head.deadline if self.coded_head is not None else 0.0
         )
@@ -1406,57 +1102,3 @@ class Server:
                 self.params, cache, jnp.asarray(prompts, jnp.int32), key,
                 deadline, true_params, self._bucket_args(), max_new=max_new,
             )
-
-    # ------------------------------------------------- legacy host loop
-    def _generate_hostloop(self, prompts, max_new, key, cache):
-        """Per-token Python loop with numpy decode (reference/baseline).
-
-        Kept for ``benchmarks/serve_throughput.py``: this is the path the
-        jit pipeline replaces — one host round-trip per decoded token.
-        Prefill routes through the same jitted ``Model.prefill`` splice
-        as the compiled pipeline (one shared prefill implementation)
-        where supported; only the token loop stays sequential.
-        """
-        b, s0 = prompts.shape
-        prompts = jnp.asarray(prompts, jnp.int32)
-        if self._can_batch_prefill():
-            logits, cache = self._prefill_fn(self.params, cache, prompts)
-        else:
-            logits = None
-            for pos in range(s0):
-                logits, cache = self._decode(
-                    self.params, cache, prompts[:, pos], jnp.int32(pos)
-                )
-        out = [prompts]
-        if self.coded_head is not None:
-            logits = self._coded_logits(logits, key, 0)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        for t in range(max_new):
-            out.append(tok[:, None])
-            if t == max_new - 1:
-                break
-            logits, cache = self._decode(self.params, cache, tok, jnp.int32(s0 + t))
-            if self.coded_head is not None:
-                logits = self._coded_logits(logits, key, t + 1)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        return jnp.concatenate(out, axis=1)
-
-    def _coded_logits(self, fallback_logits, key, step):
-        """Recompute the final logits through the coded LM head (host path)."""
-        head = self.coded_head
-        vocab = self.model.config.vocab_size
-        with self.tracer.span("erasure_solve", step=step) as sp:
-            ids = np.arange(fallback_logits.shape[-1])
-            lf = np.asarray(fallback_logits, np.float32)
-            clean = np.where(ids[None, :] < vocab, lf, 0.0)
-            products = head.encode_logits(
-                jnp.asarray(clean), use_kernel=self.cfg.use_kernel
-            )
-            mask = head.sample_finish_mask(jax.random.fold_in(key, step))
-            logits, ok = head.decode_logits(products, mask)
-            sp.set(ok=bool(ok))
-            if not ok:  # insufficient survivors: fall back (a real
-                return fallback_logits  # system would extend the deadline)
-            logits = logits[:, : fallback_logits.shape[-1]]
-            logits = np.where(ids[None, :] < vocab, logits, NEG_INF)
-            return jnp.asarray(logits)

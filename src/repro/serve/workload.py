@@ -5,9 +5,7 @@ time, prompt, output budget and deadline class — that the slot scheduler
 (``serve/scheduler.py``) admits into the running decode scan. Arrival
 times are measured in DECODE ROUNDS (the serve loop's virtual clock: one
 compiled decode step = one round, one batched prefill pass = one round),
-so traces are reproducible independent of wall-clock speed and the same
-trace drives both the continuous-batching server and the sequential
-full-batch baseline in ``benchmarks/serve_frontend.py``.
+so traces are reproducible independent of wall-clock speed.
 
 Named workloads mirror the scenario registry (``repro/sim``): factories
 are registered by name, every factory's named keyword params are its

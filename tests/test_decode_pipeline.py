@@ -298,23 +298,6 @@ def test_server_generate_coded_matches_uncoded_regression():
     np.testing.assert_array_equal(np.asarray(out_plain), np.asarray(out_coded))
 
 
-def test_jit_pipeline_matches_legacy_hostloop():
-    """The compiled pipeline reproduces the host loop token-for-token."""
-    c = ARCHS["qwen3-0.6b"].reduced()
-    m = Model(c)
-    params = m.init_params(KEY)
-    prompts = jax.random.randint(KEY, (2, 4), 0, c.vocab_size).astype(jnp.int32)
-    cluster = ClusterSpec.make([6], [4.0])
-    jit_srv = Server(m, params, cluster, ServeConfig(max_decode_steps=6))
-    host_srv = Server(m, params, cluster,
-                      ServeConfig(max_decode_steps=6, jit_pipeline=False))
-    jit_srv.coded_head.deadline = 1e9
-    host_srv.coded_head.deadline = 1e9
-    out_jit = jit_srv.generate(prompts, 6, key=jax.random.PRNGKey(7))
-    out_host = host_srv.generate(prompts, 6, key=jax.random.PRNGKey(7))
-    np.testing.assert_array_equal(np.asarray(out_jit), np.asarray(out_host))
-
-
 def test_generate_is_single_compiled_program():
     """No retrace across calls; the program is scan-driven and callback-free."""
     c = ARCHS["qwen3-0.6b"].reduced()
@@ -356,29 +339,7 @@ def test_generate_is_single_compiled_program():
     assert not everything & {"pure_callback", "io_callback", "debug_callback"}
 
 
-def test_hostloop_first_post_prefill_token_is_coded():
-    """Regression: every sampled token goes through the coded head."""
-    c = ARCHS["qwen3-0.6b"].reduced()
-    m = Model(c)
-    params = m.init_params(KEY)
-    cluster = ClusterSpec.make([8], [5.0])
-    server = Server(m, params, cluster,
-                    ServeConfig(max_decode_steps=4, jit_pipeline=False))
-    server.coded_head.deadline = 1e9
-    calls = []
-    orig = server._coded_logits
-
-    def spy(*a, **kw):
-        calls.append(1)
-        return orig(*a, **kw)
-
-    server._coded_logits = spy
-    prompts = jax.random.randint(KEY, (1, 3), 0, c.vocab_size).astype(jnp.int32)
-    server.generate(prompts, 4)
-    assert len(calls) == 4  # one per sampled token, incl. the first
-
-
-def test_jit_pipeline_first_token_is_coded():
+def test_generate_first_token_is_coded():
     """Trace-time spy: the coded select runs for token 0 and the scan body."""
     c = ARCHS["qwen3-0.6b"].reduced()
     m = Model(c)

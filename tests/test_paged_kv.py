@@ -1,5 +1,5 @@
-"""Paged KV serving (DESIGN.md §13): kernel-family parity, dense-oracle
-bit-parity, block-pool policy, memory admission control, chunked
+"""Paged KV serving (DESIGN.md §13): kernel-family parity, pool-size
+invariance, block-pool policy, memory admission control, chunked
 prefill, and the no-retrace guarantee across prompt lengths."""
 import jax
 import jax.numpy as jnp
@@ -122,59 +122,6 @@ def test_scatter_routes_inactive_rows_to_sink():
     assert np.all(k2[nb, 1] == 1.0)  # its write landed in the sink
 
 
-# ----------------------------------------- dense-oracle bit parity (decode)
-def test_decode_step_paged_bitmatches_dense_slot_oracle():
-    """Same history, same tokens: paged decode logits must BIT-match the
-    dense slot-cache path (identical einsums / promotion points), so the
-    coded head sees identical inputs under either cache layout."""
-    c = ARCHS["qwen3-0.6b"].reduced()
-    m = Model(c)
-    params = m.init_params(KEY)
-    slots, s0, steps, bl = 2, 6, 4, 4
-    cache_len = s0 + steps + 1
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (slots, s0), 0,
-                                c.vocab_size).astype(jnp.int32)
-    plog, ks, vs = m.prefill(params, tokens, jnp.full((slots,), s0, jnp.int32))
-
-    dense = m.init_slot_cache(slots, cache_len)
-    kv = dense["kv"]
-    seq = jnp.arange(s0, dtype=jnp.int32)
-    dense = {"kv": {
-        "k": kv["k"].at[:, :, :s0].set(ks),
-        "v": kv["v"].at[:, :, :s0].set(vs),
-        "pos": kv["pos"].at[:, :s0].set(jnp.broadcast_to(seq, (slots, s0))),
-    }}
-
-    mb = -(-cache_len // bl)
-    nb = slots * mb
-    paged = m.init_paged_cache(nb, bl)
-    table_np = np.full((slots, nb), -1, np.int32)
-    for s in range(slots):
-        table_np[s, :mb] = np.arange(s * mb, (s + 1) * mb)
-    pk = np.array(paged["kv"]["k"])
-    pv = np.array(paged["kv"]["v"])
-    ks_np, vs_np = np.asarray(ks), np.asarray(vs)
-    for s in range(slots):
-        for t in range(s0):
-            pk[:, table_np[s, t // bl], t % bl] = ks_np[:, s, t]
-            pv[:, table_np[s, t // bl], t % bl] = vs_np[:, s, t]
-    paged = {"kv": {"k": jnp.asarray(pk), "v": jnp.asarray(pv)}}
-    table = jnp.asarray(table_np)
-    active = jnp.ones((slots,), bool)
-
-    pos = jnp.full((slots,), s0, jnp.int32)
-    dlog = plog_p = plog
-    for _ in range(steps):
-        tok = jnp.argmax(dlog, -1).astype(jnp.int32)
-        dlog, dense = m.decode_step_slots(params, dense, tok, pos)
-        plog_p, paged = m.decode_step_paged(params, paged, tok, pos, table,
-                                            active)
-        assert np.array_equal(np.asarray(dlog), np.asarray(plog_p)), (
-            "paged decode logits must bit-match the dense slot oracle"
-        )
-        pos = pos + 1
-
-
 def test_chunked_prefill_paged_matches_full_prefill_logits():
     """Prefilling in chunks across rounds reproduces the one-shot
     batched prefill's pending logits (the serve loop's admission path
@@ -222,13 +169,15 @@ def test_chunked_prefill_paged_matches_full_prefill_logits():
                                    rtol=2e-4, atol=2e-4)
 
 
-# -------------------------------------------- serve(): paged == dense A/B
+# ------------------------------------------- serve(): pool-size invariance
 @pytest.mark.parametrize("safety,seed", [(1.2, 0), (3.0, 1)])
-def test_paged_serve_matches_dense_across_erasure_grid(safety, seed):
-    """Same trace, same key, same deadline => same erasure masks: the
-    paged path must reproduce the dense run's schedule exactly (token
-    counts, finish rounds, round accounting) — any logits divergence
-    would change an argmax somewhere and break this."""
+def test_paged_serve_is_pool_size_invariant_across_erasure_grid(safety,
+                                                                seed):
+    """Same trace, same key, same deadline => same erasure masks: a pool
+    three times the default must reproduce the default pool's run exactly
+    (token counts, finish rounds, round accounting). The tables stay as
+    wide as the largest reservation, so any logits divergence from where
+    the blocks sit would change an argmax somewhere and break this."""
     c = ARCHS["qwen3-0.6b"].reduced()
     m = Model(c)
     params = m.init_params(KEY)
@@ -238,26 +187,22 @@ def test_paged_serve_matches_dense_across_erasure_grid(safety, seed):
                        out_len=(2, 5), vocab=c.vocab_size)
     trace = wl.trace(seed=seed)
     key = jax.random.PRNGKey(seed)
-    rep_d = server.serve(trace, slots=2, decode_block=2, paged=False, key=key)
-    rep_p = server.serve(trace, slots=2, decode_block=2, paged=True, key=key)
-    # a pool three times the default: the tables stay as wide as the
-    # largest reservation, and the run is the same
-    rep_w = server.serve(trace, slots=2, decode_block=2, paged=True, key=key,
+    rep_p = server.serve(trace, slots=2, decode_block=2, key=key)
+    rep_w = server.serve(trace, slots=2, decode_block=2, key=key,
                          num_blocks=3 * rep_p.pool_blocks)
+    assert rep_w.pool_blocks == 3 * rep_p.pool_blocks
     assert rep_w.table_blocks == rep_p.table_blocks
-    assert rep_d.table_blocks is None and rep_d.pool_blocks is None
-    done_d = {f.request.rid: f for f in rep_d.finished if f.outcome == "done"}
-    assert done_d
-    for rep in (rep_p, rep_w):
-        assert rep.tokens == rep_d.tokens
-        assert rep.rounds == rep_d.rounds
-        assert rep.decode_rounds == rep_d.decode_rounds
-        assert rep.admitted == rep_d.admitted and rep.shed == rep_d.shed
-        done = {f.request.rid: f for f in rep.finished if f.outcome == "done"}
-        assert done_d.keys() == done.keys()
-        for rid, f in done_d.items():
-            assert done[rid].finish_round == f.finish_round
-            assert done[rid].tokens == f.tokens
+    done_p = {f.request.rid: f for f in rep_p.finished if f.outcome == "done"}
+    assert done_p
+    assert rep_w.tokens == rep_p.tokens
+    assert rep_w.rounds == rep_p.rounds
+    assert rep_w.decode_rounds == rep_p.decode_rounds
+    assert rep_w.admitted == rep_p.admitted and rep_w.shed == rep_p.shed
+    done = {f.request.rid: f for f in rep_w.finished if f.outcome == "done"}
+    assert done_p.keys() == done.keys()
+    for rid, f in done_p.items():
+        assert done[rid].finish_round == f.finish_round
+        assert done[rid].tokens == f.tokens
 
 
 # ------------------------------------------------ serve(): retrace pinning
@@ -276,8 +221,7 @@ def test_paged_serve_one_trace_across_8x_prompt_spread():
     trace = [_req(i, arrival=2.0 * i, out_len=3, plen=p)
              for i, p in enumerate(plens)]
     bl, nb = 4, 2 * -(-(32 + 3 + 1) // 4)
-    kw = dict(slots=2, decode_block=1, paged=True, block_len=bl,
-              num_blocks=nb)
+    kw = dict(slots=2, decode_block=1, block_len=bl, num_blocks=nb)
     rep = server.serve(trace, **kw)
     assert server.serve_traces == 1
     assert sum(1 for f in rep.finished if f.outcome == "done") == len(plens)
@@ -297,7 +241,7 @@ def test_paged_serve_compiles_once_per_table_width():
     server = Server(m, params, ClusterSpec.make([2, 2], [4.0, 0.8]),
                     ServeConfig(block_rows=64))
     bl = 4
-    kw = dict(slots=2, decode_block=1, paged=True, block_len=bl,
+    kw = dict(slots=2, decode_block=1, block_len=bl,
               num_blocks=2 * -(-(48 + 3 + 1) // bl), prompt_cap=48)
     widths = []
     for plens in ([4, 32, 8], [32, 6, 16, 4], [4, 48, 8]):
@@ -323,13 +267,15 @@ def test_paged_serve_reports_table_width(nb, width):
                     ServeConfig(block_rows=64))
     trace = [_req(i, out_len=3, plen=p, cls="batch")
              for i, p in enumerate([5, 32, 10])]
-    rep = server.serve(trace, slots=2, decode_block=2, paged=True,
-                       block_len=4, num_blocks=nb)
+    rep = server.serve(trace, slots=2, decode_block=2, block_len=4,
+                       num_blocks=nb)
     assert (rep.table_blocks, rep.pool_blocks) == (width, nb)
     assert rep.shed == (1 if nb < 9 else 0)
 
 
-def test_long_prompt_admits_via_chunked_prefill_where_dense_raises():
+def test_long_prompt_admits_via_chunked_prefill():
+    """A prompt 4.6x ``prompt_cap`` is admitted and prefilled chunk by
+    chunk across admit rounds."""
     c = ARCHS["qwen3-0.6b"].reduced()
     m = Model(c)
     params = m.init_params(KEY)
@@ -337,14 +283,19 @@ def test_long_prompt_admits_via_chunked_prefill_where_dense_raises():
                     ServeConfig(block_rows=64))
     long_req = _req(0, out_len=3, plen=37)
     trace = [long_req, _req(1, arrival=1.0, out_len=3, plen=5)]
-    with pytest.raises(ValueError, match="exceed prompt_cap"):
-        server.serve(trace, slots=2, prompt_cap=8, paged=False)
-    rep = server.serve(trace, slots=2, prompt_cap=8, paged=True,
-                       decode_block=2)
+    rep = server.serve(trace, slots=2, prompt_cap=8, decode_block=2)
     done = {f.request.rid: f for f in rep.finished if f.outcome == "done"}
     assert set(done) == {0, 1}
     assert done[0].tokens == 3
     assert rep.prefill_rounds >= -(-37 // 8)  # one round per chunk
+
+
+def test_serve_config_accepts_only_the_paged_path():
+    """``paged`` is True only: the dense slot-cache path is gone, and
+    asking for it fails at construction rather than serving paged."""
+    assert ServeConfig().paged is True
+    with pytest.raises(ValueError, match="dense slot-cache"):
+        ServeConfig(paged=False)
 
 
 # ----------------------------------------------------- BlockPool + policy

@@ -181,30 +181,35 @@ def test_prefill_matches_full_forward_last_position():
 
 
 def test_slot_decode_continues_prefilled_stream():
-    """Splice + per-slot decode == teacher-forced full-forward logits."""
+    """Prefill spliced into the paged pool + per-slot paged decode ==
+    teacher-forced full-forward logits."""
     c = ARCHS["qwen3-0.6b"].reduced()
     m = Model(c)
     params = m.init_params(KEY)
-    s0, steps, slots = 6, 3, 2
-    cache_len = s0 + steps + 1
+    s0, steps, slots, bl = 6, 3, 2, 4
     tokens = jax.random.randint(jax.random.PRNGKey(2), (slots, s0), 0,
                                 c.vocab_size).astype(jnp.int32)
     lengths = jnp.full((slots,), s0, jnp.int32)
     plog, ks, vs = m.prefill(params, tokens, lengths)
-    cache = m.init_slot_cache(slots, cache_len)
-    kv = cache["kv"]
-    seq = jnp.arange(s0, dtype=jnp.int32)
-    cache = {"kv": {
-        "k": kv["k"].at[:, :, :s0].set(ks),
-        "v": kv["v"].at[:, :, :s0].set(vs),
-        "pos": kv["pos"].at[:, :s0].set(jnp.broadcast_to(seq, (slots, s0))),
-    }}
+    # each slot's blocks in logical order, slot 1's after slot 0's
+    mb = -(-(s0 + steps + 1) // bl)
+    cache = m.init_paged_cache(slots * mb, bl)
+    table = np.arange(slots * mb, dtype=np.int32).reshape(slots, mb)
+    pk = np.array(cache["kv"]["k"])
+    pv = np.array(cache["kv"]["v"])
+    for s in range(slots):
+        for t in range(s0):
+            pk[:, table[s, t // bl], t % bl] = np.asarray(ks)[:, s, t]
+            pv[:, table[s, t // bl], t % bl] = np.asarray(vs)[:, s, t]
+    cache = {"kv": {"k": jnp.asarray(pk), "v": jnp.asarray(pv)}}
+    active = jnp.ones((slots,), bool)
     pos = jnp.full((slots,), s0, jnp.int32)
     logits, ctx = plog, tokens
     for _ in range(steps):
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
         ctx = jnp.concatenate([ctx, tok[:, None]], axis=1)
-        logits, cache = m.decode_step_slots(params, cache, tok, pos)
+        logits, cache = m.decode_step_paged(params, cache, tok, pos,
+                                            jnp.asarray(table), active)
         full = m.lm_logits(params, ctx)[:, -1]
         np.testing.assert_allclose(np.asarray(logits), np.asarray(full),
                                    rtol=2e-4, atol=2e-4)
