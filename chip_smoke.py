@@ -180,8 +180,8 @@ def kernel_check(server, log) -> dict:
     kv, g, hd = c.num_kv_heads, c.num_heads // c.num_kv_heads, c.resolved_head_dim
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (s, kv, g, hd), jnp.bfloat16)
-    k_pool = jax.random.normal(ks[1], (s * mb + 1, bl, kv, hd), jnp.bfloat16)
-    v_pool = jax.random.normal(ks[2], (s * mb + 1, bl, kv, hd), jnp.bfloat16)
+    k_pool = jax.random.normal(ks[1], (s * mb + 1, bl, kv * hd), jnp.bfloat16)
+    v_pool = jax.random.normal(ks[2], (s * mb + 1, bl, kv * hd), jnp.bfloat16)
     rng = np.random.default_rng(0)
     table = rng.permutation(s * mb).reshape(s, mb).astype(np.int32)
     table[1, 5:] = -1

@@ -329,20 +329,23 @@ def decode_attention_paged(
     params, x, cache, table, pos, active, *,
     num_heads, num_kv_heads, head_dim,
     use_rope=True, rope_theta=10_000.0, yarn=None, window=None,
-    use_kernel=False,
+    use_kernel=False, layer=None,
 ):
     """Per-slot decode against a shared KV block pool (DESIGN.md §13).
 
     Every batch row (slot) advances at its OWN position, and the KV
     state is a fixed pool of physical blocks shared across slots:
     ``cache`` holds ``{"k", "v"}`` of shape (num_blocks + 1, block_len,
-    KV, hd) (last block = write sink), ``table``: (S, max_blocks) maps
-    each slot's logical blocks to pool blocks (−1 = unallocated),
-    ``pos``: (S,) write positions, ``active``: (S,) bool — inactive rows
-    write to the sink so frozen slots can never corrupt reassigned
-    blocks. ``window``: a query at position i attends keys j with
-    i - j < window. The served logits are checked against the plain
-    float32 references (``tests/test_routed_window.py``).
+    KV * hd) (last block = write sink), or with ``layer`` the stacked
+    (L, num_blocks + 1, block_len, KV * hd) pool of which this call
+    writes and reads layer ``layer`` in place; ``table``: (S,
+    max_blocks) maps each slot's logical blocks to pool blocks (−1 =
+    unallocated), ``pos``: (S,) write positions, ``active``: (S,) bool —
+    inactive rows write to the sink so frozen slots can never corrupt
+    reassigned blocks. ``window``: a query at position i attends keys j
+    with i - j < window. Returns (y, the pool as given, written). The
+    served logits are checked against the plain float32 references
+    (``tests/test_routed_window.py``).
     """
     from repro.kernels.paged_attention import ops as paged_ops
 
@@ -354,19 +357,21 @@ def decode_attention_paged(
         q = rope(q, p, rope_theta, yarn)
         k_new = rope(k_new, p, rope_theta, yarn)
     k_pool, v_pool = paged_ops.scatter_decode(
-        cache["k"], cache["v"], k_new[:, 0], v_new[:, 0], table, pos, active
+        cache["k"], cache["v"], k_new[:, 0], v_new[:, 0], table, pos, active,
+        layer,
     )
     g = num_heads // num_kv_heads
     qr = q.reshape(b, num_kv_heads, g, head_dim)
     if use_kernel:
         if window is not None:
             raise NotImplementedError("the paged decode kernel has no window")
+        view = lambda t: t if layer is None else t[layer]
         out = paged_ops.paged_decode_attend_kernel(
-            qr, k_pool, v_pool, table, pos
+            qr, view(k_pool), view(v_pool), table, pos
         )
     else:
         out = paged_ops.paged_decode_attend(qr, k_pool, v_pool, table, pos,
-                                            window=window)
+                                            window=window, layer=layer)
     out = out.reshape(b, 1, num_heads * head_dim)
     y = out @ params["wo"].astype(x.dtype)
     return y, {"k": k_pool, "v": v_pool}
@@ -375,7 +380,7 @@ def decode_attention_paged(
 def prefill_attention_paged(
     params, x, cache, table, start, chunk_len, *,
     num_heads, num_kv_heads, head_dim,
-    use_rope=True, rope_theta=10_000.0, yarn=None, window=None,
+    use_rope=True, rope_theta=10_000.0, yarn=None, window=None, layer=None,
 ):
     """One chunked-prefill pass of C prompt tokens per slot into the pool.
 
@@ -385,7 +390,9 @@ def prefill_attention_paged(
     outputs are discarded by the caller). KV for the chunk is scattered
     FIRST, then every query attends the slot's full gathered history up
     to itself, so cross-chunk context (earlier admit rounds) and
-    in-chunk causality share one mask.
+    in-chunk causality share one mask. ``cache`` and ``layer`` are as in
+    ``decode_attention_paged``: with ``layer`` the chunk is written into
+    and read from that layer of the stacked pool in place.
     """
     from repro.kernels.paged_attention import ops as paged_ops
 
@@ -397,12 +404,12 @@ def prefill_attention_paged(
         q = rope(q, p, rope_theta, yarn)
         k_new = rope(k_new, p, rope_theta, yarn)
     k_pool, v_pool = paged_ops.scatter_chunk(
-        cache["k"], cache["v"], k_new, v_new, table, start, chunk_len
+        cache["k"], cache["v"], k_new, v_new, table, start, chunk_len, layer
     )
     g = num_heads // num_kv_heads
     qr = q.reshape(b, c, num_kv_heads, g, head_dim)
     out = paged_ops.paged_chunk_attend(qr, k_pool, v_pool, table, p,
-                                       window=window)
+                                       window=window, layer=layer)
     out = out.reshape(b, c, num_heads * head_dim).astype(x.dtype)
     y = out @ params["wo"].astype(x.dtype)
     return y, {"k": k_pool, "v": v_pool}

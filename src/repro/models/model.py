@@ -746,11 +746,13 @@ class Model:
     def init_paged_cache(self, num_blocks: int, block_len: int):
         """KV block pool for ``decode_step_paged``/``prefill_paged``.
 
-        Allocates ``num_blocks + 1`` physical blocks per layer: the last
-        block is the write SINK — inactive/frozen/padded rows scatter
-        there, so a frozen slot can never corrupt a block that was freed
-        and reassigned. No position array: validity is derived from the
-        per-dispatch block tables and positions (runtime arguments).
+        Allocates ``num_blocks + 1`` physical blocks per layer, stacked
+        over layers, each of ``block_len`` rows of ``KV * hd`` (a token's
+        heads side by side; ``kernels/paged_attention/ops.py`` says why):
+        the last block is the write SINK — inactive/frozen/padded rows
+        scatter there, so a frozen slot can never corrupt a block that was
+        freed and reassigned. No position array: validity is derived from
+        the per-dispatch block tables and positions (runtime arguments).
 
         An expert model's state also carries ``"moe"``: int32 (2,), the
         decode steps' token-choice pairs that landed on held experts and
@@ -760,7 +762,7 @@ class Model:
         c = self.config
         self._check_slot_support()
         hd = c.resolved_head_dim
-        shape = (c.num_layers, num_blocks + 1, block_len, c.num_kv_heads, hd)
+        shape = (c.num_layers, num_blocks + 1, block_len, c.num_kv_heads * hd)
         cache = {
             "kv": {
                 "k": jnp.zeros(shape, c.cdtype),
@@ -772,50 +774,55 @@ class Model:
         return cache
 
     def _paged_layers(self, attn_fn, x, blocks, cache, valid=None):
-        """The layers over the pool: ``attn_fn(j, p, h, kv_slice) -> (h,
-        new kv_slice)`` then the FFN, each half in its named scope
-        (DESIGN.md §14), a window layer's attention in its own. Returns
-        (x, new cache); ``valid`` rows' expert counts are added to the
-        cache's ``"moe"``, which is otherwise passed on.
+        """The layers over the pool: ``attn_fn(j, p, h, kv, l) -> (h,
+        kv)`` then the FFN, each half in its named scope (DESIGN.md §14),
+        a window layer's attention in its own. Returns (x, new cache);
+        ``valid`` rows' expert counts are added to the cache's ``"moe"``,
+        which is otherwise passed on.
 
-        An expert layer's weights stay stacked over layers and are sliced
-        inside ``model/moe``: the scan's own slicing of its inputs would
-        copy every held expert of the layer (of the period, and again of
-        the layer, under a period scan) in ``model/layers``, away from the
-        layer's time."""
+        The stacked pool rides in the layer scan's carry beside ``h``,
+        and the layer's index ``l`` is a scan input: ``attn_fn`` writes
+        layer ``l``'s new rows into the stacked pool in place and reads
+        that layer's blocks straight from it. Passed as the scan's input
+        and output instead, the pool would be sliced out per layer,
+        written back into a stacked output, and copied back after the
+        loop: three whole-pool passes a step for a token's few rows.
+
+        An expert layer's weights stay stacked over layers too, and are
+        sliced inside ``model/moe`` by the same index: the scan's own
+        slicing of its inputs would copy every held expert of the layer
+        (of the period, and again of the layer, under a period scan) in
+        ``model/layers``, away from the layer's time."""
         c = self.config
         moe = c.family == "moe"
-        layer_kv = {"k": cache["kv"]["k"], "v": cache["kv"]["v"]}
-        xs = (blocks, layer_kv)
-        if moe:
-            experts = blocks["moe"]
-            xs = ({k: v for k, v in blocks.items() if k != "moe"}, layer_kv,
-                  jnp.arange(c.num_layers, dtype=jnp.int32))
+        experts = blocks.get("moe")
+        xs = ({k: v for k, v in blocks.items() if k != "moe"},
+              jnp.arange(c.num_layers, dtype=jnp.int32))
 
-        def body(j, h, inp):
-            p, kv_slice, *layer = inp
+        def body(j, carry, inp):
+            h, kv = carry
+            p, layer = inp
             window = self._attn_kw(j)["window"]
             scope = SCOPE_ATTENTION if window is None else SCOPE_WINDOW_ATTENTION
             with jax.named_scope(scope):
-                h, new = attn_fn(j, p, h, kv_slice)
+                h, kv = attn_fn(j, p, h, kv, layer)
             with jax.named_scope(SCOPE_MOE if moe else SCOPE_MLP):
                 if moe:
                     p = {**p, "moe": jax.tree.map(
                         lambda t: jax.lax.dynamic_index_in_dim(
-                            t, layer[0], keepdims=False), experts)}
+                            t, layer, keepdims=False), experts)}
                 y, counts = self._ffn(p, h, valid)
                 h = h + y
-            return h, new if counts is None else (new, counts)
+            return (h, kv), counts
 
         with jax.named_scope(SCOPE_LAYERS):
-            x, out = self._scan_layers(body, x, xs)
+            (x, kv), counts = self._scan_layers(body, (x, cache["kv"]), xs)
         if not moe:
-            return x, {"kv": out}
-        new_kv, counts = out
+            return x, {"kv": kv}
         total = cache["moe"]
         if valid is not None:
             total = total + jnp.sum(counts, axis=0, dtype=jnp.int32)
-        return x, {"kv": new_kv, "moe": total}
+        return x, {"kv": kv, "moe": total}
 
     def decode_step_paged(self, params, cache, tokens, pos, table, active,
                           *, use_kernel: bool = False):
@@ -835,14 +842,14 @@ class Model:
         table = jnp.asarray(table, jnp.int32)
         active = jnp.asarray(active, bool)
 
-        def attn_fn(j, p, h, kv_slice):
-            y, new = attn_mod.decode_attention_paged(
-                p["attn"], L.rmsnorm(p["ln1"], h), kv_slice, table, pos,
+        def attn_fn(j, p, h, kv, layer):
+            y, kv = attn_mod.decode_attention_paged(
+                p["attn"], L.rmsnorm(p["ln1"], h), kv, table, pos,
                 active, num_heads=c.num_heads, num_kv_heads=c.num_kv_heads,
                 head_dim=hd, rope_theta=c.rope_theta, use_kernel=use_kernel,
-                **self._attn_kw(j),
+                layer=layer, **self._attn_kw(j),
             )
-            return h + y, new
+            return h + y, kv
 
         x, new_cache = self._paged_layers(attn_fn, x, params["blocks"], cache,
                                           valid=active[:, None])
@@ -875,14 +882,14 @@ class Model:
         chunk_len = jnp.asarray(chunk_len, jnp.int32)
         table = jnp.asarray(table, jnp.int32)
 
-        def attn_fn(j, p, h, kv_slice):
-            y, new = attn_mod.prefill_attention_paged(
-                p["attn"], L.rmsnorm(p["ln1"], h), kv_slice, table, start,
+        def attn_fn(j, p, h, kv, layer):
+            y, kv = attn_mod.prefill_attention_paged(
+                p["attn"], L.rmsnorm(p["ln1"], h), kv, table, start,
                 chunk_len, num_heads=c.num_heads,
                 num_kv_heads=c.num_kv_heads, head_dim=hd,
-                rope_theta=c.rope_theta, **self._attn_kw(j),
+                rope_theta=c.rope_theta, layer=layer, **self._attn_kw(j),
             )
-            return h + y, new
+            return h + y, kv
 
         x, new_cache = self._paged_layers(attn_fn, x, params["blocks"], cache)
         with jax.named_scope(SCOPE_UNEMBED):

@@ -1,10 +1,14 @@
 """Paged KV serving (DESIGN.md §13): kernel-family parity, pool-size
 invariance, block-pool policy, memory admission control, chunked
 prefill, and the no-retrace guarantee across prompt lengths."""
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_routed_window import tiny_model
 
 from repro.configs import ARCHS
 from repro.core import ClusterSpec
@@ -47,17 +51,22 @@ def _rand_paged(seed, *, s=3, nb=6, bl=4, kv=2, g=2, hd=8):
     return q, k_pool, v_pool, table, pos
 
 
+def _rows(pool):
+    """A (NBp, BL, KV, hd) oracle pool as the ops' rows of KV * hd."""
+    return jnp.asarray(pool).reshape(*pool.shape[:2], -1)
+
+
 # --------------------------------------------- kernel family: ref/ops/pallas
 def test_paged_decode_attend_family_parity():
     q, k_pool, v_pool, table, pos = _rand_paged(1)
     want = pa.paged_decode_attend_ref(q, k_pool, v_pool, table, pos)
     got_ops = np.asarray(pa.paged_decode_attend(
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.asarray(q), _rows(k_pool), _rows(v_pool),
         jnp.asarray(table), jnp.asarray(pos),
     ))
     np.testing.assert_allclose(got_ops, want, rtol=1e-5, atol=1e-5)
     got_kernel = np.asarray(pa.paged_decode_attend_kernel(
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.asarray(q), _rows(k_pool), _rows(v_pool),
         jnp.asarray(table), jnp.asarray(pos), interpret=True,
     ))
     np.testing.assert_allclose(got_kernel, want, rtol=1e-5, atol=1e-5)
@@ -72,7 +81,7 @@ def test_paged_chunk_attend_matches_ref():
     q_pos = start[:, None] + np.arange(c, dtype=np.int32)[None, :]
     want = pa.paged_chunk_attend_ref(q, k_pool, v_pool, table, q_pos)
     got = np.asarray(pa.paged_chunk_attend(
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.asarray(q), _rows(k_pool), _rows(v_pool),
         jnp.asarray(table), jnp.asarray(q_pos),
     ))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
@@ -88,7 +97,7 @@ def test_narrow_table_attends_like_the_pool_wide_table(window):
     narrow = narrow[:, :3]
     wide = np.full((narrow.shape[0], 12), -1, np.int32)
     wide[:, :3] = narrow
-    args = lambda t: (jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(t))
+    args = lambda t: (_rows(k_pool), _rows(v_pool), jnp.asarray(t))
     got = pa.paged_decode_attend(jnp.asarray(q), *args(narrow),
                                  jnp.asarray(pos), window)
     want = pa.paged_decode_attend(jnp.asarray(q), *args(wide),
@@ -109,8 +118,8 @@ def test_scatter_routes_inactive_rows_to_sink():
     """Frozen/padded rows must write ONLY the sink block — a freed block
     reassigned to another stream can never be corrupted by them."""
     nb, bl, kv, hd = 4, 2, 1, 3
-    k_pool = jnp.zeros((nb + 1, bl, kv, hd))
-    v_pool = jnp.zeros((nb + 1, bl, kv, hd))
+    k_pool = jnp.zeros((nb + 1, bl, kv * hd))
+    v_pool = jnp.zeros((nb + 1, bl, kv * hd))
     table = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
     k_new = jnp.ones((2, kv, hd))
     pos = jnp.asarray([1, 3], jnp.int32)
@@ -120,6 +129,158 @@ def test_scatter_routes_inactive_rows_to_sink():
     assert np.all(k2[0, 1] == 1.0)  # active slot 0: block 0, offset 1
     assert np.all(k2[1:nb] == 0.0)  # inactive slot 1 touched no real block
     assert np.all(k2[nb, 1] == 1.0)  # its write landed in the sink
+
+
+# ------------------------------------------ the stacked pool, by layer index
+def _stacked(seed, layers=3):
+    """A random stacked pool of rows, the table of ``_rand_paged`` and a
+    decode batch whose second row is inactive (it writes the sink)."""
+    rng = np.random.default_rng(seed)
+    q, _, _, table, pos = _rand_paged(seed)
+    nbp, bl, kv, hd = 7, 4, 2, 8
+    k, v = (jnp.asarray(rng.standard_normal((layers, nbp, bl, kv * hd)),
+                        jnp.float32) for _ in range(2))
+    new = [jnp.asarray(rng.standard_normal((3, kv, hd)), jnp.float32)
+           for _ in range(2)]
+    active = jnp.asarray([True, False, True])
+    return q, k, v, new, jnp.asarray(table), jnp.asarray(pos), active
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_layer_indexed_ops_equal_the_per_layer_ops(layer, kind):
+    """Each op on layer ``layer`` of the stacked pool equals the same op
+    on that layer's own pool: the scatter writes only that layer (an
+    inactive or padded row lands in its sink block), and the gather and
+    the attend read only that layer."""
+    q, k, v, (k_new, v_new), table, pos, active = _stacked(7 + layer)
+    lk, lv = k[layer], v[layer]
+    if kind == "decode":
+        want = pa.scatter_decode(lk, lv, k_new, v_new, table, pos, active)
+        got = pa.scatter_decode(k, v, k_new, v_new, table, pos, active,
+                                layer=layer)
+    else:
+        start = jnp.asarray([2, 6, 0], jnp.int32)
+        chunk_len = jnp.asarray([3, 0, 2], jnp.int32)  # slot 1 not prefilling
+        kc, vc = (jnp.stack([t, t[::-1]], axis=1) for t in (k_new, v_new))
+        want = pa.scatter_chunk(lk, lv, kc, vc, table, start, chunk_len)
+        got = pa.scatter_chunk(k, v, kc, vc, table, start, chunk_len,
+                               layer=layer)
+    sink = k.shape[1] - 1
+    for full, one, before in zip(got, want, (k, v)):
+        np.testing.assert_array_equal(np.asarray(full[layer]), np.asarray(one))
+        others = np.arange(k.shape[0]) != layer
+        np.testing.assert_array_equal(np.asarray(full)[others],
+                                      np.asarray(before)[others])
+        assert not np.array_equal(np.asarray(one[sink]),
+                                  np.asarray(before[layer, sink]))
+    k2, v2 = got
+    np.testing.assert_array_equal(np.asarray(pa.gather_kv(k2, table, layer)),
+                                  np.asarray(pa.gather_kv(k2[layer], table)))
+    for window in (None, 6):
+        if kind == "decode":
+            attend = lambda *a, **kw: pa.paged_decode_attend(
+                jnp.asarray(q), *a, table, pos, window, **kw)
+        else:
+            qc = jnp.stack([jnp.asarray(q)] * 2, axis=1)
+            q_pos = start[:, None] + jnp.arange(2, dtype=jnp.int32)[None, :]
+            attend = lambda *a, **kw: pa.paged_chunk_attend(
+                qc, *a, table, q_pos, window, **kw)
+        np.testing.assert_array_equal(
+            np.asarray(attend(k2, v2, layer=layer)),
+            np.asarray(attend(k2[layer], v2[layer])))
+
+
+def _paged_model(arch):
+    """A float32 model of each scan form: reduced qwen3 (one scan over
+    layers) or the tiny window/expert model (a scan over periods)."""
+    if arch == "qwen3":
+        return Model(ARCHS["qwen3-0.6b"].reduced())
+    return tiny_model()
+
+
+def _paged_batch(model, seed=0):
+    """Weights, a pool with history and one decode step's arguments."""
+    c = model.config
+    params = model.init_params(jax.random.PRNGKey(seed))
+    slots, bl, mb = 2, 8, 3
+    cache = model.init_paged_cache(slots * mb, bl)
+    table = jnp.asarray(np.arange(slots * mb, dtype=np.int32).reshape(slots, mb))
+    tokens = jax.random.randint(jax.random.PRNGKey(seed + 1), (slots, 8), 0,
+                                c.vocab_size).astype(jnp.int32)
+    return params, cache, table, tokens
+
+
+@pytest.mark.parametrize("arch", ["qwen3", "mellum"])
+def test_paged_steps_equal_under_unrolled_layers(arch):
+    """``prefill_paged`` then ``decode_step_paged`` give the same logits
+    and the same pool whether the layers run as a scan (over layers or
+    over periods) or unrolled (``scan_layers=False``): the layer index
+    that selects the pool's layer is a scan input in one and a constant
+    in the other."""
+    model = _paged_model(arch)
+    unrolled = Model(dataclasses.replace(model.config, scan_layers=False))
+    params, cache, table, tokens = _paged_batch(model)
+    start = jnp.asarray([0, 0], jnp.int32)
+    chunk_len = jnp.asarray([8, 5], jnp.int32)
+    active = jnp.asarray([True, True])
+    outs = []
+    for m in (model, unrolled):
+        plog, pool = m.prefill_paged(params, cache, tokens, start, chunk_len,
+                                     table)
+        dlog, pool = m.decode_step_paged(params, pool, tokens[:, 0],
+                                         chunk_len, table, active)
+        outs.append((plog, dlog, pool))
+    for a, b in zip(jax.tree.leaves(outs[0]), jax.tree.leaves(outs[1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+#: HLO ops that move a whole operand: a layer of the pool sliced out of
+#: the stack, written back into it, or the stack copied
+_POOL_MOVES = re.compile(
+    r"= \w+\[([\d,]*)\](?:\{[^}]*\})? (copy|dynamic-slice|dynamic-update-slice)\(")
+
+
+@pytest.mark.parametrize("arch", ["qwen3", "mellum"])
+def test_paged_programs_move_no_pool_sized_buffer(arch):
+    """Compiled with the pool donated, a two-step decode scan and a
+    prefill chunk write the pool only by scatter: no copy, dynamic-slice
+    or dynamic-update-slice, fused or not, has the stacked pool's shape
+    or one layer's (the pool rides in the layer scan's carry, indexed by
+    layer, and is not the scan's input and output)."""
+    model = _paged_model(arch)
+    params, cache, table, tokens = _paged_batch(model)
+    pool = cache["kv"]["k"].shape
+    shapes = {pool, pool[1:], (1, *pool[1:])}
+    active = jnp.asarray([True, True])
+
+    def decode(params, cache, tok, pos):
+        def step(carry, _):
+            cache, tok, pos = carry
+            logits, cache = model.decode_step_paged(params, cache, tok, pos,
+                                                    table, active)
+            return (cache, jnp.argmax(logits, -1).astype(jnp.int32), pos + 1), None
+
+        (cache, tok, _), _ = jax.lax.scan(step, (cache, tok, pos), None, length=2)
+        return cache, tok
+
+    def prefill(params, cache):
+        return model.prefill_paged(params, cache, tokens,
+                                   jnp.asarray([0, 0], jnp.int32),
+                                   jnp.asarray([8, 5], jnp.int32), table)
+
+    programs = {
+        "decode": jax.jit(decode, donate_argnums=1).lower(
+            params, cache, tokens[:, 0], jnp.asarray([8, 5], jnp.int32)),
+        "prefill": jax.jit(prefill, donate_argnums=1).lower(params, cache),
+    }
+    for name, lowered in programs.items():
+        hlo = lowered.compile().as_text()
+        assert "scatter" in hlo, name
+        moves = [(op, shp) for shp, op in _POOL_MOVES.findall(hlo)
+                 if tuple(int(d) for d in shp.split(",") if d) in shapes]
+        assert not moves, (name, moves)
 
 
 def test_chunked_prefill_paged_matches_full_prefill_logits():

@@ -197,10 +197,13 @@ def test_slot_decode_continues_prefilled_stream():
     table = np.arange(slots * mb, dtype=np.int32).reshape(slots, mb)
     pk = np.array(cache["kv"]["k"])
     pv = np.array(cache["kv"]["v"])
+    # a pool row holds a token's KV heads side by side
+    ks = np.asarray(ks).reshape(*ks.shape[:3], -1)
+    vs = np.asarray(vs).reshape(*vs.shape[:3], -1)
     for s in range(slots):
         for t in range(s0):
-            pk[:, table[s, t // bl], t % bl] = np.asarray(ks)[:, s, t]
-            pv[:, table[s, t // bl], t % bl] = np.asarray(vs)[:, s, t]
+            pk[:, table[s, t // bl], t % bl] = ks[:, s, t]
+            pv[:, table[s, t // bl], t % bl] = vs[:, s, t]
     cache = {"kv": {"k": jnp.asarray(pk), "v": jnp.asarray(pv)}}
     active = jnp.ones((slots,), bool)
     pos = jnp.full((slots,), s0, jnp.int32)
